@@ -8,35 +8,23 @@ Prints ``name,us_per_call,derived`` style CSV sections.
 """
 from __future__ import annotations
 
-import traceback
-
 
 def main() -> None:
-    sections = []
+    """Runs every section in turn; a section that raises stops the run
+    with its traceback and a non-zero exit."""
     print("== table1: paper Table-1 cumulative speedups ==")
-    try:
-        from benchmarks import table1
-        sections.append(("table1", table1.main()))
-    except Exception:
-        traceback.print_exc()
+    from benchmarks import table1
+    table1.main()
 
     print("\n== kernels: hot-path microbenchmarks ==")
-    try:
-        from benchmarks import kernels_bench
-        sections.append(("kernels", kernels_bench.main()))
-    except Exception:
-        traceback.print_exc()
+    from benchmarks import kernels_bench
+    kernels_bench.main()
 
     print("\n== roofline: per (arch x shape) terms from dry-run ==")
-    try:
-        from benchmarks import roofline
-        rows = roofline.main()
-        if not rows:
-            print("(no dry-run artifacts found — run "
-                  "`python -m repro.launch.dryrun --all` first)")
-        sections.append(("roofline", rows))
-    except Exception:
-        traceback.print_exc()
+    from benchmarks import roofline
+    if not roofline.main():
+        print("(no dry-run artifacts found — run "
+              "`python -m repro.launch.dryrun --all` first)")
 
 
 if __name__ == "__main__":

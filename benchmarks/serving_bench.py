@@ -66,9 +66,9 @@ import numpy as np
 
 from repro.configs.registry import get_reduced, list_archs
 from repro.core.engine import InferenceEngine
-from repro.core.precision import get_policy
 from repro.core.sampling import SamplingParams
 from repro.core.scheduler import DynamicBatcher, Request, pad_batch
+from repro.launch.serve import enable_compile_cache, serving_policy
 
 
 def build_trace(n: int, seed: int, vocab: int, max_prompt: int,
@@ -700,6 +700,7 @@ def main():
                          "<base>.perfetto.json)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = None
     if args.trace_out:
@@ -707,11 +708,7 @@ def main():
         tracer = ServeTracer()
 
     cfg = get_reduced(args.arch)
-    policy = get_policy(args.policy)
-    if args.kv_dtype != "auto" or args.weights_dtype != "auto":
-        import dataclasses
-        policy = dataclasses.replace(policy, kv_dtype=args.kv_dtype,
-                                     weights_dtype=args.weights_dtype)
+    policy = serving_policy(args.policy, args.kv_dtype, args.weights_dtype)
     from repro.models import transformer as T
     params = T.init_params(jax.random.PRNGKey(0), cfg, policy)
     sp = SamplingParams()                                 # greedy

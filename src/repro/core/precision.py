@@ -88,6 +88,7 @@ def weights_store_dtype(weights_dtype: str, param_dtype):
 # ---------------------------------------------------------------------------
 
 
+@jax.jit
 def quantize_weights(w):
     """Quantize one dense weight ``w`` (..., in, out) to an int8 record.
 
@@ -98,7 +99,9 @@ def quantize_weights(w):
     column by column — so the fused kernel and the jnp fallback can both
     accumulate codes in fp32 and apply the scale once per output.
     All-zero columns get scale 0 (codes 0) via the epsilon guard, the
-    same convention as ``kv_cache.quantize_kv``.
+    same convention as ``kv_cache.quantize_kv``.  Jitted, so the fp32
+    widening fuses into the reduction and the rounding instead of
+    materializing a float copy of the weight.
     """
     wf = jnp.asarray(w).astype(jnp.float32)
     amax = jnp.max(jnp.abs(wf), axis=-2)

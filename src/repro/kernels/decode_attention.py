@@ -72,12 +72,19 @@ def _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, *, scale,
 
 def _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, *, scale,
                   attn_softcap, g):
-    """Single-query case: q (Hq, D) under one (bk,) key mask."""
+    """Single-query case: q (Hq, D) under one (1, bk) key mask."""
     Hq, D = q.shape
     Hkv = k.shape[1]
-    _attend_block_mq(q.reshape(Hkv, 1, g, D), k, v, mask[None, :],
+    _attend_block_mq(q.reshape(Hkv, 1, g, D), k, v, mask,
                      m_scr, l_scr, acc_scr, scale=scale,
                      attn_softcap=attn_softcap)
+
+
+def _page_positions(ppos):
+    """(P, page) -> (P, 1, page): each page's stored positions become
+    one lane row, so a (1, 1, page) block meets the TPU tiling rule
+    (last two block dims equal to the array's)."""
+    return ppos.reshape(ppos.shape[0], 1, ppos.shape[1])
 
 
 def shape_supported(q, k, block_k: int = DEFAULT_BLOCK_K) -> bool:
@@ -100,12 +107,10 @@ def _kernel(q_ref, k_ref, v_ref, kp_ref, qp_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)                    # (Hq, D)
     k = k_ref[0].astype(jnp.float32)                       # (bk, Hkv, D)
     v = v_ref[0].astype(jnp.float32)                       # (bk, Hkv, Dv)
-    kp = kp_ref[0]                                         # (bk,)
-    qp = qp_ref[0]                                         # (1,)
+    kp = kp_ref[0]                                         # (1, bk)
+    qp = qp_ref[0]                                         # (1, 1)
 
-    mask = (kp <= qp[0]) & (kp >= 0)
-    if window is not None:
-        mask &= kp > (qp[0] - window)
+    mask = _mq_mask(kp, qp, True, window)                  # (1, bk)
     _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
                   attn_softcap=attn_softcap, g=g)
 
@@ -143,13 +148,10 @@ def _paged_kernel(bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)                    # (Hq, D)
     k = k_ref[0].astype(jnp.float32)                       # (page, Hkv, D)
     v = v_ref[0].astype(jnp.float32)                       # (page, Hkv, Dv)
-    kp = kp_ref[0]                                         # (page,)
-    qp = qp_ref[0]                                         # (1,)
-    allocated = bt_ref[b, j] >= 0
+    kp = kp_ref[0]                                         # (1, page)
+    qp = qp_ref[0]                                         # (1, 1)
 
-    mask = (kp <= qp[0]) & (kp >= 0) & allocated
-    if window is not None:
-        mask &= kp > (qp[0] - window)
+    mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)     # (1, page)
     _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
                   attn_softcap=attn_softcap, g=g)
 
@@ -194,9 +196,9 @@ def paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
                          lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, Dv),
                          lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, j, bt: (page_of(b, j, bt), 0)),
-            pl.BlockSpec((1, 1), lambda b, j, bt: (b, 0)),
+            pl.BlockSpec((1, 1, page),
+                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j, bt: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, Hq, Dv), lambda b, j, bt: (b, 0, 0, 0)),
         scratch_shapes=[
@@ -210,7 +212,8 @@ def paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(block_tables, q, kpool, vpool, ppos, q_pos)
+    )(block_tables, q, kpool, vpool, _page_positions(ppos),
+      q_pos.reshape(B, -1, 1))
     return out
 
 
@@ -234,13 +237,10 @@ def _paged_kernel_q8(bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, kp_ref,
         * ks_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, D)
     v = v_ref[0].astype(jnp.float32) \
         * vs_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, Dv)
-    kp = kp_ref[0]                                         # (page,)
-    qp = qp_ref[0]                                         # (1,)
-    allocated = bt_ref[b, j] >= 0
+    kp = kp_ref[0]                                         # (1, page)
+    qp = qp_ref[0]                                         # (1, 1)
 
-    mask = (kp <= qp[0]) & (kp >= 0) & allocated
-    if window is not None:
-        mask &= kp > (qp[0] - window)
+    mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)     # (1, page)
     _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
                   attn_softcap=attn_softcap, g=g)
 
@@ -291,9 +291,9 @@ def paged_decode_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
                          lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv),
                          lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, j, bt: (page_of(b, j, bt), 0)),
-            pl.BlockSpec((1, 1), lambda b, j, bt: (b, 0)),
+            pl.BlockSpec((1, 1, page),
+                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j, bt: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, Hq, Dv), lambda b, j, bt: (b, 0, 0, 0)),
         scratch_shapes=[
@@ -307,7 +307,8 @@ def paged_decode_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(block_tables, q, kpool, k_scale, vpool, v_scale, ppos, q_pos)
+    )(block_tables, q, kpool, k_scale, vpool, v_scale,
+      _page_positions(ppos), q_pos.reshape(B, -1, 1))
     return out
 
 
@@ -324,16 +325,18 @@ paged_verify_shape_supported = paged_mixed_shape_supported
 
 
 def _mq_mask(kp, qp, allocated, window):
-    """(W, page) per-query key mask for one streamed page tile: causal
+    """(W, page) per-query key mask for one streamed page tile, from the
+    tile's stored positions kp (1, page) on the lane axis and the query
+    positions qp (W, 1) on the sublane axis: causal
     against the stored absolute positions — which the mixed/verify
     forward has just written for the window's own tokens too, so query j
     attends tokens 1..j-1 of its window (causality *inside* a prefill
     chunk or speculation window) for free.  A padding query carries
     qp == -1: no key satisfies ``kp <= -1 & kp >= 0``, so its row is
     fully masked and the kernel's zero-denominator guard emits zeros."""
-    mask = (kp[None, :] <= qp[:, None]) & (kp >= 0)[None, :] & allocated
+    mask = (kp <= qp) & (kp >= 0) & allocated
     if window is not None:
-        mask &= kp[None, :] > (qp[:, None] - window)
+        mask &= kp > (qp - window)
     return mask
 
 
@@ -356,8 +359,8 @@ def _paged_mixed_kernel(bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref, o_ref,
     q = q_ref[0].astype(jnp.float32)                       # (K1, Hq, D)
     k = k_ref[0].astype(jnp.float32)                       # (page, Hkv, D)
     v = v_ref[0].astype(jnp.float32)                       # (page, Hkv, Dv)
-    kp = kp_ref[0]                                         # (page,)
-    qp = qp_ref[0]                                         # (K1,)
+    kp = kp_ref[0]                                         # (1, page)
+    qp = qp_ref[0]                                         # (K1, 1)
     K1, Hq, D = q.shape
     Hkv = k.shape[1]
 
@@ -416,9 +419,9 @@ def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
                          lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, Dv),
                          lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, j, bt: (page_of(b, j, bt), 0)),
-            pl.BlockSpec((1, K1), lambda b, j, bt: (b, 0)),
+            pl.BlockSpec((1, 1, page),
+                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
+            pl.BlockSpec((1, K1, 1), lambda b, j, bt: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, K1, Hq, Dv),
                                lambda b, j, bt: (b, 0, 0, 0)),
@@ -433,7 +436,8 @@ def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(block_tables, q, kpool, vpool, ppos, q_pos)
+    )(block_tables, q, kpool, vpool, _page_positions(ppos),
+      q_pos.reshape(B, -1, 1))
     return out
 
 
@@ -460,8 +464,8 @@ def _paged_mixed_kernel_q8(bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
         * ks_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, D)
     v = v_ref[0].astype(jnp.float32) \
         * vs_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, Dv)
-    kp = kp_ref[0]
-    qp = qp_ref[0]
+    kp = kp_ref[0]                                         # (1, page)
+    qp = qp_ref[0]                                         # (K1, 1)
     K1, Hq, D = q.shape
     Hkv = k.shape[1]
 
@@ -515,9 +519,9 @@ def paged_mixed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
                          lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv),
                          lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
-            pl.BlockSpec((1, page),
-                         lambda b, j, bt: (page_of(b, j, bt), 0)),
-            pl.BlockSpec((1, K1), lambda b, j, bt: (b, 0)),
+            pl.BlockSpec((1, 1, page),
+                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
+            pl.BlockSpec((1, K1, 1), lambda b, j, bt: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, K1, Hq, Dv),
                                lambda b, j, bt: (b, 0, 0, 0)),
@@ -532,7 +536,8 @@ def paged_mixed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(block_tables, q, kpool, k_scale, vpool, v_scale, ppos, q_pos)
+    )(block_tables, q, kpool, k_scale, vpool, v_scale,
+      _page_positions(ppos), q_pos.reshape(B, -1, 1))
     return out
 
 
@@ -609,13 +614,13 @@ def _paged_packed_kernel(meta_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref,
     q = q_ref[0, pl.ds(offc, bq)].astype(jnp.float32)      # (bq, Hq, D)
     k = k_ref[0].astype(jnp.float32)                       # (page, Hkv, D)
     v = v_ref[0].astype(jnp.float32)                       # (page, Hkv, Dv)
-    kp = kp_ref[0]                                         # (page,)
-    qp = qp_ref[0, pl.ds(offc, bq)]                        # (bq,)
+    kp = kp_ref[0]                                         # (1, page)
+    qp = qp_ref[pl.ds(offc, bq)]                           # (bq, 1)
     _, Hq, D = q.shape
     Hkv = k.shape[1]
 
-    lane = offc + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)[:, 0]
-    in_win = (lane >= ws) & (lane < we)
+    lane = offc + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    in_win = (lane >= ws) & (lane < we)                    # (bq, 1)
     qp_eff = jnp.where(in_win, qp, -1)
     allocated = (slot >= 0) & (bt_ref[jnp.maximum(slot, 0), j] >= 0)
     mask = _mq_mask(kp, qp_eff, allocated, window)
@@ -631,7 +636,7 @@ def _paged_packed_kernel(meta_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref,
             .transpose(1, 0, 2, 3).reshape(bq, Hq, acc_scr.shape[-1])
         old = o_ref[0, pl.ds(offc, bq)]
         o_ref[0, pl.ds(offc, bq)] = jnp.where(
-            in_win[:, None, None], out.astype(o_ref.dtype), old)
+            in_win[:, :, None], out.astype(o_ref.dtype), old)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",
@@ -678,10 +683,10 @@ def paged_packed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
             pl.BlockSpec((1, page, Hkv, Dv),
                          lambda w, j, meta, bt: (page_of(w, j, meta, bt),
                                                  0, 0, 0)),
-            pl.BlockSpec((1, page),
+            pl.BlockSpec((1, 1, page),
                          lambda w, j, meta, bt: (page_of(w, j, meta, bt),
-                                                 0)),
-            pl.BlockSpec((1, T), lambda w, j, meta, bt: (0, 0)),
+                                                 0, 0)),
+            pl.BlockSpec((T, 1), lambda w, j, meta, bt: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, T, Hq, Dv),
                                lambda w, j, meta, bt: (0, 0, 0, 0)),
@@ -696,7 +701,8 @@ def paged_packed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, T, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(meta, block_tables, q, kpool, vpool, ppos, q_pos)
+    )(meta, block_tables, q, kpool, vpool, _page_positions(ppos),
+      q_pos.reshape(T, 1))
     # lanes no window owns (stream padding) are never written: zero them
     return jnp.where((q_pos >= 0)[..., None, None], out, 0)
 
@@ -726,13 +732,13 @@ def _paged_packed_kernel_q8(meta_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
         * ks_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, D)
     v = v_ref[0].astype(jnp.float32) \
         * vs_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, Dv)
-    kp = kp_ref[0]
-    qp = qp_ref[0, pl.ds(offc, bq)]
+    kp = kp_ref[0]                                         # (1, page)
+    qp = qp_ref[pl.ds(offc, bq)]                           # (bq, 1)
     _, Hq, D = q.shape
     Hkv = k.shape[1]
 
-    lane = offc + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)[:, 0]
-    in_win = (lane >= ws) & (lane < we)
+    lane = offc + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    in_win = (lane >= ws) & (lane < we)                    # (bq, 1)
     qp_eff = jnp.where(in_win, qp, -1)
     allocated = (slot >= 0) & (bt_ref[jnp.maximum(slot, 0), j] >= 0)
     mask = _mq_mask(kp, qp_eff, allocated, window)
@@ -748,7 +754,7 @@ def _paged_packed_kernel_q8(meta_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
             .transpose(1, 0, 2, 3).reshape(bq, Hq, acc_scr.shape[-1])
         old = o_ref[0, pl.ds(offc, bq)]
         o_ref[0, pl.ds(offc, bq)] = jnp.where(
-            in_win[:, None, None], out.astype(o_ref.dtype), old)
+            in_win[:, :, None], out.astype(o_ref.dtype), old)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",
@@ -793,10 +799,10 @@ def paged_packed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
             pl.BlockSpec((1, page, Hkv),
                          lambda w, j, meta, bt: (page_of(w, j, meta, bt),
                                                  0, 0)),
-            pl.BlockSpec((1, page),
+            pl.BlockSpec((1, 1, page),
                          lambda w, j, meta, bt: (page_of(w, j, meta, bt),
-                                                 0)),
-            pl.BlockSpec((1, T), lambda w, j, meta, bt: (0, 0)),
+                                                 0, 0)),
+            pl.BlockSpec((T, 1), lambda w, j, meta, bt: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, T, Hq, Dv),
                                lambda w, j, meta, bt: (0, 0, 0, 0)),
@@ -811,7 +817,8 @@ def paged_packed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, T, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(meta, block_tables, q, kpool, k_scale, vpool, v_scale, ppos, q_pos)
+    )(meta, block_tables, q, kpool, k_scale, vpool, v_scale,
+      _page_positions(ppos), q_pos.reshape(T, 1))
     return jnp.where((q_pos >= 0)[..., None, None], out, 0)
 
 
@@ -839,8 +846,8 @@ def decode_attention(q, k, v, k_pos, q_pos, *, window: Optional[int],
             pl.BlockSpec((1, 1, Hq, D), lambda b, ik: (b, 0, 0, 0)),
             pl.BlockSpec((1, bk, Hkv, D), lambda b, ik: (b, ik, 0, 0)),
             pl.BlockSpec((1, bk, Hkv, Dv), lambda b, ik: (b, ik, 0, 0)),
-            pl.BlockSpec((1, bk), lambda b, ik: (b, ik)),
-            pl.BlockSpec((1, 1), lambda b, ik: (b, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, ik: (b, 0, ik)),
+            pl.BlockSpec((1, 1, 1), lambda b, ik: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, Hq, Dv), lambda b, ik: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1, Hq, Dv), q.dtype),
@@ -850,5 +857,5 @@ def decode_attention(q, k, v, k_pos, q_pos, *, window: Optional[int],
             pltpu.VMEM((Hkv, g, Dv), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, k_pos, q_pos)
+    )(q, k, v, k_pos.reshape(B, 1, Sk), q_pos.reshape(B, 1, 1))
     return out

@@ -1,9 +1,12 @@
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh).
 
-MUST be the very first two lines (before any jax-touching import): force
-512 placeholder host devices so the production meshes can be built.
+A CPU-only tool.  The first lines (before any jax-touching import) pin
+this process, and the children it starts, to the CPU platform with 512
+placeholder host devices, so the production meshes can be built and it
+can never claim an accelerator.
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 

@@ -3,13 +3,19 @@
 Runs the paper's full optimized stack — KV-cache engine, half-precision,
 optional embedding pruning, dynamic batching and the staged pipeline — over
 a synthetic request stream, printing latency/throughput stats.
+
+:func:`serving_policy`, ``T.init_params``, ``InferenceEngine`` and
+:func:`run_continuous` are the calls ``--continuous`` makes;
+``chip_smoke.py`` makes the same ones.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import json
+import os
 import time
+from typing import Optional
 
 import jax
 
@@ -17,11 +23,51 @@ from repro.configs.registry import get_config, get_reduced, list_archs
 from repro.core import pruning as PR
 from repro.core.engine import InferenceEngine
 from repro.core.pipeline import run_pipelined, run_sequential
-from repro.core.precision import get_policy
+from repro.core.precision import Policy, get_policy
 from repro.core.sampling import SamplingParams
 from repro.core.tokenizer import FastTokenizer
 from repro.data.pipeline import synthetic_corpus
 from repro.models import transformer as T
+
+# compile-cache home when JAX_COMPILATION_CACHE_DIR is unset: one fixed
+# path in the checkout (the path is part of the cache key), gitignored
+COMPILE_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache for an entry point; call
+    before the first compile.  Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set, JAX already caches there and nothing else is set."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
+def serving_policy(name: str = "bf16", kv_dtype: str = "auto",
+                   weights_dtype: str = "auto") -> Policy:
+    """The precision policy plus the KV-pool and weight storage axes."""
+    policy = get_policy(name)
+    if kv_dtype != "auto":
+        policy = dataclasses.replace(policy, kv_dtype=kv_dtype)
+    if weights_dtype != "auto":
+        policy = dataclasses.replace(policy, weights_dtype=weights_dtype)
+    return policy
+
+
+def run_continuous(engine: InferenceEngine, reqs, sp: SamplingParams, *,
+                   page_size: int = 16, steps_per_sync: int = 4,
+                   max_batched_tokens: Optional[int] = None, **kw):
+    """``engine.serve_continuous`` timed on the host clock; returns
+    (requests, ServeMetrics, wall seconds).  The wall time includes any
+    compilation the first call of each shape pays."""
+    t0 = time.perf_counter()
+    done, metrics = engine.serve_continuous(
+        reqs, sp, page_size=page_size, steps_per_sync=steps_per_sync,
+        max_batched_tokens=max_batched_tokens, **kw)
+    return done, metrics, time.perf_counter() - t0
 
 
 def main():
@@ -152,12 +198,8 @@ def main():
     if cfg.num_codebooks or cfg.num_prefix_embeds:
         raise SystemExit("serve.py drives text archs; audio/VLM backbones "
                          "are exercised via dryrun + smoke tests")
-    policy = get_policy(args.policy)
-    if args.kv_dtype != "auto":
-        policy = dataclasses.replace(policy, kv_dtype=args.kv_dtype)
-    if args.weights_dtype != "auto":
-        policy = dataclasses.replace(policy,
-                                     weights_dtype=args.weights_dtype)
+    enable_compile_cache()
+    policy = serving_policy(args.policy, args.kv_dtype, args.weights_dtype)
     params = T.init_params(jax.random.PRNGKey(0), cfg, policy)
 
     corpus = synthetic_corpus(600)
@@ -179,6 +221,7 @@ def main():
                              max_batch=args.max_batch, max_len=args.max_len,
                              use_kv_cache=not args.no_kv_cache,
                              prune_maps=maps)
+    del params                 # the engine holds the only served copy
     sp = SamplingParams(temperature=args.temperature,
                         top_k=40 if args.temperature > 0 else 0)
 
@@ -204,16 +247,14 @@ def main():
         if args.trace_out:
             from repro.core.trace import ServeTracer
             tracer = ServeTracer()
-        t0 = time.time()
-        done, metrics = engine.serve_continuous(
-            reqs, sp, page_size=args.page_size,
+        done, metrics, dt = run_continuous(
+            engine, reqs, sp, page_size=args.page_size,
             steps_per_sync=args.steps_per_sync, prefix_cache=prefix,
             spec=spec, max_batched_tokens=args.max_batched_tokens,
             chunked_prefill=chunked, packed=packed,
             preemption=args.preemption,
             host_kv_bytes=args.host_kv_bytes,
             debug_audit=args.debug_audit, trace=tracer)
-        dt = time.time() - t0
         for r in done[:3]:
             print(f"[{r.uid}] {tok.decode(r.result or [])[:70]!r}")
         if tracer is not None:

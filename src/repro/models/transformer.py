@@ -78,6 +78,14 @@ def mtp_init(rng, cfg: ModelConfig):
 
 
 def init_params(rng, cfg: ModelConfig, policy: Policy = FP32):
+    """Random parameters in ``policy.param_dtype``.  Built under one jit
+    with the cast inside, so each leaf's fp32 sample fuses into its cast
+    and only the param-dtype tree is ever allocated (a full-width bf16
+    model then fits a device its fp32 tree would not)."""
+    return _init_params_jit(rng, cfg, policy)
+
+
+def _init_params(rng, cfg: ModelConfig, policy: Policy):
     ks = jax.random.split(rng, len(cfg.stacks) + 3)
     params = {
         "embed": L.embed_params_init(ks[0], cfg),
@@ -88,6 +96,9 @@ def init_params(rng, cfg: ModelConfig, policy: Policy = FP32):
     if cfg.mtp:
         params["mtp"] = mtp_init(ks[1], cfg)
     return policy.cast_params(params)
+
+
+_init_params_jit = jax.jit(_init_params, static_argnums=(1, 2))
 
 
 # ---------------------------------------------------------------------------

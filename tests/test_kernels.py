@@ -230,3 +230,45 @@ def test_kernel_mode_dispatch(rng):
         on = L.mha_attention(q, k, v, pos, pos, window=None, scale=D ** -0.5)
     np.testing.assert_allclose(np.asarray(on), np.asarray(off),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_kernel_mode_follows_backend():
+    """Unpinned, the mode follows JAX's backend: off on the CPU."""
+    with KOPS.kernel_mode_ctx(None):
+        assert KOPS.kernel_mode() == "off"
+    with KOPS.kernel_mode_ctx("interpret"):
+        assert KOPS.kernel_mode() == "interpret"
+    with pytest.raises(ValueError):
+        KOPS.set_kernel_mode("gpu")
+
+
+def test_kernel_fallback_counted_and_warned_once():
+    """With kernels on, a shape the kernel cannot take runs the jnp
+    reference, counts one fallback per traced call, and warns once per
+    kernel and shape."""
+    import warnings
+    x = jnp.ones((1, 1), jnp.float32)
+    q = jnp.ones((1, 1), jnp.int8)
+    s = jnp.ones((1,), jnp.float32)
+    KOPS.reset_counts()
+    with KOPS.kernel_mode_ctx("tpu"), warnings.catch_warnings(record=True) \
+            as caught:
+        warnings.simplefilter("always")
+        assert KOPS.maybe_quant_matmul(x, q, s) is None
+        assert KOPS.maybe_quant_matmul(x, q, s) is None
+    assert KOPS.fallback_counts() == {"quant_matmul": 2}
+    assert KOPS.kernel_counts() == {}
+    assert sum("quant_matmul" in str(w.message) for w in caught) == 1
+    KOPS.reset_counts()
+    assert KOPS.fallback_counts() == {}
+
+
+def test_mlstm_dispatch_refuses_tpu_mode(rng):
+    """The mLSTM kernel has no TPU lowering: in "tpu" mode its
+    dispatcher raises instead of falling back."""
+    q = _rand(rng, (1, 128, 2, 16), jnp.float32)
+    g = jnp.zeros((1, 128, 2), jnp.float32)
+    st = {"C": jnp.zeros((1, 2, 16, 16)), "n": jnp.zeros((1, 2, 16)),
+          "m": jnp.zeros((1, 2))}
+    with KOPS.kernel_mode_ctx("tpu"), pytest.raises(NotImplementedError):
+        KOPS.maybe_mlstm_chunked(q, q, q, g, g, st)

@@ -21,11 +21,7 @@ from repro import perf_flags
 def _fake_mesh():
     """Abstract 16x16 mesh for spec computation (no devices needed)."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:
-        # older AbstractMesh signature: one tuple of (name, size) pairs
-        return AbstractMesh((("data", 16), ("model", 16)))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_param_pspecs_shapes():

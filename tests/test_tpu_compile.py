@@ -1,0 +1,174 @@
+"""Ahead-of-time compiles for a described TPU v5e chip, no chip needed.
+
+The TPU compiler is installed with JAX, and it compiles for a chip that
+is described rather than attached.  These tests compile every kernel of
+the continuous serving path at the widths of the models it serves —
+qwen3-4b (Hq 32, Hkv 8, head_dim 128) and unimo-text (Hq = Hkv = 16,
+head_dim 64) — with bf16 and int8 page pools, and check that the
+compiled program holds the Pallas kernel (``tpu_custom_call``).  They
+catch what interpret mode cannot: blocks that break the TPU tiling
+rule, unaligned slices, kernels that use too much fast memory.  A
+compile that passes says nothing about results or speed.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process at a time may load the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import decode_attention as DA
+from repro.kernels import flash_attention as FA
+from repro.kernels import quant_matmul as QM
+
+# (Hq, Hkv, head_dim)
+WIDTHS = {"qwen3-4b": (32, 8, 128), "unimo-text": (16, 16, 64)}
+# serving geometry of the smoke: 4 slots, 512-token context, 16-token
+# pages, a 256-token packed stream
+SLOTS, PAGE, PAGES_PER_SLOT, STREAM = 4, 16, 32, 256
+NUM_PAGES = SLOTS * PAGES_PER_SLOT + 1            # + the dump page
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+        if log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+        else:
+            os.environ["TPU_LOG_DIR"] = log_dir
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _pool_shapes(Hkv, D, pool):
+    dt = jnp.int8 if pool == "int8" else jnp.bfloat16
+    kv = ((NUM_PAGES, PAGE, Hkv, D), dt)
+    scale = ((NUM_PAGES, PAGE, Hkv), jnp.float32)
+    ppos = ((NUM_PAGES, PAGE), jnp.int32)
+    bt = ((SLOTS, PAGES_PER_SLOT), jnp.int32)
+    pools = [kv, scale, kv, scale] if pool == "int8" else [kv, kv]
+    return pools, ppos, bt
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_packed_attention_compiles(one_chip, arch, pool):
+    Hq, Hkv, D = WIDTHS[arch]
+    pools, ppos, bt = _pool_shapes(Hkv, D, pool)
+    fn = DA.paged_packed_attention_q8 if pool == "int8" \
+        else DA.paged_packed_attention
+    n_work = STREAM // DA.PACKED_BLOCK_Q + SLOTS
+    compiled = _compile(
+        one_chip, lambda *a: fn(*a, window=None, scale=D ** -0.5),
+        ((1, STREAM, Hq, D), jnp.bfloat16), *pools, ppos, bt,
+        ((1, STREAM), jnp.int32), ((n_work, 4), jnp.int32))
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_decode_attention_compiles(one_chip, arch, pool):
+    Hq, Hkv, D = WIDTHS[arch]
+    pools, ppos, bt = _pool_shapes(Hkv, D, pool)
+    fn = DA.paged_decode_attention_q8 if pool == "int8" \
+        else DA.paged_decode_attention
+    compiled = _compile(
+        one_chip, lambda *a: fn(*a, window=None, scale=D ** -0.5),
+        ((SLOTS, 1, Hq, D), jnp.bfloat16), *pools, ppos, bt,
+        ((SLOTS, 1), jnp.int32))
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_paged_mixed_attention_compiles(one_chip, arch, pool):
+    """Speculative verify windows (k + 1 = 5 queries per slot); the
+    per-chunk mixed path uses the same kernel with wider windows."""
+    Hq, Hkv, D = WIDTHS[arch]
+    pools, ppos, bt = _pool_shapes(Hkv, D, pool)
+    fn = DA.paged_mixed_attention_q8 if pool == "int8" \
+        else DA.paged_mixed_attention
+    compiled = _compile(
+        one_chip, lambda *a: fn(*a, window=None, scale=D ** -0.5),
+        ((SLOTS, 5, Hq, D), jnp.bfloat16), *pools, ppos, bt,
+        ((SLOTS, 5), jnp.int32))
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def test_flash_attention_compiles(one_chip, arch):
+    Hq, Hkv, D = WIDTHS[arch]
+    S = 256
+    compiled = _compile(
+        one_chip,
+        lambda q, k, v, qp, kp: FA.flash_attention(
+            q, k, v, qp, kp, window=None, scale=D ** -0.5),
+        ((2, S, Hq, D), jnp.bfloat16), ((2, S, Hkv, D), jnp.bfloat16),
+        ((2, S, Hkv, D), jnp.bfloat16), ((2, S), jnp.int32),
+        ((2, S), jnp.int32))
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("M", [4, 256])
+@pytest.mark.parametrize("K,N", [(2560, 9728), (2560, 151936),
+                                 (1024, 4096), (1024, 12800)])
+def test_quant_matmul_compiles(one_chip, M, K, N):
+    """Decode rows and a packed stream against the FFN and unembedding
+    weights of both models."""
+    compiled = _compile(one_chip, QM.quant_matmul,
+                        ((M, K), jnp.bfloat16), ((K, N), jnp.int8),
+                        ((N,), jnp.float32))
+    _assert_kernel(compiled)
+
+
+def test_qwen3_4b_init_holds_only_bf16_weights(one_chip):
+    """The jitted init of full-width qwen3-4b under the bf16 policy
+    allocates exactly the bf16 parameter tree: no fp32 temporaries, so
+    it fits one 16 GB chip."""
+    from repro.configs.registry import get_config
+    from repro.core.precision import BF16
+    from repro.models import transformer as T
+    cfg = get_config("qwen3-4b")
+    shapes = jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg, BF16))
+    leaves = jax.tree.leaves(shapes)
+    assert all(a.dtype == jnp.bfloat16 for a in leaves)
+    tree_bytes = sum(a.size * 2 for a in leaves)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    mem = T._init_params_jit.lower(key, cfg, BF16).compile() \
+        .memory_analysis()
+    # the output is the bf16 tree (small leaves pad to the TPU tiling)
+    assert tree_bytes <= mem.output_size_in_bytes <= tree_bytes + 2 ** 20
+    assert mem.temp_size_in_bytes == 0
+    assert tree_bytes < 9e9
