@@ -273,7 +273,9 @@ class SlotState:
     shared_count: int = 0              # leading fully-shared pages
     cow_src: int = -1                  # partial tail page to copy, or -1
     emitted: List[int] = field(default_factory=list)
-    submitted_at: float = 0.0          # queued (arrival) time
+    due_at: float = 0.0                # serve time the request was due:
+    #                                    its scheduled arrival, else the
+    #                                    time it was submitted
     admitted_at: float = 0.0
     finished_at: Optional[float] = None
     # -- chunked prefill progress (unified token-budget scheduler) ----------
@@ -366,6 +368,18 @@ class PackedBatch:
     n_tokens: int            # real lanes (== plan.total_tokens)
 
 
+def attention_work(starts, lengths) -> Tuple[int, int]:
+    """(KV tokens read, query-key pairs) of one layer's paged attention
+    over segments of ``lengths`` queries at positions ``starts`` onward:
+    a segment reads its slot's context once, through its last query
+    (``start + length`` tokens), and its i-th query attends the
+    ``start + i + 1`` tokens up to itself.  A decode lane is a segment of
+    one query at position ``lens``."""
+    s = np.asarray(starts, np.int64)
+    n = np.asarray(lengths, np.int64)
+    return int((s + n).sum()), int((n * s + n * (n + 1) // 2).sum())
+
+
 @dataclass
 class ServeMetrics:
     """Per-run counters for the continuous path (the bench compares these
@@ -379,7 +393,7 @@ class ServeMetrics:
     admitted: int = 0
     retired: int = 0
     rejected: int = 0                # could never fit the page pool
-    latency_s: List[float] = field(default_factory=list)
+    latency_s: List[float] = field(default_factory=list)  # due->retire
     # -- prefix cache -------------------------------------------------------
     prefix_hits: int = 0             # admissions with a non-empty match
     prefix_matched_tokens: int = 0   # prefill tokens saved by sharing
@@ -408,7 +422,7 @@ class ServeMetrics:
     scheduler: str = "bucketed"      # "unified" (token budget) | "bucketed"
     max_batched_tokens: int = 0      # per-iteration token budget (0 = n/a)
     prefill_chunks: int = 0          # prefill chunk rows scheduled
-    ttft_s: List[float] = field(default_factory=list)   # submit->first tok
+    ttft_s: List[float] = field(default_factory=list)   # due->first tok
     itl_s: List[float] = field(default_factory=list)    # inter-token gaps
     # -- packed execution (token-packed ragged iterations) ------------------
     host_s: float = 0.0              # serve-loop wall time minus device time
@@ -592,6 +606,7 @@ class ContinuousScheduler:
         self.waiting: List[Request] = []
         self.slots: Dict[int, SlotState] = {}      # slot idx -> state
         self._submit_t: Dict[int, float] = {}      # uid -> queued time
+        self._due_t: Dict[int, float] = {}         # uid -> due time
         self._admit_seq = 0                        # FCFS chunk ordering
         # -- overload survivability ----------------------------------------
         if preemption not in ("off", "lru", "priority"):
@@ -609,14 +624,21 @@ class ContinuousScheduler:
         self.promoted_pages = 0        # host->device trie re-promotions
 
     # -- queue --------------------------------------------------------------
-    def submit(self, req: Request, now: float = 0.0) -> None:
+    def submit(self, req: Request, now: float = 0.0,
+               due: Optional[float] = None) -> None:
+        """Queue ``req`` at serve time ``now``; ``due`` is the time its
+        arrival was scheduled for (None: it was due when submitted).
+        TTFT and latency count from the due time; ``max_queue_wait``
+        counts from ``now``."""
         self.waiting.append(req)
         self._submit_t[req.uid] = now
+        self._due_t[req.uid] = now if due is None else due
         if self.trace is not None:
             self.trace.emit("enqueue", t=now, uid=req.uid,
                             prompt_len=req.prompt_len,
                             max_new=req.max_new_tokens,
-                            deadline=req.deadline)
+                            deadline=req.deadline,
+                            **({} if due is None else {"due": due}))
 
     def has_work(self) -> bool:
         return bool(self.waiting or self.slots)
@@ -805,7 +827,7 @@ class ContinuousScheduler:
         self._resume.pop(req.uid)
         st = SlotState(request=req, pages=pages, fresh_pages=pages,
                        admitted_at=now,
-                       submitted_at=self._submit_t.get(req.uid, 0.0),
+                       due_at=self._due_t.get(req.uid, 0.0),
                        admit_seq=self._admit_seq)
         self._admit_seq += 1
         st.emitted = list(pr.emitted)
@@ -920,7 +942,7 @@ class ContinuousScheduler:
                        fresh_pages=fresh, matched_len=matched,
                        shared_count=shared, cow_src=cow_src,
                        admitted_at=now,
-                       submitted_at=self._submit_t.get(req.uid, 0.0),
+                       due_at=self._due_t.get(req.uid, 0.0),
                        prefill_pos=matched, admit_seq=self._admit_seq)
         self._admit_seq += 1
         req.prefix_tokens_matched = matched

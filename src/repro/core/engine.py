@@ -27,7 +27,8 @@ from repro.core import kv_cache as KV
 from repro.core import prefix_cache as PC
 from repro.core import pruning as PR
 from repro.core.continuous import (ContinuousScheduler, FaultConfig,
-                                   HostKVStore, PageAllocator, ServeMetrics)
+                                   HostKVStore, PageAllocator, ServeMetrics,
+                                   attention_work)
 from repro.core.precision import BF16, Policy, compress_weights
 from repro.core.sampling import SamplingParams, sample, speculative_verify
 from repro.core.speculative import SpecConfig, get_drafter
@@ -101,7 +102,7 @@ class InferenceEngine:
         # serve-time weight compression (weights_dtype axis): quantize /
         # recast the dense serve-path matmul weights AFTER cast_params
         # (which would recast the fp32 scales of a quantized tree).
-        # Timed so the serve trace can carry a load-time span.
+        # Timed so the serve trace's weights event can carry it.
         t_q = time.perf_counter()
         self.params, self.weight_stats = compress_weights(self.params,
                                                           policy)
@@ -713,7 +714,10 @@ class InferenceEngine:
 
         arrivals: optional per-request arrival offsets in seconds (same
         order as ``requests``) for open-loop traces; requests only become
-        admissible once their arrival time has passed.
+        admissible once their arrival time has passed.  TTFT and latency
+        (``ServeMetrics`` and the trace) count from that due time, so the
+        loop's lag in noticing an arrival is part of them; without
+        ``arrivals`` they count from submission.
 
         spec: a :class:`~repro.core.speculative.SpecConfig` enables
         draft–verify decoding: each decode step drafts ``spec.k`` tokens
@@ -761,8 +765,9 @@ class InferenceEngine:
         trace: an optional :class:`~repro.core.trace.ServeTracer`.  When
         attached, the serve loop emits a structured event timeline —
         per-iteration records (budget use, decode lanes vs. chunk
-        segments, packed width/padding, host/device wall split, pool
-        gauges), request lifecycle events (enqueue → admit →
+        segments, packed width/padding, host/device wall split, the
+        attention work of the iteration's dispatches, pool gauges),
+        request lifecycle events (enqueue → admit →
         prefill_chunk → first_token → preempt/offload/restore → retire)
         and scheduler decisions (admission denials, victim choices,
         prefix/host-tier events) — and sources EVERY wall-clock reading
@@ -944,25 +949,26 @@ class InferenceEngine:
         t0 = clk()
         if tr is not None:
             tr.set_origin(t0)
-            # weight-compression state gauge at serve start, plus the
-            # load-time quantization span (only when weights actually
-            # compressed: the span's wall-clock duration would otherwise
-            # break fake-clock byte-determinism for uncompressed runs)
+            # weight-compression state gauge at serve start, with the
+            # construction-time quantization wall (only when weights
+            # actually compressed: that duration would otherwise break
+            # fake-clock byte-determinism for uncompressed runs)
             tr.emit("weights", 0.0, dtype=ws["weights_dtype"],
                     weight_bytes=int(ws["weight_bytes"]),
                     weight_bytes_dense=int(ws["weight_bytes_dense"]),
-                    quantized_tensors=int(ws["n_quantized"]))
-            if ws["n_quantized"]:
-                tr.emit("span", 0.0, name="quantize_weights",
-                        dur=float(self.weight_quant_s), track="load")
+                    quantized_tensors=int(ws["n_quantized"]),
+                    **({"quantize_s": float(self.weight_quant_s)}
+                       if ws["n_quantized"] else {}))
 
         def now():
             return clk() - t0
 
         # per-iteration accounting for the trace timeline: device time
-        # accumulates across this iteration's spans, then one iteration
-        # record carries the host/device split + pool gauges
-        it_acc = {"t0": 0.0, "iter": 0, "device_s": 0.0}
+        # and attention work accumulate across this iteration's
+        # dispatches, then one iteration record carries the host/device
+        # split, the attention counters and the pool gauges
+        it_acc = {"t0": 0.0, "iter": 0, "device_s": 0.0, "attn_kv": 0,
+                  "attn_pairs": 0}
 
         @contextmanager
         def dev_span(name, phase):
@@ -984,12 +990,28 @@ class InferenceEngine:
                     tr.emit("span", t=ts - t0, name=name, dur=dt,
                             track="device")
 
+        def count_attention(starts, lengths):
+            """Add one attention dispatch's work (``attention_work`` of
+            its segments) to the iteration record; callers guard on
+            ``tr`` so the untraced loop never computes it."""
+            kv, pairs = attention_work(starts, lengths)
+            it_acc["attn_kv"] += kv
+            it_acc["attn_pairs"] += pairs
+
+        def count_decode_attention(lens0, acts):
+            """A fused decode scan's work: lane s active at step k
+            (``acts[s, k]``) is one query at position ``lens0[s] + k``."""
+            pos = lens0[:, None] + np.arange(acts.shape[1])[None, :]
+            count_attention(pos[acts], 1)
+
         def emit_iteration(**kw):
             dev = it_acc["device_s"]
             it_acc["device_s"] = 0.0
             it_acc["iter"] += 1
             if tr is None:
                 return
+            kv, pairs = it_acc["attn_kv"], it_acc["attn_pairs"]
+            it_acc["attn_kv"] = it_acc["attn_pairs"] = 0
             t_it = it_acc["t0"]
             dur = max(0.0, now() - t_it)
             tr.emit("iteration", t=t_it, iter=it_acc["iter"] - 1, dur=dur,
@@ -998,7 +1020,8 @@ class InferenceEngine:
                     pages_in_use=int(sched.allocator.allocated_count),
                     host_bytes=int(host.used_bytes) if host is not None
                     else 0,
-                    trie_nodes=int(trie.num_nodes), **kw)
+                    trie_nodes=int(trie.num_nodes), attn_kv_tokens=kv,
+                    attn_pairs=pairs, **kw)
 
         def count_outcome(req):
             """Fold a request's terminal outcome into the run metrics —
@@ -1020,15 +1043,15 @@ class InferenceEngine:
             metrics.retired += 1
             metrics.generated_tokens += len(st.request.result)
             count_outcome(st.request)
-            # queue wait counts: latency is submission -> completion
-            metrics.latency_s.append(st.finished_at - st.submitted_at)
+            # queue wait counts: latency is due time -> completion
+            metrics.latency_s.append(st.finished_at - st.due_at)
             if tr is not None:
                 oc = st.request.outcome
                 tr.emit("retire", t=st.finished_at, uid=st.request.uid,
                         slot=int(slot), status=oc.status,
                         preemptions=int(oc.preemptions),
                         deadline_missed=bool(oc.deadline_missed),
-                        latency_s=st.finished_at - st.submitted_at,
+                        latency_s=st.finished_at - st.due_at,
                         generated=len(st.request.result))
 
         def record_emit(st, n, t):
@@ -1043,10 +1066,10 @@ class InferenceEngine:
                 # a slot's first emission is always the single admission /
                 # final-chunk sample: it defines TTFT and no ITL gap
                 assert n == 1, "first emission must be a single token"
-                metrics.ttft_s.append(t - st.submitted_at)
+                metrics.ttft_s.append(t - st.due_at)
                 if tr is not None:
                     tr.emit("first_token", t=t, uid=st.request.uid,
-                            ttft_s=t - st.submitted_at)
+                            ttft_s=t - st.due_at)
             else:
                 metrics.itl_s.extend([(t - st.last_token_at) / n] * n)
             st.last_token_at = t
@@ -1177,6 +1200,11 @@ class InferenceEngine:
                      "acts": None if dec is None else dec[5],
                      "finals": finals})
             metrics.host_syncs += 1
+            if tr is not None:
+                if dec is not None:
+                    count_decode_attention(lens, np.asarray(sync["acts"]))
+                count_attention([c.start for c in plan.chunks],
+                                [c.length for c in plan.chunks])
             if dec is not None:
                 tok_d, lens_d, rem_d, act_d = dec[:4]
                 metrics.slot_steps_active += int(sync["acts"].sum())
@@ -1237,6 +1265,10 @@ class InferenceEngine:
             from repro.kernels import decode_attention as DA
             W = pick_bucket(plan.total_tokens, packed_buckets)
             pb = sched.pack_batch(plan, tok, lens, W)
+            if tr is not None:
+                seg = slice(0, pb.n_segments)
+                count_attention(pb.positions[pb.seg_start[seg]],
+                                pb.seg_len[seg])
             reset_rows = np.full((slots, pages_per_slot), dump, np.int32)
             cow_src = np.full((slots,), dump, np.int32)
             cow_dst = np.full((slots,), dump, np.int32)
@@ -1346,7 +1378,7 @@ class InferenceEngine:
                 it_acc["t0"] = now()
             # -- release arrived requests into the FCFS queue -------------
             while incoming and incoming[0][0] <= now():
-                _, req = incoming.pop(0)
+                due, req = incoming.pop(0)
                 if self.prune_maps is not None:
                     req.tokens = [int(t) for t in PR.remap_tokens(
                         np.asarray([req.tokens], np.int32),
@@ -1368,7 +1400,7 @@ class InferenceEngine:
                     req.tokens = truncate_prompt(req.tokens, limit,
                                                  uid=req.uid)
                     req.truncated = True
-                sched.submit(req, now())
+                sched.submit(req, now(), due=due if arrivals else None)
 
             # -- backpressure: cancel expired / unservable queued work ----
             for req in sched.cancel_expired(now()):
@@ -1535,6 +1567,8 @@ class InferenceEngine:
                             jnp.asarray(pages_arr), cache, rng)
                     first = np.asarray(jax.block_until_ready(first))
                 metrics.host_syncs += 1
+                if tr is not None:
+                    count_attention(starts, plens)
                 t_adm = now()
                 for i, (slot, st, _) in enumerate(chunk):
                     req = st.request
@@ -1687,6 +1721,10 @@ class InferenceEngine:
                         cache, rng)
                     emits = np.asarray(jax.block_until_ready(emits))
                 metrics.host_syncs += 1
+                if tr is not None:
+                    # each live lane verifies k drafts after its pending
+                    # token: a segment of k + 1 queries from lens
+                    count_attention(lens[act], drafter.k + 1)
                 metrics.steps += 1
                 metrics.slot_steps_total += slots
                 metrics.slot_steps_active += n_lanes
@@ -1704,6 +1742,8 @@ class InferenceEngine:
                     emits = np.asarray(jax.block_until_ready(emits))
                 metrics.host_syncs += 1
                 acts = np.asarray(acts)
+                if tr is not None:
+                    count_decode_attention(lens, acts)
                 metrics.steps += steps_per_sync
                 metrics.slot_steps_total += slots * steps_per_sync
                 metrics.slot_steps_active += int(acts.sum())

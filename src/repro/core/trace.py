@@ -11,7 +11,9 @@ Three record families share one flat schema (see ``EVENT_SCHEMAS``):
 * **iteration** — one record per serve-loop iteration: token budget used vs.
   ``max_batched_tokens``, decode lanes vs. chunk segments, the chosen packed
   width bucket and padded lanes, the host/device wall split for the iteration,
-  and gauges (pages in use, host-tier bytes, radix-trie nodes) sampled each step.
+  the attention work its dispatches carry (KV tokens read and query-key
+  pairs, per layer), and gauges (pages in use, host-tier bytes, radix-trie
+  nodes) sampled each step.
 * **request lifecycle** — ``enqueue → admit → prefill_chunk* → first_token →
   (preempt/offload/restore)* → retire``, the retire stamped with the request's
   structured ``RequestOutcome``.
@@ -44,7 +46,7 @@ import json
 import time
 from collections import deque
 
-TRACE_SCHEMA_VERSION = 1
+TRACE_SCHEMA_VERSION = 2
 
 # Sentinel for schema fields that may be absent (or null) on an event.
 _OPTIONAL = True
@@ -75,6 +77,11 @@ EVENT_SCHEMAS = {
         "pages_in_use": (_INT, _REQUIRED),  # KV page-pool gauge
         "host_bytes": (_INT, _REQUIRED),    # host KV tier gauge
         "trie_nodes": (_INT, _REQUIRED),    # radix prefix-trie gauge
+        # per layer, over every attention dispatch of the iteration: KV
+        # tokens read (a segment reads its slot's context through its last
+        # query) and query-key pairs (continuous.attention_work)
+        "attn_kv_tokens": (_INT, _REQUIRED),
+        "attn_pairs": (_INT, _REQUIRED),
     },
     "span": {
         "name": (_STR, _REQUIRED),          # e.g. decode, packed, chunk, verify
@@ -87,6 +94,8 @@ EVENT_SCHEMAS = {
         "prompt_len": (_INT, _REQUIRED),
         "max_new": (_INT, _REQUIRED),
         "deadline": (_NUM, _OPTIONAL),      # absolute serve-relative seconds
+        "due": (_NUM, _OPTIONAL),           # scheduled arrival (serve-relative;
+        #                                     absent without ``arrivals``)
     },
     "admit": {
         "uid": (_INT, _REQUIRED),
@@ -165,6 +174,8 @@ EVENT_SCHEMAS = {
         "weight_bytes": (_INT, _REQUIRED),  # serve-path matmul weight bytes
         "weight_bytes_dense": (_INT, _REQUIRED),   # same set, uncompressed
         "quantized_tensors": (_INT, _REQUIRED),
+        "quantize_s": (_NUM, _OPTIONAL),    # load-time quantization wall
+        #                                     seconds (absent: none ran)
     },
 }
 
@@ -476,6 +487,8 @@ def to_perfetto_dict(events, dropped=0):
                     "idle",
                     "host_s",
                     "device_s",
+                    "attn_kv_tokens",
+                    "attn_pairs",
                 )
                 if f in ev
             }

@@ -253,6 +253,224 @@ def test_first_token_matches_metrics_ttft(rng, model):
 
 
 # ---------------------------------------------------------------------------
+# Attention-work counters and due times
+# ---------------------------------------------------------------------------
+
+PAGE = 8        # _serve's page size
+
+
+def _visible(block_row, pos):
+    """Brute force: KV entries of one slot's block-table row at absolute
+    positions <= ``pos`` (what a query at ``pos`` attends)."""
+    return sum(1 for j, page in enumerate(block_row) if page >= 0
+               for o in range(PAGE) if j * PAGE + o <= pos)
+
+
+def _brute_packed(args, _out):
+    slot_ids, positions = np.asarray(args[2]), np.asarray(args[3])
+    bt = np.asarray(args[6])
+    kv = pairs = 0
+    lanes = [t for t in range(len(slot_ids)) if slot_ids[t] >= 0]
+    for i, t in enumerate(lanes):
+        s = slot_ids[t]
+        pairs += _visible(bt[s], positions[t])
+        # a segment is a run of one slot's lanes: it reads its slot's
+        # context once, through its last query
+        if i + 1 == len(lanes) or slot_ids[lanes[i + 1]] != s:
+            kv += _visible(bt[s], positions[t])
+    return kv, pairs
+
+
+def _brute_step(args, out):
+    lens, bt = np.asarray(args[2]), np.asarray(args[5])
+    acts = np.asarray(out[7])
+    n = sum(_visible(bt[s], lens[s] + k)
+            for s in range(acts.shape[0]) for k in range(acts.shape[1])
+            if acts[s, k])
+    return n, n
+
+
+def _brute_mixed(args, _out):
+    start, n_q = int(np.asarray(args[2])[0]), int(np.asarray(args[3])[0])
+    row = np.asarray(args[4])[0]
+    return (_visible(row, start + n_q - 1),
+            sum(_visible(row, p) for p in range(start, start + n_q)))
+
+
+def _record_dispatches(eng, tr, steps_per_sync=4):
+    """Wrap the engine's cached step programs: each call appends (the
+    iteration it belongs to, its brute-force attention work, and the
+    abstract arguments it was called with) to the returned dict."""
+    from repro.core.sampling import SamplingParams
+    sp = SamplingParams()
+    calls = {"packed": [], "step": [], "mixed": [], "lowered": {}}
+
+    def wrap(name, fn, brute):
+        def run(*args):
+            avals = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), args)
+            host = [np.asarray(a) if i not in (0, len(args) - 2) else None
+                    for i, a in enumerate(args)]
+            out = fn(*args)
+            it = sum(1 for e in tr.events if e["kind"] == "iteration")
+            calls[name].append((it, brute(host, out)))
+            calls["lowered"].setdefault(name, (fn, avals))
+            return out
+        return run
+
+    eng._packed_fns(sp)
+    eng._mixed_fns(sp)
+    for steps in {1, steps_per_sync}:
+        eng._continuous_fns(sp, steps)
+    c = eng._cont_cache
+    c[("packed", sp)] = wrap("packed", c[("packed", sp)], _brute_packed)
+    c[("mixed", sp)] = wrap("mixed", c[("mixed", sp)], _brute_mixed)
+    for steps in {1, steps_per_sync}:
+        a, ap, step = c[(sp, steps)]
+        c[(sp, steps)] = (a, ap, wrap("step", step, _brute_step))
+    return calls
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_attention_counters_match_brute_force(rng, model, packed):
+    """Each iteration's attn_kv_tokens / attn_pairs equal a brute-force
+    count over its dispatches' block tables, lengths and plan: packed
+    iterations, mixed ones (decode micro-step plus per-chunk forwards)
+    and fused decode scans."""
+    cfg, params = model
+    reqs = _requests(rng, cfg, [(30, 3), (26, 9), (9, 6), (22, 7)])
+    tr = ServeTracer(clock=FakeClock())
+    eng = _engine(cfg, params)
+    calls = _record_dispatches(eng, tr)
+    _serve(eng, reqs, trace=tr, packed=packed)
+    assert validate_events(tr.events) == []
+    its = list(tr.iter_events("iteration"))
+    want = {}
+    for name in ("packed", "step", "mixed"):
+        for it, (kv, pairs) in calls[name]:
+            w = want.setdefault(it, [0, 0])
+            w[0] += kv
+            w[1] += pairs
+    got = {e["iter"]: [e["attn_kv_tokens"], e["attn_pairs"]] for e in its
+           if e["attn_kv_tokens"] or e["attn_pairs"]}
+    assert got == want
+    assert all(e["attn_kv_tokens"] == e["attn_pairs"] == 0
+               for e in its if e["idle"])
+    assert calls["packed" if packed else "mixed"]
+    assert any(its[it]["chunk_segments"] == 0 for it, _ in calls["step"]), \
+        "no fused decode iteration ran"
+
+
+def test_attention_work_rule():
+    from repro.core.continuous import attention_work
+    # decode lanes at lens 5 and 0: one query each over lens + 1 tokens
+    assert attention_work([5, 0], 1) == (7, 7)
+    # a 4-token chunk from position 3: reads 7 tokens, 4+5+6+7 pairs
+    assert attention_work([3], [4]) == (7, 22)
+    assert attention_work([], []) == (0, 0)
+
+
+def test_a_lane_retires_mid_scan_and_counts_only_its_live_steps(rng, model):
+    cfg, params = model
+    reqs = _requests(rng, cfg, [(12, 3), (10, 12)])
+    tr = ServeTracer(clock=FakeClock())
+    eng = _engine(cfg, params)
+    seen = []
+    from repro.core.sampling import SamplingParams
+    sp = SamplingParams()
+    a, ap, step = eng._continuous_fns(sp, 4)
+
+    def spy(*args):
+        lens = np.asarray(args[2]).copy()
+        out = step(*args)
+        seen.append((lens, np.asarray(out[7])))
+        return out
+    eng._cont_cache[(sp, 4)] = (a, ap, spy)
+    _serve(eng, reqs, trace=tr)
+    partial = [(l, acts) for l, acts in seen
+               if any(r.any() and not r.all() for r in acts)]
+    assert partial, "no lane retired inside a fused scan"
+    fused = [e for e in tr.iter_events("iteration")
+             if not e["idle"] and e["chunk_segments"] == 0]
+    assert len(fused) == len(seen)
+    for e, (lens, acts) in zip(fused, seen):
+        want = sum(int(lens[s]) + k + 1 for s in range(acts.shape[0])
+                   for k in range(acts.shape[1]) if acts[s, k])
+        assert e["attn_kv_tokens"] == e["attn_pairs"] == want
+
+
+def test_step_program_jit_names(rng, model):
+    """The benchmark finds the step programs' executions in the device
+    trace by their jit names: lowering them must keep jit_packed_fn and
+    jit_step_fn as the module names."""
+    cfg, params = model
+    reqs = _requests(rng, cfg, [(20, 6), (9, 6)])
+    tr = ServeTracer()
+    eng = _engine(cfg, params)
+    calls = _record_dispatches(eng, tr)
+    _serve(eng, reqs, trace=tr)
+    for name, module in (("packed", "jit_packed_fn"), ("step", "jit_step_fn")):
+        fn, avals = calls["lowered"][name]
+        text = fn.lower(*avals).as_text()
+        assert f"module @{module} " in text, text[:200]
+
+
+def test_enqueue_due_and_latencies_count_from_it(rng, model):
+    cfg, params = model
+    reqs = _requests(rng, cfg, [(13, 4), (21, 4), (9, 3)])
+    arrivals = [0.0, 0.05, 0.3]
+    tr = ServeTracer(clock=FakeClock())
+    _, m = _serve(_engine(cfg, params), reqs, trace=tr, arrivals=arrivals)
+    assert validate_events(tr.events) == []
+    enq = {e["uid"]: e for e in tr.iter_events("enqueue")}
+    due = {r.uid: a for r, a in zip(reqs, arrivals)}
+    assert {u: e["due"] for u, e in enq.items()} == due
+    assert all(e["t"] >= e["due"] for e in enq.values())
+    first = list(tr.iter_events("first_token"))
+    for e in first:
+        assert e["ttft_s"] == pytest.approx(e["t"] - due[e["uid"]],
+                                            abs=1e-12)
+    np.testing.assert_allclose(sorted(e["ttft_s"] for e in first),
+                               sorted(m.ttft_s), rtol=1e-12)
+    for e in tr.iter_events("retire"):
+        assert e["latency_s"] == pytest.approx(e["t"] - due[e["uid"]],
+                                               abs=1e-12)
+    # without arrivals the request is due when submitted: no field, and
+    # TTFT counts from the enqueue as before
+    tr2 = ServeTracer(clock=FakeClock())
+    _serve(_engine(cfg, params), reqs, trace=tr2)
+    enq2 = {e["uid"]: e for e in tr2.iter_events("enqueue")}
+    assert not any("due" in e for e in enq2.values())
+    for e in tr2.iter_events("first_token"):
+        assert e["ttft_s"] == pytest.approx(e["t"] - enq2[e["uid"]]["t"],
+                                            abs=1e-12)
+
+
+def test_schema_v2_fields_validate():
+    it = {"kind": "iteration", "t": 0.0, "iter": 0, "dur": 0.1,
+          "host_s": 0.01, "device_s": 0.09, "budget": 16, "budget_used": 9,
+          "decode_lanes": 2, "chunk_segments": 1, "chunk_tokens": 7,
+          "width_bucket": 16, "padded_lanes": 7, "idle": False,
+          "pages_in_use": 3, "host_bytes": 0, "trie_nodes": 1,
+          "attn_kv_tokens": 40, "attn_pairs": 90}
+    assert validate_event(it) == []
+    for f in ("attn_kv_tokens", "attn_pairs"):
+        assert validate_event({k: v for k, v in it.items() if k != f})
+        assert validate_event({**it, f: 1.5})
+    enq = {"kind": "enqueue", "t": 0.2, "uid": 1, "prompt_len": 5,
+           "max_new": 4}
+    assert validate_event(enq) == []
+    assert validate_event({**enq, "due": 0.15}) == []
+    assert validate_event({**enq, "due": "soon"})
+    w = {"kind": "weights", "t": 0.0, "dtype": "int8", "weight_bytes": 1,
+         "weight_bytes_dense": 2, "quantized_tensors": 1}
+    assert validate_event(w) == [] == validate_event({**w, "quantize_s": .5})
+    assert TR.TRACE_SCHEMA_VERSION == 2
+    assert validate_event({"kind": "trace_header", "v": 2}) == []
+    assert validate_event({"kind": "trace_header", "v": 1})
+
+
+# ---------------------------------------------------------------------------
 # Perfetto exporter
 # ---------------------------------------------------------------------------
 
@@ -279,6 +497,10 @@ def test_perfetto_structure(rng, model):
     # gauges exported as counter tracks, timestamps in microseconds
     counters = {e["name"] for e in te if e["ph"] == "C"}
     assert {"pages_in_use", "host_bytes", "trie_nodes"} <= counters
+    # iteration slices carry the attention counters in their args
+    its = [e for e in te if e["ph"] == "X" and e["name"] == "iteration"]
+    assert its and all({"attn_kv_tokens", "attn_pairs"} <= set(e["args"])
+                       for e in its)
     assert all(isinstance(e["ts"], (int, float))
                for e in te if "ts" in e)
 

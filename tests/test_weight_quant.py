@@ -337,9 +337,10 @@ def test_int8_weights_kernel_vs_fallback_bit_identical(rng):
 
 
 def test_weights_trace_event_and_span(rng):
-    """Traced int8 serving emits a schema-valid 'weights' event and a
-    load-time quantize_weights span on the 'load' track (never the
-    device track — its sum must keep reconciling with device_s)."""
+    """Traced int8 serving emits a schema-valid 'weights' event carrying
+    the construction-time quantization wall as ``quantize_s``, and no
+    span for it: every span is a device dispatch of this serve call (the
+    device track's sum reconciles with device_s)."""
     from repro.core.trace import ServeTracer, validate_events
     cfg = get_reduced("qwen3-4b")
     params = T.init_params(jax.random.PRNGKey(0), cfg)
@@ -352,17 +353,20 @@ def test_weights_trace_event_and_span(rng):
     assert len(wev) == 1
     assert wev[0]["dtype"] == "int8"
     assert 0 < wev[0]["weight_bytes"] < wev[0]["weight_bytes_dense"]
-    spans = [e for e in tr.events if e["kind"] == "span"
-             and e["name"] == "quantize_weights"]
-    assert len(spans) == 1 and spans[0]["track"] == "load"
-    # fp32 runs emit no quantize span (byte-determinism of fake-clock
+    assert wev[0]["quantize_s"] == eng.weight_quant_s > 0
+    spans = [e for e in tr.events if e["kind"] == "span"]
+    assert spans and all(e["track"] == "device" for e in spans)
+    assert "quantize_weights" not in {e["name"] for e in spans}
+    # fp32 runs carry no quantize_s (byte-determinism of fake-clock
     # traces) but still stamp the weights gauge
     tr2 = ServeTracer()
     InferenceEngine(cfg, params, policy=FP32, max_len=64,
                     max_batch=2).serve_continuous(
         _trace(rng), page_size=8, max_batched_tokens=16, trace=tr2)
-    assert not [e for e in tr2.events if e["kind"] == "span"
-                and e["name"] == "quantize_weights"]
+    wev2 = [e for e in tr2.events if e["kind"] == "weights"]
+    assert len(wev2) == 1 and "quantize_s" not in wev2[0]
+    assert all(e["track"] == "device" for e in tr2.events
+               if e["kind"] == "span")
     assert validate_events(tr2.events) == []
 
 
