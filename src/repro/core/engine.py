@@ -28,7 +28,7 @@ from repro.core import prefix_cache as PC
 from repro.core import pruning as PR
 from repro.core.continuous import (ContinuousScheduler, FaultConfig,
                                    HostKVStore, PageAllocator, ServeMetrics,
-                                   attention_work)
+                                   attention_work, page_work)
 from repro.core.precision import BF16, Policy, compress_weights
 from repro.core.sampling import SamplingParams, sample, speculative_verify
 from repro.core.speculative import SpecConfig, get_drafter
@@ -968,7 +968,7 @@ class InferenceEngine:
         # dispatches, then one iteration record carries the host/device
         # split, the attention counters and the pool gauges
         it_acc = {"t0": 0.0, "iter": 0, "device_s": 0.0, "attn_kv": 0,
-                  "attn_pairs": 0}
+                  "attn_pairs": 0, "attn_pages": 0, "attn_page_steps": 0}
 
         @contextmanager
         def dev_span(name, phase):
@@ -998,11 +998,27 @@ class InferenceEngine:
             it_acc["attn_kv"] += kv
             it_acc["attn_pairs"] += pairs
 
+        def count_pages(max_qpos):
+            """Add one paged-kernel dispatch's page steps (``page_work``
+            of its work items' largest query positions)."""
+            live, steps = page_work(max_qpos, page_size, pages_per_slot)
+            it_acc["attn_pages"] += live
+            it_acc["attn_page_steps"] += steps
+
+        def row_qpos(qpos):
+            """A block-table row's query position as the paged kernels
+            see it: none (-1) for a row with no first page."""
+            return np.where(block_tables[:, 0] >= 0, qpos, -1)
+
         def count_decode_attention(lens0, acts):
             """A fused decode scan's work: lane s active at step k
-            (``acts[s, k]``) is one query at position ``lens0[s] + k``."""
+            (``acts[s, k]``) is one query at position ``lens0[s] + k``.
+            Every slot is a kernel work item at every step, at the
+            position its length has reached by then."""
             pos = lens0[:, None] + np.arange(acts.shape[1])[None, :]
             count_attention(pos[acts], 1)
+            lens_k = lens0[:, None] + np.cumsum(acts, axis=1) - acts
+            count_pages(row_qpos(lens_k.T).ravel())
 
         def emit_iteration(**kw):
             dev = it_acc["device_s"]
@@ -1011,7 +1027,9 @@ class InferenceEngine:
             if tr is None:
                 return
             kv, pairs = it_acc["attn_kv"], it_acc["attn_pairs"]
+            pages, page_steps = it_acc["attn_pages"], it_acc["attn_page_steps"]
             it_acc["attn_kv"] = it_acc["attn_pairs"] = 0
+            it_acc["attn_pages"] = it_acc["attn_page_steps"] = 0
             t_it = it_acc["t0"]
             dur = max(0.0, now() - t_it)
             tr.emit("iteration", t=t_it, iter=it_acc["iter"] - 1, dur=dur,
@@ -1021,7 +1039,8 @@ class InferenceEngine:
                     host_bytes=int(host.used_bytes) if host is not None
                     else 0,
                     trie_nodes=int(trie.num_nodes), attn_kv_tokens=kv,
-                    attn_pairs=pairs, **kw)
+                    attn_pairs=pairs, attn_pages=pages,
+                    attn_page_steps=page_steps, **kw)
 
         def count_outcome(req):
             """Fold a request's terminal outcome into the run metrics —
@@ -1205,6 +1224,8 @@ class InferenceEngine:
                     count_decode_attention(lens, np.asarray(sync["acts"]))
                 count_attention([c.start for c in plan.chunks],
                                 [c.length for c in plan.chunks])
+                # each chunk: one row, through its last query
+                count_pages([c.start + c.length - 1 for c in plan.chunks])
             if dec is not None:
                 tok_d, lens_d, rem_d, act_d = dec[:4]
                 metrics.slot_steps_active += int(sync["acts"].sum())
@@ -1294,6 +1315,12 @@ class InferenceEngine:
                                         pb.seg_len[:pb.n_segments],
                                         pb.seg_slots[:pb.n_segments],
                                         W, n_work)
+            if tr is not None:
+                # each work row (query window) through its last lane;
+                # unused rows (slot -1) have none
+                count_pages(np.where(
+                    meta[:, 0] >= 0,
+                    pb.positions[np.maximum(meta[:, 3] - 1, 0)], -1))
             # one dispatch carries both shares; device_s sums both pools
             with dev_span("packed", "prefill"):
                 nxt, cache, rng = packed_fn(
@@ -1725,6 +1752,7 @@ class InferenceEngine:
                     # each live lane verifies k drafts after its pending
                     # token: a segment of k + 1 queries from lens
                     count_attention(lens[act], drafter.k + 1)
+                    count_pages(row_qpos(lens + drafter.k))
                 metrics.steps += 1
                 metrics.slot_steps_total += slots
                 metrics.slot_steps_active += n_lanes

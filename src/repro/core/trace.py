@@ -11,8 +11,9 @@ Three record families share one flat schema (see ``EVENT_SCHEMAS``):
 * **iteration** — one record per serve-loop iteration: token budget used vs.
   ``max_batched_tokens``, decode lanes vs. chunk segments, the chosen packed
   width bucket and padded lanes, the host/device wall split for the iteration,
-  the attention work its dispatches carry (KV tokens read and query-key
-  pairs, per layer), and gauges (pages in use, host-tier bytes, radix-trie
+  the attention work its dispatches carry (KV tokens read, query-key
+  pairs, and the paged kernels' page steps computed and walked, per
+  layer), and gauges (pages in use, host-tier bytes, radix-trie
   nodes) sampled each step.
 * **request lifecycle** — ``enqueue → admit → prefill_chunk* → first_token →
   (preempt/offload/restore)* → retire``, the retire stamped with the request's
@@ -46,7 +47,7 @@ import json
 import time
 from collections import deque
 
-TRACE_SCHEMA_VERSION = 2
+TRACE_SCHEMA_VERSION = 3
 
 # Sentinel for schema fields that may be absent (or null) on an event.
 _OPTIONAL = True
@@ -82,6 +83,11 @@ EVENT_SCHEMAS = {
         # query) and query-key pairs (continuous.attention_work)
         "attn_kv_tokens": (_INT, _REQUIRED),
         "attn_pairs": (_INT, _REQUIRED),
+        # per layer, over every paged attention kernel dispatch: page steps
+        # computed (each work item's live pages, continuous.page_work) and
+        # the grid's page steps (work items x pages per slot)
+        "attn_pages": (_INT, _REQUIRED),
+        "attn_page_steps": (_INT, _REQUIRED),
     },
     "span": {
         "name": (_STR, _REQUIRED),          # e.g. decode, packed, chunk, verify
@@ -489,6 +495,8 @@ def to_perfetto_dict(events, dropped=0):
                     "device_s",
                     "attn_kv_tokens",
                     "attn_pairs",
+                    "attn_pages",
+                    "attn_page_steps",
                 )
                 if f in ev
             }

@@ -16,6 +16,17 @@ The quantized-pool variant (``paged_decode_attention_q8``) streams int8
 K/V pages plus their per-entry fp32 scale rows and dequantizes
 *in-register* to fp32 right before QK^T / PV — halving the HBM bytes per
 decode step versus bf16 pages while the matmuls still accumulate in fp32.
+
+The paged kernels (decode, mixed, packed) walk a block table: their grid
+is (items, npages) with ``npages = block_tables.shape[1]``, but each item
+streams and computes only its ``n_live`` live pages (:func:`live_pages`,
+from the item's largest query position, passed by scalar prefetch).  On
+the dead steps past them the index maps repeat the last live page, so the
+pipeline fetches nothing, and the body is skipped; ``_init`` and
+``_finish`` still run at the first and last step, so the grid and the
+output contract are those of a full walk.  A skipped page lies wholly
+beyond the item's queries, and a fully masked page leaves the online
+softmax state exactly as it was, so the outputs are the full walk's.
 """
 from __future__ import annotations
 
@@ -87,6 +98,47 @@ def _page_positions(ppos):
     return ppos.reshape(ppos.shape[0], 1, ppos.shape[1])
 
 
+def live_pages(max_qpos, page: int, npages: int):
+    """The live-page bound of each grid item: how many logical pages can
+    hold a key its queries attend.  Logical page j holds positions
+    ``j*page .. j*page+page-1`` (modulo a ring layer's ring, whose every
+    page is live once it has wrapped), so a query at position p sees
+    pages ``0 .. p // page``.  max_qpos: (items,) int the item's largest
+    query position, < 0 where it has no query (0 live pages)."""
+    return jnp.where(max_qpos >= 0,
+                     jnp.minimum(max_qpos // page + 1, npages),
+                     0).astype(jnp.int32)
+
+
+def _live_page_id(bt, row, j, n_live, dump):
+    """Physical page an index map streams at page step j of an item with
+    ``n_live`` live pages on block-table row ``row``: page j while it is
+    live, then the last live page again — a block index equal to the
+    previous step's is not fetched again, so dead steps move no bytes.
+    The dump page where the item has no live page or the entry is
+    unallocated."""
+    pid = bt[row, jnp.minimum(j, jnp.maximum(n_live - 1, 0))]
+    return jnp.where((n_live == 0) | (pid < 0), dump, pid)
+
+
+def _row_live_pages(q_pos, block_tables, page: int):
+    """:func:`live_pages` of each block-table row's query window (the
+    decode and mixed kernels): its largest query position.  A row whose
+    first page is unallocated holds no context (tables fill from logical
+    page 0), so it has no live page: an empty slot's lane streams
+    nothing."""
+    B, npages = block_tables.shape
+    max_qpos = jnp.where(block_tables[:, 0] >= 0,
+                         q_pos.reshape(B, -1).max(axis=1), -1)
+    return live_pages(max_qpos, page, npages)
+
+
+def _on_live_page(j, n_live):
+    """Guard of a page step's body (dequant, matmuls, softmax update):
+    it runs only while j < n_live; dead steps leave the state as is."""
+    return pl.when(j < n_live)
+
+
 def shape_supported(q, k, block_k: int = DEFAULT_BLOCK_K) -> bool:
     B, Sq, Hq, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
@@ -129,14 +181,15 @@ def paged_shape_supported(q, kpool, block_tables) -> bool:
             and block_tables.shape[0] == B)
 
 
-def _paged_kernel(bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale, attn_softcap, window,
-                  npages, g):
+def _paged_kernel(nl_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref,
+                  o_ref, m_scr, l_scr, acc_scr, *, scale, attn_softcap,
+                  window, npages, g):
     """Same online-softmax scheme as _kernel, but the grid walks the
     slot's block table: page j streams physical page bt[b, j] from the
     pool (the BlockSpec index_map does the indirection; bt itself arrives
     via scalar prefetch).  Unallocated entries resolve to the dump page,
-    whose positions are always -1, so masking alone keeps them out."""
+    whose positions are always -1, so masking alone keeps them out.
+    Only the slot's nl[b] live pages are streamed and attended."""
     b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -145,20 +198,22 @@ def _paged_kernel(bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)                    # (Hq, D)
-    k = k_ref[0].astype(jnp.float32)                       # (page, Hkv, D)
-    v = v_ref[0].astype(jnp.float32)                       # (page, Hkv, Dv)
-    kp = kp_ref[0]                                         # (1, page)
-    qp = qp_ref[0]                                         # (1, 1)
-
-    mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)     # (1, page)
-    _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
-                  attn_softcap=attn_softcap, g=g)
+    @_on_live_page(j, nl_ref[b])
+    def _step():
+        q = q_ref[0, 0].astype(jnp.float32)                # (Hq, D)
+        k = k_ref[0].astype(jnp.float32)                   # (page, Hkv, D)
+        v = v_ref[0].astype(jnp.float32)                   # (page, Hkv, Dv)
+        kp = kp_ref[0]                                     # (1, page)
+        qp = qp_ref[0]                                     # (1, 1)
+        mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)  # (1, page)
+        _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
+                      attn_softcap=attn_softcap, g=g)
 
     @pl.when(j == npages - 1)
     def _finish():
         denom = jnp.maximum(l_scr[...], 1e-37)[..., None]
-        out = (acc_scr[...] / denom).reshape(q.shape[0], acc_scr.shape[-1])
+        out = (acc_scr[...] / denom).reshape(q_ref.shape[2],
+                                             acc_scr.shape[-1])
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -173,6 +228,10 @@ def paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
     q: (B,1,Hq,D); kpool/vpool: (P,page,Hkv,D[v]); ppos: (P,page) absolute
     positions (-1 empty); block_tables: (B,npages) physical page ids with
     -1 = unallocated; q_pos: (B,1).  Page P-1 is the dump page.
+
+    The grid spans (B, npages), but slot b streams and attends only its
+    ``n_live`` live pages (``q_pos[b] // page + 1``; none for a row with
+    no first page); later steps fetch nothing and compute nothing.
     """
     B, _, Hq, D = q.shape
     P, page, Hkv, Dv = vpool.shape
@@ -180,27 +239,29 @@ def paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
     g = Hq // Hkv
     dump = P - 1
 
-    def page_of(b, j, bt):
-        pid = bt[b, j]
-        return jnp.where(pid < 0, dump, pid)
+    n_live = _row_live_pages(q_pos, block_tables, page)
+
+    # index maps take the scalar-prefetch refs (n_live, block tables)
+    def page_of(b, j, nl, bt):
+        return _live_page_id(bt, b, j, nl[b], dump)
 
     kernel = functools.partial(_paged_kernel, scale=scale,
                                attn_softcap=attn_softcap, window=window,
                                npages=npages, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, npages),
         in_specs=[
-            pl.BlockSpec((1, 1, Hq, D), lambda b, j, bt: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, Hq, D), lambda b, j, *pf: (b, 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
             pl.BlockSpec((1, 1, page),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, j, bt: (b, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j, *pf: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, Hq, Dv), lambda b, j, bt: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, Hq, Dv), lambda b, j, *pf: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, g), jnp.float32),
             pltpu.VMEM((Hkv, g), jnp.float32),
@@ -212,13 +273,13 @@ def paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(block_tables, q, kpool, vpool, _page_positions(ppos),
+    )(n_live, block_tables, q, kpool, vpool, _page_positions(ppos),
       q_pos.reshape(B, -1, 1))
     return out
 
 
-def _paged_kernel_q8(bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, kp_ref,
-                     qp_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
+def _paged_kernel_q8(nl_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
+                     kp_ref, qp_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
                      attn_softcap, window, npages, g):
     """Quantized-pool variant of _paged_kernel: the page tile arrives as
     int8 codes plus a per-entry (page, Hkv) fp32 scale row, and the
@@ -232,22 +293,24 @@ def _paged_kernel_q8(bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref, kp_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)                    # (Hq, D)
-    k = k_ref[0].astype(jnp.float32) \
-        * ks_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, D)
-    v = v_ref[0].astype(jnp.float32) \
-        * vs_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, Dv)
-    kp = kp_ref[0]                                         # (1, page)
-    qp = qp_ref[0]                                         # (1, 1)
-
-    mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)     # (1, page)
-    _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
-                  attn_softcap=attn_softcap, g=g)
+    @_on_live_page(j, nl_ref[b])
+    def _step():
+        q = q_ref[0, 0].astype(jnp.float32)                # (Hq, D)
+        k = k_ref[0].astype(jnp.float32) \
+            * ks_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, D)
+        v = v_ref[0].astype(jnp.float32) \
+            * vs_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, Dv)
+        kp = kp_ref[0]                                     # (1, page)
+        qp = qp_ref[0]                                     # (1, 1)
+        mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)  # (1, page)
+        _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
+                      attn_softcap=attn_softcap, g=g)
 
     @pl.when(j == npages - 1)
     def _finish():
         denom = jnp.maximum(l_scr[...], 1e-37)[..., None]
-        out = (acc_scr[...] / denom).reshape(q.shape[0], acc_scr.shape[-1])
+        out = (acc_scr[...] / denom).reshape(q_ref.shape[2],
+                                             acc_scr.shape[-1])
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -264,38 +327,41 @@ def paged_decode_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
     scale pools: kpool/vpool are (P,page,Hkv,D[v]) int8 codes and
     k_scale/v_scale are (P,page,Hkv) fp32 per-entry absmax scales.
     Dequantization is fused into the page stream (in-register, before
-    the matmuls)."""
+    the matmuls).  Like the fp entry, each slot streams and dequantizes
+    only its live pages of the (B, npages) grid."""
     B, _, Hq, D = q.shape
     P, page, Hkv, Dv = vpool.shape
     npages = block_tables.shape[1]
     g = Hq // Hkv
     dump = P - 1
 
-    def page_of(b, j, bt):
-        pid = bt[b, j]
-        return jnp.where(pid < 0, dump, pid)
+    n_live = _row_live_pages(q_pos, block_tables, page)
+
+    # index maps take the scalar-prefetch refs (n_live, block tables)
+    def page_of(b, j, nl, bt):
+        return _live_page_id(bt, b, j, nl[b], dump)
 
     kernel = functools.partial(_paged_kernel_q8, scale=scale,
                                attn_softcap=attn_softcap, window=window,
                                npages=npages, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, npages),
         in_specs=[
-            pl.BlockSpec((1, 1, Hq, D), lambda b, j, bt: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, Hq, D), lambda b, j, *pf: (b, 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
             pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
             pl.BlockSpec((1, 1, page),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, j, bt: (b, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, j, *pf: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, Hq, Dv), lambda b, j, bt: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, Hq, Dv), lambda b, j, *pf: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, g), jnp.float32),
             pltpu.VMEM((Hkv, g), jnp.float32),
@@ -307,7 +373,7 @@ def paged_decode_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(block_tables, q, kpool, k_scale, vpool, v_scale,
+    )(n_live, block_tables, q, kpool, k_scale, vpool, v_scale,
       _page_positions(ppos), q_pos.reshape(B, -1, 1))
     return out
 
@@ -340,8 +406,8 @@ def _mq_mask(kp, qp, allocated, window):
     return mask
 
 
-def _paged_mixed_kernel(bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref, o_ref,
-                        m_scr, l_scr, acc_scr, *, scale, attn_softcap,
+def _paged_mixed_kernel(nl_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref,
+                        o_ref, m_scr, l_scr, acc_scr, *, scale, attn_softcap,
                         window, npages, g):
     """Multi-query-per-slot variant of _paged_kernel: all W query
     positions of a slot's window (prefill chunk, speculation window, or
@@ -356,18 +422,20 @@ def _paged_mixed_kernel(bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)                       # (K1, Hq, D)
-    k = k_ref[0].astype(jnp.float32)                       # (page, Hkv, D)
-    v = v_ref[0].astype(jnp.float32)                       # (page, Hkv, Dv)
-    kp = kp_ref[0]                                         # (1, page)
-    qp = qp_ref[0]                                         # (K1, 1)
-    K1, Hq, D = q.shape
-    Hkv = k.shape[1]
+    _, K1, Hq, D = q_ref.shape
+    Hkv = k_ref.shape[2]
 
-    mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)
-    qg = q.reshape(K1, Hkv, g, D).transpose(1, 0, 2, 3)    # (Hkv, K1, g, D)
-    _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
-                     attn_softcap=attn_softcap)
+    @_on_live_page(j, nl_ref[b])
+    def _step():
+        q = q_ref[0].astype(jnp.float32)                   # (K1, Hq, D)
+        k = k_ref[0].astype(jnp.float32)                   # (page, Hkv, D)
+        v = v_ref[0].astype(jnp.float32)                   # (page, Hkv, Dv)
+        kp = kp_ref[0]                                     # (1, page)
+        qp = qp_ref[0]                                     # (K1, 1)
+        mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)
+        qg = q.reshape(K1, Hkv, g, D).transpose(1, 0, 2, 3)  # (Hkv,K1,g,D)
+        _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
+                         attn_softcap=attn_softcap)
 
     @pl.when(j == npages - 1)
     def _finish():
@@ -395,7 +463,9 @@ def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
     discard it).  The window's own K/V must already be in the pool
     (written by ``kv_cache.paged_write_decode_multi``); stored positions
     make the per-query causal mask exact inside the window, so any
-    chunk boundary is legal.
+    chunk boundary is legal.  The grid spans (B, npages), but row b
+    streams and attends only the live pages of its largest query
+    position (:func:`live_pages`).
     """
     B, K1, Hq, D = q.shape
     P, page, Hkv, Dv = vpool.shape
@@ -403,28 +473,30 @@ def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
     g = Hq // Hkv
     dump = P - 1
 
-    def page_of(b, j, bt):
-        pid = bt[b, j]
-        return jnp.where(pid < 0, dump, pid)
+    n_live = _row_live_pages(q_pos, block_tables, page)
+
+    # index maps take the scalar-prefetch refs (n_live, block tables)
+    def page_of(b, j, nl, bt):
+        return _live_page_id(bt, b, j, nl[b], dump)
 
     kernel = functools.partial(_paged_mixed_kernel, scale=scale,
                                attn_softcap=attn_softcap, window=window,
                                npages=npages, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, npages),
         in_specs=[
-            pl.BlockSpec((1, K1, Hq, D), lambda b, j, bt: (b, 0, 0, 0)),
+            pl.BlockSpec((1, K1, Hq, D), lambda b, j, *pf: (b, 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
             pl.BlockSpec((1, 1, page),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
-            pl.BlockSpec((1, K1, 1), lambda b, j, bt: (b, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
+            pl.BlockSpec((1, K1, 1), lambda b, j, *pf: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, K1, Hq, Dv),
-                               lambda b, j, bt: (b, 0, 0, 0)),
+                               lambda b, j, *pf: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, K1 * g), jnp.float32),
             pltpu.VMEM((Hkv, K1 * g), jnp.float32),
@@ -436,7 +508,7 @@ def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(block_tables, q, kpool, vpool, _page_positions(ppos),
+    )(n_live, block_tables, q, kpool, vpool, _page_positions(ppos),
       q_pos.reshape(B, -1, 1))
     return out
 
@@ -445,9 +517,10 @@ def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
 paged_verify_attention = paged_mixed_attention
 
 
-def _paged_mixed_kernel_q8(bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                           kp_ref, qp_ref, o_ref, m_scr, l_scr, acc_scr,
-                           *, scale, attn_softcap, window, npages, g):
+def _paged_mixed_kernel_q8(nl_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
+                           vs_ref, kp_ref, qp_ref, o_ref, m_scr, l_scr,
+                           acc_scr, *, scale, attn_softcap, window, npages,
+                           g):
     """Quantized-pool mixed kernel: int8 page tiles + per-entry scale
     rows dequantized in-register (exactly _paged_kernel_q8's stream)
     feeding the multi-query online-softmax body."""
@@ -459,20 +532,22 @@ def _paged_mixed_kernel_q8(bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)                       # (K1, Hq, D)
-    k = k_ref[0].astype(jnp.float32) \
-        * ks_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, D)
-    v = v_ref[0].astype(jnp.float32) \
-        * vs_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, Dv)
-    kp = kp_ref[0]                                         # (1, page)
-    qp = qp_ref[0]                                         # (K1, 1)
-    K1, Hq, D = q.shape
-    Hkv = k.shape[1]
+    _, K1, Hq, D = q_ref.shape
+    Hkv = k_ref.shape[2]
 
-    mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)
-    qg = q.reshape(K1, Hkv, g, D).transpose(1, 0, 2, 3)
-    _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
-                     attn_softcap=attn_softcap)
+    @_on_live_page(j, nl_ref[b])
+    def _step():
+        q = q_ref[0].astype(jnp.float32)                   # (K1, Hq, D)
+        k = k_ref[0].astype(jnp.float32) \
+            * ks_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, D)
+        v = v_ref[0].astype(jnp.float32) \
+            * vs_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, Dv)
+        kp = kp_ref[0]                                     # (1, page)
+        qp = qp_ref[0]                                     # (K1, 1)
+        mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)
+        qg = q.reshape(K1, Hkv, g, D).transpose(1, 0, 2, 3)
+        _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
+                         attn_softcap=attn_softcap)
 
     @pl.when(j == npages - 1)
     def _finish():
@@ -492,39 +567,42 @@ def paged_mixed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
                              interpret: bool = False):
     """:func:`paged_mixed_attention` over an int8-quantized pool (same
     scale-pool contract as :func:`paged_decode_attention_q8`; q_pos of
-    -1 marks padding queries exactly like the fp entry)."""
+    -1 marks padding queries exactly like the fp entry, and each row
+    streams only its live pages)."""
     B, K1, Hq, D = q.shape
     P, page, Hkv, Dv = vpool.shape
     npages = block_tables.shape[1]
     g = Hq // Hkv
     dump = P - 1
 
-    def page_of(b, j, bt):
-        pid = bt[b, j]
-        return jnp.where(pid < 0, dump, pid)
+    n_live = _row_live_pages(q_pos, block_tables, page)
+
+    # index maps take the scalar-prefetch refs (n_live, block tables)
+    def page_of(b, j, nl, bt):
+        return _live_page_id(bt, b, j, nl[b], dump)
 
     kernel = functools.partial(_paged_mixed_kernel_q8, scale=scale,
                                attn_softcap=attn_softcap, window=window,
                                npages=npages, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, npages),
         in_specs=[
-            pl.BlockSpec((1, K1, Hq, D), lambda b, j, bt: (b, 0, 0, 0)),
+            pl.BlockSpec((1, K1, Hq, D), lambda b, j, *pf: (b, 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
             pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
             pl.BlockSpec((1, 1, page),
-                         lambda b, j, bt: (page_of(b, j, bt), 0, 0)),
-            pl.BlockSpec((1, K1, 1), lambda b, j, bt: (b, 0, 0)),
+                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
+            pl.BlockSpec((1, K1, 1), lambda b, j, *pf: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, K1, Hq, Dv),
-                               lambda b, j, bt: (b, 0, 0, 0)),
+                               lambda b, j, *pf: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, K1 * g), jnp.float32),
             pltpu.VMEM((Hkv, K1 * g), jnp.float32),
@@ -536,7 +614,7 @@ def paged_mixed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(block_tables, q, kpool, k_scale, vpool, v_scale,
+    )(n_live, block_tables, q, kpool, k_scale, vpool, v_scale,
       _page_positions(ppos), q_pos.reshape(B, -1, 1))
     return out
 
@@ -584,9 +662,18 @@ def packed_meta_table(seg_starts, seg_lengths, seg_slots, n_tokens,
     return meta
 
 
-def _paged_packed_kernel(meta_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref,
-                         qp_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
-                         attn_softcap, window, npages, g):
+def packed_live_pages(meta, q_pos, page: int, npages: int):
+    """:func:`live_pages` of each work row of a :func:`packed_meta_table`:
+    the position of its window's last lane (a segment's lanes carry
+    consecutive positions), none for an unused row (slot -1).
+    q_pos: (1, T)."""
+    last = q_pos[0, jnp.maximum(meta[:, 3] - 1, 0)]
+    return live_pages(jnp.where(meta[:, 0] >= 0, last, -1), page, npages)
+
+
+def _paged_packed_kernel(nl_ref, meta_ref, bt_ref, q_ref, k_ref, v_ref,
+                         kp_ref, qp_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                         scale, attn_softcap, window, npages, g):
     """Token-packed ragged variant of _paged_mixed_kernel: the grid's
     first dim walks *query windows* of the flat (1, T) stream instead of
     slots.  Work item w covers PACKED_BLOCK_Q stream lanes starting at
@@ -597,7 +684,9 @@ def _paged_packed_kernel(meta_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref,
     races).  The streamed pages are the *segment's slot's* pages
     (meta[w, 0] indexes the block table); each query lane masks keys
     against its own absolute position, so every token of every slot gets
-    its exact causal paged-attention in ONE kernel launch."""
+    its exact causal paged-attention in ONE kernel launch.  Only the
+    window's nl[w] live pages (through its last lane) are streamed and
+    attended."""
     w, j = pl.program_id(0), pl.program_id(1)
     bq = PACKED_BLOCK_Q
     slot = meta_ref[w, 0]
@@ -611,22 +700,24 @@ def _paged_packed_kernel(meta_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, pl.ds(offc, bq)].astype(jnp.float32)      # (bq, Hq, D)
-    k = k_ref[0].astype(jnp.float32)                       # (page, Hkv, D)
-    v = v_ref[0].astype(jnp.float32)                       # (page, Hkv, Dv)
-    kp = kp_ref[0]                                         # (1, page)
-    qp = qp_ref[pl.ds(offc, bq)]                           # (bq, 1)
-    _, Hq, D = q.shape
-    Hkv = k.shape[1]
-
+    _, _, Hq, D = q_ref.shape
+    Hkv = k_ref.shape[2]
     lane = offc + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     in_win = (lane >= ws) & (lane < we)                    # (bq, 1)
-    qp_eff = jnp.where(in_win, qp, -1)
-    allocated = (slot >= 0) & (bt_ref[jnp.maximum(slot, 0), j] >= 0)
-    mask = _mq_mask(kp, qp_eff, allocated, window)
-    qg = q.reshape(bq, Hkv, g, D).transpose(1, 0, 2, 3)    # (Hkv, bq, g, D)
-    _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
-                     attn_softcap=attn_softcap)
+
+    @_on_live_page(j, nl_ref[w])
+    def _step():
+        q = q_ref[0, pl.ds(offc, bq)].astype(jnp.float32)  # (bq, Hq, D)
+        k = k_ref[0].astype(jnp.float32)                   # (page, Hkv, D)
+        v = v_ref[0].astype(jnp.float32)                   # (page, Hkv, Dv)
+        kp = kp_ref[0]                                     # (1, page)
+        qp = qp_ref[pl.ds(offc, bq)]                       # (bq, 1)
+        qp_eff = jnp.where(in_win, qp, -1)
+        allocated = (slot >= 0) & (bt_ref[jnp.maximum(slot, 0), j] >= 0)
+        mask = _mq_mask(kp, qp_eff, allocated, window)
+        qg = q.reshape(bq, Hkv, g, D).transpose(1, 0, 2, 3)  # (Hkv,bq,g,D)
+        _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
+                         attn_softcap=attn_softcap)
 
     @pl.when(j == npages - 1)
     def _finish():
@@ -655,7 +746,10 @@ def paged_packed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
     The stream's own K/V must already be in the pool (written by
     ``kv_cache.paged_write_packed``); stored absolute positions give each
     query its exact causal mask over its own slot's history, including
-    earlier tokens of its own chunk."""
+    earlier tokens of its own chunk.  The grid spans (n_work, npages),
+    but work row w streams and attends only the live pages of its
+    window's last lane (:func:`packed_live_pages`); an unused row
+    (slot -1) streams nothing."""
     _, T, Hq, D = q.shape
     P, page, Hkv, Dv = vpool.shape
     npages = block_tables.shape[1]
@@ -664,32 +758,33 @@ def paged_packed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
     dump = P - 1
     bq = PACKED_BLOCK_Q
 
-    def page_of(w, j, meta, bt):
-        slot = meta[w, 0]
-        pid = bt[jnp.maximum(slot, 0), j]
-        return jnp.where((slot < 0) | (pid < 0), dump, pid)
+    n_live = packed_live_pages(meta, q_pos, page, npages)
+
+    # index maps take the scalar-prefetch refs (n_live, meta, tables)
+    def page_of(w, j, nl, meta, bt):
+        return _live_page_id(bt, jnp.maximum(meta[w, 0], 0), j, nl[w], dump)
 
     kernel = functools.partial(_paged_packed_kernel, scale=scale,
                                attn_softcap=attn_softcap, window=window,
                                npages=npages, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(n_work, npages),
         in_specs=[
-            pl.BlockSpec((1, T, Hq, D), lambda w, j, meta, bt: (0, 0, 0, 0)),
+            pl.BlockSpec((1, T, Hq, D), lambda w, j, *pf: (0, 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, D),
-                         lambda w, j, meta, bt: (page_of(w, j, meta, bt),
+                         lambda w, j, *pf: (page_of(w, j, *pf),
                                                  0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda w, j, meta, bt: (page_of(w, j, meta, bt),
+                         lambda w, j, *pf: (page_of(w, j, *pf),
                                                  0, 0, 0)),
             pl.BlockSpec((1, 1, page),
-                         lambda w, j, meta, bt: (page_of(w, j, meta, bt),
+                         lambda w, j, *pf: (page_of(w, j, *pf),
                                                  0, 0)),
-            pl.BlockSpec((T, 1), lambda w, j, meta, bt: (0, 0)),
+            pl.BlockSpec((T, 1), lambda w, j, *pf: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, T, Hq, Dv),
-                               lambda w, j, meta, bt: (0, 0, 0, 0)),
+                               lambda w, j, *pf: (0, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, bq * g), jnp.float32),
             pltpu.VMEM((Hkv, bq * g), jnp.float32),
@@ -701,15 +796,15 @@ def paged_packed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, T, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(meta, block_tables, q, kpool, vpool, _page_positions(ppos),
+    )(n_live, meta, block_tables, q, kpool, vpool, _page_positions(ppos),
       q_pos.reshape(T, 1))
     # lanes no window owns (stream padding) are never written: zero them
     return jnp.where((q_pos >= 0)[..., None, None], out, 0)
 
 
-def _paged_packed_kernel_q8(meta_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
-                            vs_ref, kp_ref, qp_ref, o_ref, m_scr, l_scr,
-                            acc_scr, *, scale, attn_softcap, window,
+def _paged_packed_kernel_q8(nl_ref, meta_ref, bt_ref, q_ref, k_ref, ks_ref,
+                            v_ref, vs_ref, kp_ref, qp_ref, o_ref, m_scr,
+                            l_scr, acc_scr, *, scale, attn_softcap, window,
                             npages, g):
     """Quantized-pool packed kernel: int8 page tiles + per-entry scale
     rows dequantized in-register, feeding the same windowed
@@ -727,24 +822,26 @@ def _paged_packed_kernel_q8(meta_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, pl.ds(offc, bq)].astype(jnp.float32)      # (bq, Hq, D)
-    k = k_ref[0].astype(jnp.float32) \
-        * ks_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, D)
-    v = v_ref[0].astype(jnp.float32) \
-        * vs_ref[0].astype(jnp.float32)[..., None]         # (page, Hkv, Dv)
-    kp = kp_ref[0]                                         # (1, page)
-    qp = qp_ref[pl.ds(offc, bq)]                           # (bq, 1)
-    _, Hq, D = q.shape
-    Hkv = k.shape[1]
-
+    _, _, Hq, D = q_ref.shape
+    Hkv = k_ref.shape[2]
     lane = offc + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     in_win = (lane >= ws) & (lane < we)                    # (bq, 1)
-    qp_eff = jnp.where(in_win, qp, -1)
-    allocated = (slot >= 0) & (bt_ref[jnp.maximum(slot, 0), j] >= 0)
-    mask = _mq_mask(kp, qp_eff, allocated, window)
-    qg = q.reshape(bq, Hkv, g, D).transpose(1, 0, 2, 3)
-    _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
-                     attn_softcap=attn_softcap)
+
+    @_on_live_page(j, nl_ref[w])
+    def _step():
+        q = q_ref[0, pl.ds(offc, bq)].astype(jnp.float32)  # (bq, Hq, D)
+        k = k_ref[0].astype(jnp.float32) \
+            * ks_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, D)
+        v = v_ref[0].astype(jnp.float32) \
+            * vs_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, Dv)
+        kp = kp_ref[0]                                     # (1, page)
+        qp = qp_ref[pl.ds(offc, bq)]                       # (bq, 1)
+        qp_eff = jnp.where(in_win, qp, -1)
+        allocated = (slot >= 0) & (bt_ref[jnp.maximum(slot, 0), j] >= 0)
+        mask = _mq_mask(kp, qp_eff, allocated, window)
+        qg = q.reshape(bq, Hkv, g, D).transpose(1, 0, 2, 3)
+        _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
+                         attn_softcap=attn_softcap)
 
     @pl.when(j == npages - 1)
     def _finish():
@@ -765,7 +862,8 @@ def paged_packed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
                               attn_softcap: Optional[float] = None,
                               interpret: bool = False):
     """:func:`paged_packed_attention` over an int8-quantized pool (same
-    scale-pool contract as :func:`paged_decode_attention_q8`)."""
+    scale-pool contract as :func:`paged_decode_attention_q8`; each work
+    row streams and dequantizes only its live pages)."""
     _, T, Hq, D = q.shape
     P, page, Hkv, Dv = vpool.shape
     npages = block_tables.shape[1]
@@ -774,38 +872,39 @@ def paged_packed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
     dump = P - 1
     bq = PACKED_BLOCK_Q
 
-    def page_of(w, j, meta, bt):
-        slot = meta[w, 0]
-        pid = bt[jnp.maximum(slot, 0), j]
-        return jnp.where((slot < 0) | (pid < 0), dump, pid)
+    n_live = packed_live_pages(meta, q_pos, page, npages)
+
+    # index maps take the scalar-prefetch refs (n_live, meta, tables)
+    def page_of(w, j, nl, meta, bt):
+        return _live_page_id(bt, jnp.maximum(meta[w, 0], 0), j, nl[w], dump)
 
     kernel = functools.partial(_paged_packed_kernel_q8, scale=scale,
                                attn_softcap=attn_softcap, window=window,
                                npages=npages, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(n_work, npages),
         in_specs=[
-            pl.BlockSpec((1, T, Hq, D), lambda w, j, meta, bt: (0, 0, 0, 0)),
+            pl.BlockSpec((1, T, Hq, D), lambda w, j, *pf: (0, 0, 0, 0)),
             pl.BlockSpec((1, page, Hkv, D),
-                         lambda w, j, meta, bt: (page_of(w, j, meta, bt),
+                         lambda w, j, *pf: (page_of(w, j, *pf),
                                                  0, 0, 0)),
             pl.BlockSpec((1, page, Hkv),
-                         lambda w, j, meta, bt: (page_of(w, j, meta, bt),
+                         lambda w, j, *pf: (page_of(w, j, *pf),
                                                  0, 0)),
             pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda w, j, meta, bt: (page_of(w, j, meta, bt),
+                         lambda w, j, *pf: (page_of(w, j, *pf),
                                                  0, 0, 0)),
             pl.BlockSpec((1, page, Hkv),
-                         lambda w, j, meta, bt: (page_of(w, j, meta, bt),
+                         lambda w, j, *pf: (page_of(w, j, *pf),
                                                  0, 0)),
             pl.BlockSpec((1, 1, page),
-                         lambda w, j, meta, bt: (page_of(w, j, meta, bt),
+                         lambda w, j, *pf: (page_of(w, j, *pf),
                                                  0, 0)),
-            pl.BlockSpec((T, 1), lambda w, j, meta, bt: (0, 0)),
+            pl.BlockSpec((T, 1), lambda w, j, *pf: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, T, Hq, Dv),
-                               lambda w, j, meta, bt: (0, 0, 0, 0)),
+                               lambda w, j, *pf: (0, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, bq * g), jnp.float32),
             pltpu.VMEM((Hkv, bq * g), jnp.float32),
@@ -817,7 +916,7 @@ def paged_packed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, T, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(meta, block_tables, q, kpool, k_scale, vpool, v_scale,
+    )(n_live, meta, block_tables, q, kpool, k_scale, vpool, v_scale,
       _page_positions(ppos), q_pos.reshape(T, 1))
     return jnp.where((q_pos >= 0)[..., None, None], out, 0)
 

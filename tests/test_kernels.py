@@ -4,6 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import kv_cache as KV
+from repro.core.continuous import page_work
 from repro.kernels import decode_attention as DA
 from repro.kernels import flash_attention as FA
 from repro.kernels import ops as KOPS
@@ -168,6 +170,131 @@ def test_paged_decode_matches_dense_decode(rng):
                               window=None, scale=D ** -0.5, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# Live-page bound: each item streams only the pages its queries can see
+# ---------------------------------------------------------------------------
+
+EDGE_PAGE, EDGE_NPAGES = 16, 4           # max_len 64
+
+
+def _edge_layout(rng, window):
+    """Block tables, stored positions and query positions that put the
+    live-page bound at its edges.  Without a window: contexts of 1, 15,
+    16, 17 and max_len tokens; an empty slot (no pages); an inactive
+    fused-decode lane (pages allocated, its query position ``lens`` never
+    written); and a slot whose allocated pages past the bound, and the
+    rest of its last live page, hold stale entries beyond its query.
+    With a window: ring layers past and short of their ring length,
+    beside unwritten pages the full-attention layers would fill."""
+    P = 40
+    ppos = np.full((P, EDGE_PAGE), -1, np.int32)
+    free = iter(rng.permutation(P - 1))   # page P-1 stays the dump page
+    rows, q_pos = [], []
+
+    def row(n_pages):
+        bt = np.full(EDGE_NPAGES, -1, np.int32)
+        bt[:n_pages] = [next(free) for _ in range(n_pages)]
+        rows.append(bt)
+        return bt
+
+    def store(bt, positions, ring_len=EDGE_NPAGES * EDGE_PAGE):
+        for p in positions:
+            rp = p % ring_len
+            ppos[bt[rp // EDGE_PAGE], rp % EDGE_PAGE] = p
+
+    max_len = EDGE_NPAGES * EDGE_PAGE
+    if window is None:
+        for ctx in (1, 15, 16, 17, max_len):
+            store(row(-(-ctx // EDGE_PAGE)), range(ctx))
+            q_pos.append(ctx - 1)
+        row(0)                                  # empty slot
+        q_pos.append(5)
+        store(row(2), range(21))                # inactive lane at lens 21
+        q_pos.append(21)
+        bt = row(EDGE_NPAGES)                   # stale entries past q_pos
+        store(bt, range(20))
+        ppos[bt[1], 4:] = np.arange(28, 40)
+        ppos[bt[2]] = np.arange(40, 56)
+        ppos[bt[3]] = np.arange(56, 72)
+        q_pos.append(19)
+    else:
+        ring_len = KV.paged_ring_len(window, EDGE_PAGE, EDGE_NPAGES)
+        for q in (70, 25, ring_len, 3):
+            store(row(EDGE_NPAGES), range(max(0, q - ring_len + 1), q + 1),
+                  ring_len)
+            q_pos.append(q)
+    return P, np.stack(rows), np.asarray(q_pos, np.int32), ppos
+
+
+@pytest.mark.parametrize("window", [None, 20])
+@pytest.mark.parametrize("W", [1, 3])
+@pytest.mark.parametrize("pool", ["fp", "q8"])
+def test_paged_kernels_live_bound_vs_oracle(rng, pool, W, window):
+    """The decode (W == 1) and mixed (W == 3: each row's query window
+    ends at its query position) kernels against the dense-gather oracle
+    on the live-bound edge layouts, in both pool dtypes."""
+    Hq, Hkv, D = 4, 2, 32
+    P, bt, q_last, ppos = _edge_layout(rng, window)
+    B = bt.shape[0]
+    k = jnp.asarray(rng.normal(size=(P, EDGE_PAGE, Hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(P, EDGE_PAGE, Hkv, D)), jnp.float32)
+    q_pos = q_last[:, None] - np.arange(W)[::-1][None, :]
+    q_pos = np.where(q_pos >= 0, q_pos, -1).astype(np.int32)
+    q = jnp.asarray(rng.normal(size=(B, W, Hq, D)), jnp.float32)
+    args = (jnp.asarray(ppos), jnp.asarray(bt), jnp.asarray(q_pos))
+    kw = dict(window=window, scale=D ** -0.5)
+    if pool == "q8":
+        (kq, ks), (vq, vs) = KV.quantize_kv(k), KV.quantize_kv(v)
+        fn = DA.paged_decode_attention_q8 if W == 1 \
+            else DA.paged_mixed_attention_q8
+        out = fn(q, kq, ks, vq, vs, *args, interpret=True, **kw)
+        ref = R.paged_mixed_attention_ref(q, kq, vq, *args, k_scale=ks,
+                                          v_scale=vs, **kw)
+    else:
+        fn = DA.paged_decode_attention if W == 1 \
+            else DA.paged_mixed_attention
+        out = fn(q, k, v, *args, interpret=True, **kw)
+        ref = R.paged_mixed_attention_ref(q, k, v, *args, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    if window is None:                   # the empty slot comes back zero
+        assert not np.asarray(out[5]).any()
+
+
+def test_live_pages_hand_count():
+    """n_live against a hand count: decode rows at page edges, a row past
+    max_len, a query-less row, an empty slot; packed windows of
+    packed_meta_table, padding rows included."""
+    page, npages = 16, 4
+    q = jnp.asarray([0, 14, 15, 16, 17, 31, 32, 63, 64, 200, -1],
+                    jnp.int32)
+    want = [1, 1, 1, 2, 2, 2, 3, 4, 4, 4, 0]
+    assert DA.live_pages(q, page, npages).tolist() == want
+    # decode rows: the query position, none for a row with no first page
+    bt = np.zeros((4, npages), np.int32)
+    bt[2] = -1
+    assert DA._row_live_pages(jnp.asarray([[15], [16], [40], [-1]]),
+                              jnp.asarray(bt), page).tolist() \
+        == [1, 2, 0, 0]
+    # mixed rows: the window's largest query position (-1 = padding)
+    assert DA._row_live_pages(jnp.asarray([[30, 31, 32], [5, -1, -1]]),
+                              jnp.asarray(bt[:2]), page).tolist() == [3, 1]
+    # packed: a decode lane at 15, a 10-token chunk from 12 (windows of
+    # 8 and 2 lanes, ending at 19 and 21), a decode lane at 16; T = 24
+    # and 8 work rows, the last 4 unused
+    meta = DA.packed_meta_table([0, 1, 11], [1, 10, 1], [0, 1, 2], 24, 8)
+    qp = np.full((1, 24), -1, np.int32)
+    qp[0, 0] = 15
+    qp[0, 1:11] = np.arange(12, 22)
+    qp[0, 11] = 16
+    got = DA.packed_live_pages(jnp.asarray(meta), jnp.asarray(qp), page,
+                               npages).tolist()
+    assert got == [1, 2, 2, 2, 0, 0, 0, 0]
+    # the serve loop's host count agrees with the kernels' bound
+    assert page_work(np.asarray(q), page, npages) == (sum(want),
+                                                      q.size * npages)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])

@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_reduced
+from repro.core import kv_cache as KV
 from repro.core.continuous import (ContinuousScheduler, PageAllocator,
                                    ServeMetrics)
 from repro.core.engine import InferenceEngine
@@ -125,6 +126,67 @@ def test_packed_kernel_q8_vs_oracle(rng):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     assert not np.asarray(out[0, n_real:]).any()
+
+
+@pytest.mark.parametrize("pool", ["fp", "q8"])
+def test_packed_kernel_live_bound_vs_oracle(rng, pool):
+    """The live-page bound at its edges in one packed stream: decode
+    lanes at contexts of 1, 15, 16, 17 and max_len tokens, a 10-token
+    chunk from position 12 (two query windows, across a page edge), a
+    slot whose allocated pages past the bound hold stale entries beyond
+    its query, an empty slot in the block table, and unused (slot -1)
+    work rows."""
+    P, page, npages, Hq, Hkv, D, T_ = 24, 16, 4, 4, 2, 16, 24
+    max_len = page * npages
+    ppos = np.full((P, page), -1, np.int32)
+    free = iter(rng.permutation(P - 1))      # last page is the dump
+    # (stored context incl. the stream's own tokens, segment length)
+    slots = [(1, 1), (15, 1), (16, 1), (17, 1), (max_len, 1), (22, 10),
+             (20, 1), (0, 0)]
+    bt = np.full((len(slots), npages), -1, np.int32)
+    slot_ids = np.full(T_, -1, np.int32)
+    q_pos = np.full(T_, -1, np.int32)
+    seg_start, seg_len, seg_slot, t = [], [], [], 0
+    for b, (ctx, n) in enumerate(slots):
+        used = npages if b == 6 else -(-ctx // page)
+        bt[b, :used] = [next(free) for _ in range(used)]
+        for p in range(ctx):
+            ppos[bt[b, p // page], p % page] = p
+        if n:
+            seg_start.append(t)
+            seg_len.append(n)
+            seg_slot.append(b)
+            slot_ids[t:t + n] = b
+            q_pos[t:t + n] = np.arange(ctx - n, ctx)
+            t += n
+    ppos[bt[6, 1], 4:] = np.arange(28, 40)   # stale, beyond position 19
+    ppos[bt[6, 2]] = np.arange(40, 56)
+    ppos[bt[6, 3]] = np.arange(56, 72)
+    n_work = T_ // DA.PACKED_BLOCK_Q + len(slots)
+    meta = DA.packed_meta_table(seg_start, seg_len, seg_slot, T_, n_work)
+    assert (meta[:, 0] < 0).any()
+    k = jnp.asarray(rng.normal(size=(P, page, Hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(P, page, Hkv, D)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(1, T_, Hq, D)), jnp.float32)
+    args = (jnp.asarray(ppos), jnp.asarray(bt), jnp.asarray(q_pos[None]))
+    if pool == "q8":
+        (kq, ks), (vq, vs) = KV.quantize_kv(k), KV.quantize_kv(v)
+        out = DA.paged_packed_attention_q8(
+            q, kq, ks, vq, vs, *args, jnp.asarray(meta), window=None,
+            scale=D ** -0.5, interpret=True)
+        ref = R.paged_packed_attention_ref(
+            q, kq, vq, *args, jnp.asarray(slot_ids), window=None,
+            scale=D ** -0.5, k_scale=ks, v_scale=vs)
+    else:
+        out = DA.paged_packed_attention(q, k, v, *args, jnp.asarray(meta),
+                                        window=None, scale=D ** -0.5,
+                                        interpret=True)
+        ref = R.paged_packed_attention_ref(q, k, v, *args,
+                                           jnp.asarray(slot_ids),
+                                           window=None, scale=D ** -0.5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    assert not np.asarray(out[0, t:]).any()
 
 
 def test_packed_meta_table_tiles_segments():

@@ -297,10 +297,46 @@ def _brute_mixed(args, _out):
             sum(_visible(row, p) for p in range(start, start + n_q)))
 
 
-def _record_dispatches(eng, tr, steps_per_sync=4):
+def _live(block_row, qmax):
+    """Hand count: the pages of a block-table row a paged kernel computes
+    for a work item whose largest query is at ``qmax`` — every page that
+    starts at or before it; none without a query or a first page."""
+    if qmax < 0 or block_row[0] < 0:
+        return 0
+    return sum(1 for j in range(len(block_row)) if j * PAGE <= qmax)
+
+
+def _pages_packed(args, _out):
+    positions, meta, bt = (np.asarray(args[i]) for i in (3, 4, 6))
+    live = sum(_live(bt[slot], positions[ws:we].max())
+               for slot, _, ws, we in meta if slot >= 0)
+    return live, len(meta) * bt.shape[1]
+
+
+def _pages_step(args, out):
+    lens, bt = np.asarray(args[2]), np.asarray(args[5])
+    acts = np.asarray(out[7])
+    live = sum(_live(bt[s], lens[s] + acts[s, :k].sum())
+               for s in range(acts.shape[0]) for k in range(acts.shape[1]))
+    return live, acts.size * bt.shape[1]
+
+
+def _pages_mixed(args, _out):
+    start, n_q = int(np.asarray(args[2])[0]), int(np.asarray(args[3])[0])
+    row = np.asarray(args[4])[0]
+    return _live(row, start + n_q - 1), len(row)
+
+
+BRUTE_WORK = {"packed": _brute_packed, "step": _brute_step,
+              "mixed": _brute_mixed}
+BRUTE_PAGES = {"packed": _pages_packed, "step": _pages_step,
+               "mixed": _pages_mixed}
+
+
+def _record_dispatches(eng, tr, steps_per_sync=4, brute=BRUTE_WORK):
     """Wrap the engine's cached step programs: each call appends (the
-    iteration it belongs to, its brute-force attention work, and the
-    abstract arguments it was called with) to the returned dict."""
+    iteration it belongs to, its ``brute`` count, and the abstract
+    arguments it was called with) to the returned dict."""
     from repro.core.sampling import SamplingParams
     sp = SamplingParams()
     calls = {"packed": [], "step": [], "mixed": [], "lowered": {}}
@@ -323,11 +359,11 @@ def _record_dispatches(eng, tr, steps_per_sync=4):
     for steps in {1, steps_per_sync}:
         eng._continuous_fns(sp, steps)
     c = eng._cont_cache
-    c[("packed", sp)] = wrap("packed", c[("packed", sp)], _brute_packed)
-    c[("mixed", sp)] = wrap("mixed", c[("mixed", sp)], _brute_mixed)
+    c[("packed", sp)] = wrap("packed", c[("packed", sp)], brute["packed"])
+    c[("mixed", sp)] = wrap("mixed", c[("mixed", sp)], brute["mixed"])
     for steps in {1, steps_per_sync}:
         a, ap, step = c[(sp, steps)]
-        c[(sp, steps)] = (a, ap, wrap("step", step, _brute_step))
+        c[(sp, steps)] = (a, ap, wrap("step", step, brute["step"]))
     return calls
 
 
@@ -359,6 +395,34 @@ def test_attention_counters_match_brute_force(rng, model, packed):
     assert calls["packed" if packed else "mixed"]
     assert any(its[it]["chunk_segments"] == 0 for it, _ in calls["step"]), \
         "no fused decode iteration ran"
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_page_counters_match_hand_count(rng, model, packed):
+    """Each iteration's attn_pages equals a hand count of the live pages
+    of its kernel dispatches' work items (packed query windows, fused
+    decode lanes at every step, chunk rows), and attn_page_steps equals
+    work items x pages per slot."""
+    cfg, params = model
+    reqs = _requests(rng, cfg, [(30, 3), (26, 9), (9, 6), (22, 7)])
+    tr = ServeTracer(clock=FakeClock())
+    eng = _engine(cfg, params)
+    calls = _record_dispatches(eng, tr, brute=BRUTE_PAGES)
+    _serve(eng, reqs, trace=tr, packed=packed)
+    assert validate_events(tr.events) == []
+    want = {}
+    for name in ("packed", "step", "mixed"):
+        for it, (live, steps) in calls[name]:
+            w = want.setdefault(it, [0, 0])
+            w[0] += live
+            w[1] += steps
+    its = list(tr.iter_events("iteration"))
+    got = {e["iter"]: [e["attn_pages"], e["attn_page_steps"]] for e in its
+           if e["attn_page_steps"]}
+    assert got == want
+    live, steps = np.asarray(list(want.values())).sum(0)
+    assert 0 < live < steps
+    assert calls["packed" if packed else "mixed"] and calls["step"]
 
 
 def test_attention_work_rule():
@@ -452,7 +516,8 @@ def test_schema_v2_fields_validate():
           "decode_lanes": 2, "chunk_segments": 1, "chunk_tokens": 7,
           "width_bucket": 16, "padded_lanes": 7, "idle": False,
           "pages_in_use": 3, "host_bytes": 0, "trie_nodes": 1,
-          "attn_kv_tokens": 40, "attn_pairs": 90}
+          "attn_kv_tokens": 40, "attn_pairs": 90, "attn_pages": 12,
+          "attn_page_steps": 96}
     assert validate_event(it) == []
     for f in ("attn_kv_tokens", "attn_pairs"):
         assert validate_event({k: v for k, v in it.items() if k != f})
@@ -465,9 +530,24 @@ def test_schema_v2_fields_validate():
     w = {"kind": "weights", "t": 0.0, "dtype": "int8", "weight_bytes": 1,
          "weight_bytes_dense": 2, "quantized_tensors": 1}
     assert validate_event(w) == [] == validate_event({**w, "quantize_s": .5})
-    assert TR.TRACE_SCHEMA_VERSION == 2
-    assert validate_event({"kind": "trace_header", "v": 2}) == []
-    assert validate_event({"kind": "trace_header", "v": 1})
+
+
+def test_schema_v3_fields_validate():
+    it = {"kind": "iteration", "t": 0.0, "iter": 0, "dur": 0.1,
+          "host_s": 0.01, "device_s": 0.09, "budget": 16, "budget_used": 9,
+          "decode_lanes": 2, "chunk_segments": 1, "chunk_tokens": 7,
+          "width_bucket": 16, "padded_lanes": 7, "idle": False,
+          "pages_in_use": 3, "host_bytes": 0, "trie_nodes": 1,
+          "attn_kv_tokens": 40, "attn_pairs": 90, "attn_pages": 12,
+          "attn_page_steps": 96}
+    assert validate_event(it) == []
+    for f in ("attn_pages", "attn_page_steps"):
+        assert validate_event({k: v for k, v in it.items() if k != f})
+        assert validate_event({**it, f: 1.5})
+    assert TR.TRACE_SCHEMA_VERSION == 3
+    assert validate_event({"kind": "trace_header", "v": 3}) == []
+    for old in (1, 2):
+        assert validate_event({"kind": "trace_header", "v": old})
 
 
 # ---------------------------------------------------------------------------
@@ -499,8 +579,8 @@ def test_perfetto_structure(rng, model):
     assert {"pages_in_use", "host_bytes", "trie_nodes"} <= counters
     # iteration slices carry the attention counters in their args
     its = [e for e in te if e["ph"] == "X" and e["name"] == "iteration"]
-    assert its and all({"attn_kv_tokens", "attn_pairs"} <= set(e["args"])
-                       for e in its)
+    assert its and all({"attn_kv_tokens", "attn_pairs", "attn_pages",
+                        "attn_page_steps"} <= set(e["args"]) for e in its)
     assert all(isinstance(e["ts"], (int, float))
                for e in te if "ts" in e)
 
