@@ -1,5 +1,5 @@
 """qwen3-4b [dense] — 36L d_model=2560 32H (GQA kv=8) d_ff=9728
-vocab=151936; qk_norm, GQA.  [hf:Qwen/Qwen3-8B]"""
+vocab=151936; qk_norm, GQA.  [hf:Qwen/Qwen3-4B]"""
 from repro.configs.base import LayerSpec, ModelConfig, uniform_stack
 
 ARCH = "qwen3-4b"
@@ -7,7 +7,7 @@ ARCH = "qwen3-4b"
 
 def config() -> ModelConfig:
     return ModelConfig(
-        name=ARCH, family="dense", source="hf:Qwen/Qwen3-8B",
+        name=ARCH, family="dense", source="hf:Qwen/Qwen3-4B",
         d_model=2560, num_heads=32, num_kv_heads=8, head_dim=128,
         d_ff=9728, vocab_size=151936,
         stacks=uniform_stack(36, LayerSpec()),

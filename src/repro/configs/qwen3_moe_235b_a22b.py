@@ -1,6 +1,6 @@
 """qwen3-moe-235b-a22b [moe] — 94L d_model=4096 64H (GQA kv=4)
 d_ff=1536(expert) vocab=151936, MoE 128e top-8, qk-norm.
-[hf:Qwen/Qwen3-30B-A3B]"""
+[hf:Qwen/Qwen3-235B-A22B]"""
 from repro.configs.base import (MOE_FFN, LayerSpec, ModelConfig, MoEConfig,
                                 uniform_stack)
 
@@ -9,7 +9,7 @@ ARCH = "qwen3-moe-235b-a22b"
 
 def config() -> ModelConfig:
     return ModelConfig(
-        name=ARCH, family="moe", source="hf:Qwen/Qwen3-30B-A3B",
+        name=ARCH, family="moe", source="hf:Qwen/Qwen3-235B-A22B",
         d_model=4096, num_heads=64, num_kv_heads=4, head_dim=128,
         d_ff=0, vocab_size=151936,
         stacks=uniform_stack(94, LayerSpec(ffn=MOE_FFN)),

@@ -18,9 +18,15 @@ _MODULES = {
     "qwen3-moe-235b-a22b": "repro.configs.qwen3_moe_235b_a22b",
     "gemma2-2b": "repro.configs.gemma2_2b",
     "unimo-text": "repro.configs.unimo_text",
+    "qwen3-30b-a3b": "repro.configs.qwen3_30b_a3b",
+    "qwen3-30b-a3b-stage6": "repro.configs.qwen3_30b_a3b_stage6",
 }
 
-ASSIGNED: List[str] = [a for a in _MODULES if a != "unimo-text"]
+# the architectures of the original brief; the others were added later
+ASSIGNED: List[str] = [
+    "qwen3-4b", "hymba-1.5b", "musicgen-medium", "deepseek-v3-671b",
+    "gemma3-27b", "xlstm-125m", "phi3-mini-3.8b", "internvl2-1b",
+    "qwen3-moe-235b-a22b", "gemma2-2b"]
 
 
 def get_config(arch: str) -> ModelConfig:

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import (ATTN, HYBRID, MLSTM, MOE_FFN, SLSTM,
+from repro.configs.base import (ATTN, HYBRID, MLSTM, SLSTM,
                                 ModelConfig)
 from repro.core import kv_cache as KV
 from repro.core import prefix_cache as PC
@@ -36,6 +36,7 @@ from repro.core.scheduler import (DEFAULT_BUCKETS, Batch, DynamicBatcher,
                                   Request, pad_batch, pick_bucket,
                                   truncate_prompt)
 from repro.core.tokenizer import EOS
+from repro.models import moe as MOE
 from repro.models import transformer as T
 
 
@@ -120,11 +121,11 @@ class InferenceEngine:
 
         def prefill_fn(params, tokens, lengths, cache):
             return T.forward_prefill(params, cfg, tokens, lengths, cache,
-                                     policy=policy, max_len=max_len)
+                                     policy=policy, max_len=max_len)[:2]
 
         def decode_fn(params, tokens, cache, lengths):
             return T.forward_decode(params, cfg, tokens, cache, lengths,
-                                    policy=policy, max_len=max_len)
+                                    policy=policy, max_len=max_len)[:2]
 
         def full_fn(params, tokens):
             return T.forward_train(params, cfg, tokens, policy=policy,
@@ -137,9 +138,9 @@ class InferenceEngine:
 
             def body(carry, _):
                 tok, cache, lens, done = carry
-                logits, cache = T.forward_decode(params, cfg, tok[:, None],
-                                                 cache, lens, policy=policy,
-                                                 max_len=max_len)
+                logits, cache, _ = T.forward_decode(
+                    params, cfg, tok[:, None], cache, lens, policy=policy,
+                    max_len=max_len)
                 nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
                 nxt = jnp.where(done, 0, nxt)
                 done = done | (nxt == EOS)     # EOS itself is not emitted
@@ -229,7 +230,7 @@ class InferenceEngine:
                 view = KV.slot_view(cache, 1)
                 paged = {"block_tables": block_row,
                          "active": jnp.ones((1,), bool)}
-                _, view = T.forward_prefill(
+                _, view, _ = T.forward_prefill(
                     params, cfg, tokens, length, view, policy=policy,
                     max_len=max_len, last_only=True, paged=paged)
                 return KV.slot_merge(cache, view,
@@ -429,14 +430,13 @@ class InferenceEngine:
             view = KV.slot_view(cache, tokens.shape[0])
             paged = {"block_tables": block_row,
                      "active": jnp.ones((tokens.shape[0],), bool)}
-            logits, view = T.forward_prefill(params, cfg, tokens, length,
-                                             view, policy=policy,
-                                             max_len=max_len, last_only=True,
-                                             paged=paged)
+            logits, view, moe = T.forward_prefill(
+                params, cfg, tokens, length, view, policy=policy,
+                max_len=max_len, last_only=True, paged=paged)
             cache = KV.slot_merge(cache, view, slot)
             rng, sub = jax.random.split(rng)
             first = sample(logits[:, 0], sub, sp)
-            return first, cache, rng
+            return first, cache, rng, moe
 
         def admit_prefix_fn(params, tokens, length, start, slot, block_row,
                             pages, cow_src, cow_dst, cow_keep, cache, rng):
@@ -448,21 +448,20 @@ class InferenceEngine:
             view = KV.slot_view(cache, tokens.shape[0])
             paged = {"block_tables": block_row,
                      "active": jnp.ones((tokens.shape[0],), bool)}
-            logits, view = T.forward_prefill(params, cfg, tokens, length,
-                                             view, policy=policy,
-                                             max_len=max_len, last_only=True,
-                                             start=start, paged=paged)
+            logits, view, moe = T.forward_prefill(
+                params, cfg, tokens, length, view, policy=policy,
+                max_len=max_len, last_only=True, start=start, paged=paged)
             cache = KV.slot_merge(cache, view, slot)
             rng, sub = jax.random.split(rng)
             first = sample(logits[:, 0], sub, sp)
-            return first, cache, rng
+            return first, cache, rng, moe
 
         def step_fn(params, tok, lens, rem, act, block_tables, cache, rng):
             paged = {"block_tables": block_tables}
 
             def body(carry, _):
-                tok, lens, rem, act, cache, rng = carry
-                logits, cache = T.forward_decode(
+                tok, lens, rem, act, cache, rng, moe = carry
+                logits, cache, mc = T.forward_decode(
                     params, cfg, tok[:, None], cache, lens, policy=policy,
                     max_len=max_len, paged={**paged, "active": act})
                 rng, sub = jax.random.split(rng)
@@ -473,13 +472,14 @@ class InferenceEngine:
                 lens = lens + act.astype(lens.dtype)
                 rem = rem - act.astype(rem.dtype)
                 tok = jnp.where(still, nxt, tok)
-                return (tok, lens, rem, still, cache, rng), (emit, act)
+                return (tok, lens, rem, still, cache, rng,
+                        MOE.merge_counts(moe, mc)), (emit, act)
 
             carry, (emits, acts) = jax.lax.scan(
-                body, (tok, lens, rem, act, cache, rng), None,
-                length=steps_per_sync)
-            tok, lens, rem, act, cache, rng = carry
-            return tok, lens, rem, act, cache, rng, emits.T, acts.T
+                body, (tok, lens, rem, act, cache, rng, MOE.no_counts()),
+                None, length=steps_per_sync)
+            tok, lens, rem, act, cache, rng, moe = carry
+            return tok, lens, rem, act, cache, rng, emits.T, acts.T, moe
 
         fns = (jax.jit(admit_fn,
                        donate_argnums=(6,) if self._donate else ()),
@@ -514,12 +514,12 @@ class InferenceEngine:
                      reset_rows, cow_src, cow_dst, cow_keep, cache, rng):
             cache = KV.reset_pages_all(cache, reset_rows)
             cache = KV.copy_pages_all(cache, cow_src, cow_dst, cow_keep)
-            logits, cache = T.forward_mixed(
+            logits, cache, moe = T.forward_mixed(
                 params, cfg, tokens, cache, row_start, n_q, policy=policy,
                 max_len=max_len, paged={"block_tables": block_tables})
             rng, sub = jax.random.split(rng)
             nxt = sample(logits[:, 0], sub, sp)
-            return nxt, cache, rng
+            return nxt, cache, rng, moe
 
         fn = jax.jit(mixed_fn, donate_argnums=(9,) if self._donate else ())
         self._cont_cache[key] = fn
@@ -551,13 +551,13 @@ class InferenceEngine:
                       cache, rng):
             cache = KV.reset_pages_all(cache, reset_rows)
             cache = KV.copy_pages_all(cache, cow_src, cow_dst, cow_keep)
-            logits, cache = T.forward_packed(
+            logits, cache, moe = T.forward_packed(
                 params, cfg, tokens, cache, slot_ids, positions, seg_last,
                 policy=policy, max_len=max_len,
                 paged={"block_tables": block_tables, "packed_meta": meta})
             rng, sub = jax.random.split(rng)
             nxt = sample(logits[0], sub, sp)        # (S,)
-            return nxt, cache, rng
+            return nxt, cache, rng, moe
 
         fn = jax.jit(packed_fn,
                      donate_argnums=(11,) if self._donate else ())
@@ -584,7 +584,7 @@ class InferenceEngine:
                       cache, rng):
             K = drafts.shape[1]
             toks_in = jnp.concatenate([tok[:, None], drafts], axis=1)
-            logits, cache = T.forward_verify(
+            logits, cache, moe = T.forward_verify(
                 params, cfg, toks_in, cache, lens, policy=policy,
                 max_len=max_len,
                 paged={"block_tables": block_tables, "active": act})
@@ -623,7 +623,7 @@ class InferenceEngine:
             # nothing at or beyond it is visible
             cache = KV.paged_truncate_all(cache, block_tables, new_lens)
             return (tok, new_lens, new_rem, still, cache, rng, emits,
-                    jnp.where(act, d_count, 0))
+                    jnp.where(act, d_count, 0), moe)
 
         fn = jax.jit(verify_fn,
                      donate_argnums=(7,) if self._donate else ())
@@ -871,14 +871,13 @@ class InferenceEngine:
         admit_fn, admit_prefix_fn, step_fn = \
             self._continuous_fns(sp, steps_per_sync)
         buckets = self.prompt_buckets()
-        # Two layer families are sensitive to prompt padding (the dense
-        # bucket path shares both limitations for ragged batches):
-        # recurrent mixers fold PAD steps into their state, and
-        # capacity-based MoE lets PAD tokens compete for expert slots.
-        # Admit those architectures at exact prompt length instead — one
-        # retrace per distinct length, but exact results.
+        # Recurrent mixers fold PAD steps into their state, so they are
+        # sensitive to prompt padding (the dense bucket path shares the
+        # limitation for ragged batches).  Admit those architectures at
+        # exact prompt length instead — one retrace per distinct length,
+        # but exact results.  (Expert layers route padding to no expert.)
         pad_sensitive = any(
-            spec.mixer in (MLSTM, SLSTM, HYBRID) or spec.ffn == MOE_FFN
+            spec.mixer in (MLSTM, SLSTM, HYBRID)
             for stack in self.cfg.stacks for spec in stack.pattern)
 
         cache = ctx["cache"]
@@ -968,7 +967,8 @@ class InferenceEngine:
         # dispatches, then one iteration record carries the host/device
         # split, the attention counters and the pool gauges
         it_acc = {"t0": 0.0, "iter": 0, "device_s": 0.0, "attn_kv": 0,
-                  "attn_pairs": 0, "attn_pages": 0, "attn_page_steps": 0}
+                  "attn_pairs": 0, "attn_pages": 0, "attn_page_steps": 0,
+                  "moe": np.zeros(3, np.int64)}
 
         @contextmanager
         def dev_span(name, phase):
@@ -1020,6 +1020,15 @@ class InferenceEngine:
             lens_k = lens0[:, None] + np.cumsum(acts, axis=1) - acts
             count_pages(row_qpos(lens_k.T).ravel())
 
+        def count_moe(moe):
+            """Add one dispatch's routing counters (the step program's
+            [assignments, experts hit, most rows of one expert]); called
+            with a tracer attached, after the dispatch's sync."""
+            moe = np.asarray(moe)
+            acc = it_acc["moe"]
+            acc[:2] += moe[:2]
+            acc[2] = max(acc[2], moe[2])
+
         def emit_iteration(**kw):
             dev = it_acc["device_s"]
             it_acc["device_s"] = 0.0
@@ -1030,6 +1039,8 @@ class InferenceEngine:
             pages, page_steps = it_acc["attn_pages"], it_acc["attn_page_steps"]
             it_acc["attn_kv"] = it_acc["attn_pairs"] = 0
             it_acc["attn_pages"] = it_acc["attn_page_steps"] = 0
+            moe = [int(v) for v in it_acc["moe"]]
+            it_acc["moe"][:] = 0
             t_it = it_acc["t0"]
             dur = max(0.0, now() - t_it)
             tr.emit("iteration", t=t_it, iter=it_acc["iter"] - 1, dur=dur,
@@ -1040,7 +1051,8 @@ class InferenceEngine:
                     else 0,
                     trie_nodes=int(trie.num_nodes), attn_kv_tokens=kv,
                     attn_pairs=pairs, attn_pages=pages,
-                    attn_page_steps=page_steps, **kw)
+                    attn_page_steps=page_steps, moe_rows=moe[0],
+                    moe_experts_hit=moe[1], moe_rows_max=moe[2], **kw)
 
         def count_outcome(req):
             """Fold a request's terminal outcome into the run metrics —
@@ -1127,13 +1139,14 @@ class InferenceEngine:
             nonlocal cache, rng
             with dev_span("decode_micro", "decode"):
                 (tok_d, lens_d, rem_d, act_d, cache, rng, emits,
-                 acts) = step_fn1(self.params, jnp.asarray(tok),
-                                  jnp.asarray(lens), jnp.asarray(rem),
-                                  jnp.asarray(act),
-                                  jnp.asarray(block_tables), cache, rng)
+                 acts, moe) = step_fn1(self.params, jnp.asarray(tok),
+                                       jnp.asarray(lens), jnp.asarray(rem),
+                                       jnp.asarray(act),
+                                       jnp.asarray(block_tables), cache,
+                                       rng)
             metrics.steps += 1
             metrics.slot_steps_total += slots
-            return tok_d, lens_d, rem_d, act_d, emits, acts
+            return tok_d, lens_d, rem_d, act_d, emits, acts, moe
 
         def run_mixed(plan):
             """One mixed iteration, ONE host sync.  The decode
@@ -1163,6 +1176,7 @@ class InferenceEngine:
             dec = decode_micro_step() if plan.decode_slots else None
             padded = 0
             finals = {}        # chunk index -> final-chunk logits handle
+            moes = []          # each dispatch's routing counters
             inited = set()     # slots whose page init this plan consumed
             for ci, c in enumerate(plan.chunks):
                 st = sched.slots[c.slot]
@@ -1190,7 +1204,7 @@ class InferenceEngine:
                         cow_keep[0] = st.matched_len
                         metrics.cow_copies += 1
                 with dev_span("chunk", "prefill"):
-                    nxt, cache, rng = mixed_fn(
+                    nxt, cache, rng, moe = mixed_fn(
                         self.params, jnp.asarray(toks),
                         jnp.asarray([c.start], jnp.int32),
                         jnp.asarray([c.length], jnp.int32),
@@ -1202,6 +1216,7 @@ class InferenceEngine:
                     tr.emit_now("prefill_chunk", uid=st.request.uid,
                                 slot=int(c.slot), start=int(c.start),
                                 len=int(c.length))
+                moes.append(moe)
                 padded += W - c.length
                 metrics.prefill_chunks += 1
                 metrics.prefill_tokens += c.length
@@ -1217,9 +1232,13 @@ class InferenceEngine:
                 sync = jax.device_get(
                     {"emits": None if dec is None else dec[4],
                      "acts": None if dec is None else dec[5],
-                     "finals": finals})
+                     "finals": finals,
+                     "moe": None if tr is None else
+                     moes + ([] if dec is None else [dec[6]])})
             metrics.host_syncs += 1
             if tr is not None:
+                for moe in sync["moe"]:
+                    count_moe(moe)
                 if dec is not None:
                     count_decode_attention(lens, np.asarray(sync["acts"]))
                 count_attention([c.start for c in plan.chunks],
@@ -1323,14 +1342,18 @@ class InferenceEngine:
                     pb.positions[np.maximum(meta[:, 3] - 1, 0)], -1))
             # one dispatch carries both shares; device_s sums both pools
             with dev_span("packed", "prefill"):
-                nxt, cache, rng = packed_fn(
+                nxt, cache, rng, moe = packed_fn(
                     self.params, jnp.asarray(pb.tokens[None, :]),
                     jnp.asarray(pb.slot_ids), jnp.asarray(pb.positions),
                     jnp.asarray(meta), jnp.asarray(pb.last_idx),
                     jnp.asarray(block_tables), jnp.asarray(reset_rows),
                     jnp.asarray(cow_src), jnp.asarray(cow_dst),
                     jnp.asarray(cow_keep), cache, rng)
-                nxt = np.asarray(jax.block_until_ready(nxt))
+                if tr is None:
+                    nxt = np.asarray(jax.block_until_ready(nxt))
+                else:
+                    nxt, moe = jax.device_get((nxt, moe))
+                    count_moe(moe)
             metrics.host_syncs += 1
             metrics.steps += 1
             metrics.slot_steps_total += slots
@@ -1579,7 +1602,7 @@ class InferenceEngine:
                         metrics.cow_copies += 1
                 with dev_span("admit_prefill", "prefill"):
                     if share:
-                        first, cache, rng = admit_prefix_fn(
+                        first, cache, rng, moe = admit_prefix_fn(
                             self.params, jnp.asarray(toks),
                             jnp.asarray(plens),
                             jnp.asarray(starts), jnp.asarray(slots_arr),
@@ -1587,7 +1610,7 @@ class InferenceEngine:
                             jnp.asarray(cow_src), jnp.asarray(cow_dst),
                             jnp.asarray(cow_keep), cache, rng)
                     else:
-                        first, cache, rng = admit_fn(
+                        first, cache, rng, moe = admit_fn(
                             self.params, jnp.asarray(toks),
                             jnp.asarray(plens),
                             jnp.asarray(slots_arr), jnp.asarray(rows),
@@ -1595,6 +1618,7 @@ class InferenceEngine:
                     first = np.asarray(jax.block_until_ready(first))
                 metrics.host_syncs += 1
                 if tr is not None:
+                    count_moe(moe)
                     count_attention(starts, plens)
                 t_adm = now()
                 for i, (slot, st, _) in enumerate(chunk):
@@ -1741,7 +1765,7 @@ class InferenceEngine:
                             contexts[slot] = st.request.tokens + st.emitted
                     drafts = drafter.propose_slots(contexts)
                     (tok_d, lens_d, rem_d, act_d, cache, rng, emits,
-                     accepted) = verify_fn(
+                     accepted, moe) = verify_fn(
                         self.params, jnp.asarray(tok), jnp.asarray(lens),
                         jnp.asarray(rem), jnp.asarray(act),
                         jnp.asarray(drafts), jnp.asarray(block_tables),
@@ -1749,6 +1773,7 @@ class InferenceEngine:
                     emits = np.asarray(jax.block_until_ready(emits))
                 metrics.host_syncs += 1
                 if tr is not None:
+                    count_moe(moe)
                     # each live lane verifies k drafts after its pending
                     # token: a segment of k + 1 queries from lens
                     count_attention(lens[act], drafter.k + 1)
@@ -1762,15 +1787,17 @@ class InferenceEngine:
             else:
                 with dev_span("decode", "decode"):
                     (tok_d, lens_d, rem_d, act_d, cache, rng, emits,
-                     acts) = step_fn(self.params, jnp.asarray(tok),
-                                     jnp.asarray(lens), jnp.asarray(rem),
-                                     jnp.asarray(act),
-                                     jnp.asarray(block_tables), cache,
-                                     rng)
+                     acts, moe) = step_fn(self.params, jnp.asarray(tok),
+                                          jnp.asarray(lens),
+                                          jnp.asarray(rem),
+                                          jnp.asarray(act),
+                                          jnp.asarray(block_tables), cache,
+                                          rng)
                     emits = np.asarray(jax.block_until_ready(emits))
                 metrics.host_syncs += 1
                 acts = np.asarray(acts)
                 if tr is not None:
+                    count_moe(moe)
                     count_decode_attention(lens, acts)
                 metrics.steps += steps_per_sync
                 metrics.slot_steps_total += slots * steps_per_sync

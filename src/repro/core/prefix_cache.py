@@ -18,10 +18,10 @@ them.  This module is the host-side index that makes that sharing safe:
 
 Sharing is only sound for layer families whose per-position KV is (a)
 position-stable and (b) written exactly once at prefill.  That rules out
-sliding-window/ring attention (pages are cyclically overwritten),
+sliding-window/ring attention (pages are cyclically overwritten) and
 MLA-latent / recurrent / hybrid families (dense per-slot state, not
-pages), and capacity-routed MoE (token dropping depends on batch
-composition, so suffix-only prefill would change results).
+pages).  Expert layers share: serving routes them dropless, so a
+token's output does not depend on what else is in the batch.
 :func:`shareable` is the per-layer opt-out gate; a model with any
 opted-out layer serves correctly but never shares.
 """
@@ -31,7 +31,7 @@ import heapq
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from repro.configs.base import ATTN, MOE_FFN, ModelConfig
+from repro.configs.base import ATTN, ModelConfig
 
 
 def shareable(cfg: ModelConfig, max_len: int) -> Optional[str]:
@@ -46,10 +46,6 @@ def shareable(cfg: ModelConfig, max_len: int) -> Optional[str]:
             if KV.effective_window(cfg, spec, max_len) is not None:
                 return ("sliding-window attention cyclically overwrites "
                         "its pages (ring), so they cannot be shared")
-            if spec.ffn == MOE_FFN:
-                return ("capacity-routed MoE drops tokens as a function "
-                        "of batch composition; suffix-only prefill would "
-                        "change results")
     return None
 
 

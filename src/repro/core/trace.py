@@ -13,8 +13,9 @@ Three record families share one flat schema (see ``EVENT_SCHEMAS``):
   width bucket and padded lanes, the host/device wall split for the iteration,
   the attention work its dispatches carry (KV tokens read, query-key
   pairs, and the paged kernels' page steps computed and walked, per
-  layer), and gauges (pages in use, host-tier bytes, radix-trie
-  nodes) sampled each step.
+  layer), the routing its expert layers did (token-expert rows, experts
+  hit, the largest expert's rows), and gauges (pages in use, host-tier
+  bytes, radix-trie nodes) sampled each step.
 * **request lifecycle** — ``enqueue → admit → prefill_chunk* → first_token →
   (preempt/offload/restore)* → retire``, the retire stamped with the request's
   structured ``RequestOutcome``.
@@ -47,7 +48,7 @@ import json
 import time
 from collections import deque
 
-TRACE_SCHEMA_VERSION = 3
+TRACE_SCHEMA_VERSION = 4
 
 # Sentinel for schema fields that may be absent (or null) on an event.
 _OPTIONAL = True
@@ -88,6 +89,13 @@ EVENT_SCHEMAS = {
         # the grid's page steps (work items x pages per slot)
         "attn_pages": (_INT, _REQUIRED),
         "attn_page_steps": (_INT, _REQUIRED),
+        # over every forward and expert layer of the iteration's
+        # dispatches, as the device routed them: token-expert assignments
+        # of real tokens, experts given at least one, and the most rows
+        # one expert took in one layer (all 0 for a model without experts)
+        "moe_rows": (_INT, _REQUIRED),
+        "moe_experts_hit": (_INT, _REQUIRED),
+        "moe_rows_max": (_INT, _REQUIRED),
     },
     "span": {
         "name": (_STR, _REQUIRED),          # e.g. decode, packed, chunk, verify
@@ -497,6 +505,9 @@ def to_perfetto_dict(events, dropped=0):
                     "attn_pairs",
                     "attn_pages",
                     "attn_page_steps",
+                    "moe_rows",
+                    "moe_experts_hit",
+                    "moe_rows_max",
                 )
                 if f in ev
             }

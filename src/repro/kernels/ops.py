@@ -220,6 +220,21 @@ def maybe_quant_matmul(x, q, s):
                 QM.quant_matmul(x, q, s, interpret=(mode == "interpret")))
 
 
+def maybe_expert_matmul(lhs, rhs, group_sizes, layer=None):
+    """Grouped matmul of the expert layer: rows of lhs (m, K) sorted by
+    group, times their group's matrix of rhs (G, K, N), or of layer
+    ``layer`` of a whole stack rhs (L, G, K, N).  Returns fp32 (m, N),
+    or None -> caller falls back to ``jax.lax.ragged_dot``."""
+    mode = kernel_mode()
+    if mode == "off":
+        return None
+    from repro.kernels import expert_matmul as EM
+    if not EM.shape_supported(lhs, rhs):
+        return _fallback(EM.KERNEL_NAME, lhs, rhs)
+    return _ran(EM.KERNEL_NAME, EM.expert_matmul(
+        lhs, rhs, group_sizes, layer, interpret=(mode == "interpret")))
+
+
 def maybe_rmsnorm(x, w):
     mode = kernel_mode()
     if mode == "off":

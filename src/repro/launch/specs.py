@@ -153,7 +153,7 @@ def make_target(cfg: ModelConfig, shape_name: str, mesh,
         def prefill_fn(params, tokens, lengths, cache):
             return T.forward_prefill(params, cfg, tokens, lengths, cache,
                                      policy=policy, max_len=max_len,
-                                     last_only=True)
+                                     last_only=True)[:2]
 
         return LoweringTarget(
             fn=prefill_fn,
@@ -164,7 +164,7 @@ def make_target(cfg: ModelConfig, shape_name: str, mesh,
 
     def decode_fn(params, tokens, cache, lengths):
         return T.forward_decode(params, cfg, tokens, cache, lengths,
-                                policy=policy, max_len=max_len)
+                                policy=policy, max_len=max_len)[:2]
 
     return LoweringTarget(
         fn=decode_fn, args=(pstruct, ins["tokens"], cstruct, ins["lengths"]),

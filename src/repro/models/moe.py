@@ -1,9 +1,15 @@
 """Mixture-of-Experts FFN (DeepSeek-V3 / Qwen3-MoE style).
 
-Dispatch is sort-based with static capacity (GShard-style), expressed as an
-expert-batched einsum ``ecd,edf->ecf`` whose expert axis shards over the
-``model`` mesh axis (expert parallelism).  Token gather/scatter around the
-einsum becomes an all-to-all-ish collective pattern under pjit.
+Two dispatches share one router:
+
+* serving (``moe_apply_dropless``): no capacity and no drops.  The
+  token-expert assignments are sorted by expert and each expert matrix
+  is one grouped matrix product over the sorted rows (the Pallas
+  grouped matmul on the chip, ``lax.ragged_dot`` elsewhere), so a
+  token's output is the same whatever else is in the batch.
+* training (``moe_apply_capacity``): sort-based with static capacity
+  (GShard-style), an expert-batched einsum ``ecd,edf->ecf`` whose
+  expert axis shards over the ``model`` mesh axis (expert parallelism).
 
 Router options: softmax top-k (Qwen3-MoE) or sigmoid scores normalized over
 the selected top-k (DeepSeek-V3), plus optional shared experts and the
@@ -44,80 +50,114 @@ def _capacity(num_tokens: int, cfg: ModelConfig) -> int:
     return max(8, -(-c // 8) * 8)  # round up to multiple of 8, >= 8
 
 
-def moe_apply(cfg: ModelConfig, p, x, router_kind: str = "softmax"):
-    """x: (B, S, d) -> (out (B,S,d), aux_loss scalar).
-
-    Two dispatch backends:
-      * capacity (default): GShard-style static capacity + expert-batched
-        einsum — the paper-faithful baseline, sheds overflow tokens.
-      * ragged (``REPRO_PERF_OPTS=moe_ragged``, beyond-paper): TPU-native
-        ``jax.lax.ragged_dot`` grouped matmul over expert-sorted tokens —
-        no capacity, no drops, no padded (E, C, d) gather buffer.
-    """
-    from repro import perf_flags
-    if perf_flags.flag("moe_ragged"):
-        return moe_apply_ragged(cfg, p, x, router_kind)
-    return moe_apply_capacity(cfg, p, x, router_kind)
-
-
 def _route(cfg: ModelConfig, p, xf, router_kind: str):
-    """Shared router: -> (topw (T,k), tope (T,k), aux scalar)."""
-    m = cfg.moe
-    T = xf.shape[0]
-    E, k = m.num_experts, m.top_k
+    """Router: (T, d) -> (top-k weights (T, k) float32, experts (T, k),
+    probabilities the load-balance loss reads (T, E))."""
+    k = cfg.moe.top_k
     scores = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))
     if router_kind == "sigmoid":                     # DeepSeek-V3
         probs = jax.nn.sigmoid(scores)
         topw, tope = jax.lax.top_k(probs, k)
-        topw = topw / (topw.sum(-1, keepdims=True) + 1e-9)
         lb_probs = probs / (probs.sum(-1, keepdims=True) + 1e-9)
     else:                                            # softmax (Qwen3-MoE)
         probs = jax.nn.softmax(scores, axis=-1)
         topw, tope = jax.lax.top_k(probs, k)
-        topw = topw / (topw.sum(-1, keepdims=True) + 1e-9)
         lb_probs = probs
-    onehot = jax.nn.one_hot(tope, E, dtype=jnp.float32)           # (T,k,E)
-    frac_tokens = onehot.sum((0, 1)) / (T * k)
-    aux = m.router_aux_coef * E * jnp.sum(frac_tokens * lb_probs.mean(0))
-    return topw, tope, aux
+    topw = topw / (topw.sum(-1, keepdims=True) + 1e-9)
+    return topw, tope, lb_probs
 
 
-def moe_apply_ragged(cfg: ModelConfig, p, x, router_kind: str = "softmax"):
-    """Grouped-matmul dispatch via jax.lax.ragged_dot (beyond-paper)."""
+def no_counts():
+    """Routing counters of a forward that routed nothing."""
+    return jnp.zeros((3,), jnp.int32)
+
+
+def merge_counts(a, b):
+    """Counters of two dispatches together: rows and experts hit add up,
+    the largest expert's rows is the larger of the two."""
+    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2])])
+
+
+# the expert matrices of a layer, stacked (E, in, out); serving passes
+# them whole over the layers, (L, E, in, out), with the layer's index
+EXPERT_MATS = ("wi", "wg", "wo")
+
+
+def grouped_matmul(lhs, rhs, group_sizes, layer=None):
+    """Rows of ``lhs`` (m, K) sorted by group times their group's matrix
+    of ``rhs`` (G, K, N), in float32; rows past ``sum(group_sizes)``
+    belong to no group and their output is undefined.  With ``layer``,
+    ``rhs`` is the whole stack (L, G, K, N) and layer ``layer``'s
+    matrices are used."""
+    from repro.kernels import ops as kops
+    if layer is not None and rhs.dtype != lhs.dtype:
+        # a cast copies anyway: the layer's matrices alone
+        rhs = jax.lax.dynamic_index_in_dim(rhs, layer, keepdims=False)
+        layer = None
+    rhs = rhs.astype(lhs.dtype)
+    out = kops.maybe_expert_matmul(lhs, rhs, group_sizes, layer)
+    if out is None:
+        if layer is not None:
+            rhs = jax.lax.dynamic_index_in_dim(rhs, layer, keepdims=False)
+        out = jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                 preferred_element_type=jnp.float32)
+    return out
+
+
+def moe_apply_dropless(cfg: ModelConfig, p, x, router_kind: str = "softmax",
+                       valid=None):
+    """The serving dispatch: x (B, S, d) -> (out (B, S, d), counters).
+
+    Every token goes to each of its top-k experts; nothing is dropped, so
+    a row's output does not depend on what else is in the batch.  The
+    token-expert assignments are sorted by expert and each expert matrix
+    is one grouped matrix product over its rows (``grouped_matmul``).
+    ``p`` holds one layer's expert stacks (E, in, out), or the whole
+    stacks (L, E, in, out) and that layer's index ``p["layer"]``.
+    ``valid`` (broadcastable to (B, S)) marks real tokens: the others
+    (padding lanes, inactive slots) are routed to no expert, add no rows
+    and output zero.  ``counters`` is int32 [assignments, experts with at
+    least one, the most one expert took].
+    """
     m = cfg.moe
     B, S, d = x.shape
     T = B * S
     E, k = m.num_experts, m.top_k
     xf = x.reshape(T, d)
-    topw, tope, aux = _route(cfg, p, xf, router_kind)
-
+    topw, tope, _ = _route(cfg, p, xf, router_kind)
+    if valid is not None:
+        keep = jnp.broadcast_to(valid, (B, S)).reshape(T, 1)
+        tope = jnp.where(keep, tope, E)              # E: no expert
     e_flat = tope.reshape(-1)
-    t_flat = jnp.repeat(jnp.arange(T), k)
-    w_flat = topw.reshape(-1)
     order = jnp.argsort(e_flat)
-    st, sw = t_flat[order], w_flat[order]
     sizes = jnp.bincount(e_flat, length=E).astype(jnp.int32)
-
-    rows = xf[st]                                                 # (T*k, d)
-    h = jax.lax.ragged_dot(rows, p["wi"].astype(x.dtype), sizes)
+    rows = xf[order // k]                                         # (T*k, d)
+    layer = p.get("layer")
+    h = grouped_matmul(rows, p["wi"], sizes, layer)
     if cfg.activation in ("swiglu", "geglu"):
-        g = jax.lax.ragged_dot(rows, p["wg"].astype(x.dtype), sizes)
+        g = grouped_matmul(rows, p["wg"], sizes, layer)
         act = jax.nn.silu if cfg.activation == "swiglu" else \
             (lambda t: jax.nn.gelu(t, approximate=True))
         h = act(g) * h
     else:
         h = jax.nn.gelu(h, approximate=True)
-    eo = jax.lax.ragged_dot(h, p["wo"].astype(x.dtype), sizes)    # (T*k, d)
-    eo = eo * sw[:, None].astype(eo.dtype)
-    out = jnp.zeros((T, d), eo.dtype).at[st].add(eo)
+    eo = grouped_matmul(h.astype(x.dtype), p["wo"], sizes, layer)
+    # back to token order: each token's k outputs, weighted and summed;
+    # rows of no expert hold undefined values and are selected away
+    eo = eo[jnp.argsort(order)].reshape(T, k, d)
+    eo = jnp.where((tope < E)[..., None], eo * topw[..., None], 0.0)
+    out = eo.sum(1)
     if "shared" in p:
-        out = out + L.ffn_apply(cfg, p["shared"], xf)
-    return out.reshape(B, S, d), aux
+        out = out + L.ffn_apply(cfg, p["shared"], xf).astype(jnp.float32)
+    counts = jnp.stack([sizes.sum(), (sizes > 0).sum(), sizes.max()])
+    return out.astype(x.dtype).reshape(B, S, d), counts.astype(jnp.int32)
 
 
 def moe_apply_capacity(cfg: ModelConfig, p, x,
                        router_kind: str = "softmax"):
-    """x: (B, S, d) -> (out (B,S,d), aux_loss scalar)."""
+    """The training dispatch: x (B, S, d) -> (out (B,S,d), aux_loss
+    scalar).  Each expert takes at most its capacity of tokens; the
+    overflow is dropped, so a token's output depends on the batch."""
     m = cfg.moe
     B, S, d = x.shape
     T = B * S
@@ -125,17 +165,7 @@ def moe_apply_capacity(cfg: ModelConfig, p, x,
     C = _capacity(T, cfg)
     xf = x.reshape(T, d)
 
-    scores = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    if router_kind == "sigmoid":                     # DeepSeek-V3
-        probs = jax.nn.sigmoid(scores)
-        topw, tope = jax.lax.top_k(probs, k)
-        topw = topw / (topw.sum(-1, keepdims=True) + 1e-9)
-        lb_probs = probs / (probs.sum(-1, keepdims=True) + 1e-9)
-    else:                                            # softmax (Qwen3-MoE)
-        probs = jax.nn.softmax(scores, axis=-1)
-        topw, tope = jax.lax.top_k(probs, k)
-        topw = topw / (topw.sum(-1, keepdims=True) + 1e-9)
-        lb_probs = probs
+    topw, tope, lb_probs = _route(cfg, p, xf, router_kind)
 
     # ---- load-balance aux loss ------------------------------------------
     onehot = jax.nn.one_hot(tope, E, dtype=jnp.float32)           # (T,k,E)

@@ -8,8 +8,13 @@ one pattern), with caches carried as scan xs/ys for prefill/decode.
 Entry points:
     init_params(rng, cfg, policy)
     forward_train(params, cfg, tokens, ...)   -> (logits, aux)
-    forward_prefill(params, cfg, tokens, ...) -> (logits, cache)
-    forward_decode(params, cfg, tokens, ...)  -> (logits, cache)
+    forward_prefill(params, cfg, tokens, ...) -> (logits, cache, moe)
+    forward_decode(params, cfg, tokens, ...)  -> (logits, cache, moe)
+
+Every serving forward (prefill, decode, verify, mixed, packed) returns,
+after its logits and cache, the routing counters of its expert layers:
+int32 [token-expert assignments, experts hit, most rows of one expert],
+zeros for a model with none.
     init_cache(cfg, batch, max_len, dtype)    -> cache pytree
 """
 from __future__ import annotations
@@ -159,7 +164,9 @@ def init_paged_cache(cfg: ModelConfig, *, num_pages: int, page_size: int,
 def layer_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions,
                 cache_pos, cache, mode: str, max_len: int,
                 attend_cache: bool = False, paged=None):
-    """Returns (x, new_cache, aux). cache is None in train mode.
+    """Returns (x, new_cache, aux, moe). cache is None in train mode;
+    moe is the routing counters of an expert layer (``moe.no_counts()``
+    elsewhere and in training).
     attend_cache: prefill continues from a pre-filled cache (prefix
     caching) — queries attend to cache contents, not just in-context k/v.
     paged: {"block_tables": (B, pages), "active": (B,) bool | None} when
@@ -327,19 +334,39 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions,
     x = x + mixer_out
 
     # ----- ffn -------------------------------------------------------------
+    moe = MOE.no_counts()
     if spec.ffn != NO_FFN:
         h2 = L.apply_norm(cfg, p["norm2"], x)
+        kind = "sigmoid" if cfg.mla is not None else "softmax"
         if spec.ffn == DENSE_FFN:
             out = L.ffn_apply(cfg, p["ffn"], h2)
-        else:
-            kind = "sigmoid" if cfg.mla is not None else "softmax"
-            out, moe_aux = MOE.moe_apply(cfg, p["ffn"], h2, kind)
+        elif mode == "train":
+            out, moe_aux = MOE.moe_apply_capacity(cfg, p["ffn"], h2, kind)
             aux = aux + moe_aux
+        else:
+            out, moe = MOE.moe_apply_dropless(
+                cfg, p["ffn"], h2, kind,
+                valid=_real_tokens(mode, cache_pos, paged))
         if cfg.sandwich_norm:
             out = L.apply_norm(cfg, p["norm2_post"], out)
         x = x + out
 
-    return x, new_cache, aux
+    return x, new_cache, aux, moe
+
+
+def _real_tokens(mode: str, cache_pos, paged):
+    """Which rows of a serving forward are real tokens, broadcastable to
+    (B, S), or None for all: packed lanes with a slot, active decode and
+    verify rows, prompt positions inside each prompt's length."""
+    paged = paged or {}
+    if mode == "packed":
+        return (paged["slot_ids"] >= 0)[None, :]
+    if mode == "prefill":
+        return cache_pos >= 0
+    active = paged.get("active")
+    if active is None:
+        return None
+    return active if active.ndim == 2 else active[:, None]
 
 
 # ---------------------------------------------------------------------------
@@ -351,47 +378,71 @@ def _run_stack(cfg, stack: Stack, stack_p, stack_c, x, *, positions,
                cache_pos, mode, max_len, remat, attend_cache=False,
                paged=None):
     has_cache = mode != "train"
+    # serving keeps each expert layer's stacks whole, outside the scan:
+    # the grouped matmul reads the scanned layer's experts in place, where
+    # the scan's slice of a stack would be copied out before the kernel
+    # could read it (MOE.EXPERT_MATS)
+    whole = {}
+    if has_cache:
+        stack_p = list(stack_p)
+        for pi, spec in enumerate(stack.pattern):
+            if spec.ffn == MOE_FFN:
+                ffn = dict(stack_p[pi]["ffn"])
+                whole[pi] = {n: ffn.pop(n) for n in MOE.EXPERT_MATS
+                             if n in ffn}
+                stack_p[pi] = {**stack_p[pi], "ffn": ffn}
+        stack_p = tuple(stack_p)
 
     def body(carry, xs):
-        xx, aux = carry
+        xx, aux, moe = carry
         if has_cache:
-            p_r, c_r = xs
+            p_r, c_r, layer = xs
         else:
             (p_r,) = xs
             c_r = (None,) * len(stack.pattern)
         new_cs = []
         for pi, spec in enumerate(stack.pattern):
-            xx, nc, a = layer_apply(cfg, spec, p_r[pi], xx,
-                                    positions=positions, cache_pos=cache_pos,
-                                    cache=c_r[pi], mode=mode, max_len=max_len,
-                                    attend_cache=attend_cache, paged=paged)
+            p_l = p_r[pi]
+            if pi in whole:
+                p_l = {**p_l, "ffn": {**p_l["ffn"], **whole[pi],
+                                      "layer": layer}}
+            xx, nc, a, mc = layer_apply(
+                cfg, spec, p_l, xx, positions=positions,
+                cache_pos=cache_pos, cache=c_r[pi], mode=mode,
+                max_len=max_len, attend_cache=attend_cache, paged=paged)
             new_cs.append(nc)
             aux = aux + a
-        return (xx, aux), (tuple(new_cs) if has_cache else None)
+            moe = MOE.merge_counts(moe, mc)
+        return (xx, aux, moe), (tuple(new_cs) if has_cache else None)
 
     if remat and mode == "train":
         body = jax.checkpoint(body)
 
-    xs = (stack_p, stack_c) if has_cache else (stack_p,)
-    (x, aux), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                       xs)
-    return x, new_cache, aux
+    xs = ((stack_p, stack_c, jnp.arange(stack.repeats)) if has_cache
+          else (stack_p,))
+    (x, aux, moe), new_cache = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), MOE.no_counts()), xs)
+    return x, new_cache, aux, moe
 
 
 def _run_all(cfg, params, x, *, positions, cache_pos, cache, mode, max_len,
              remat=False, attend_cache=False, paged=None):
+    """Every stack in turn -> (x, new cache, aux loss, routing counters
+    summed over the expert layers)."""
     new_layers = []
     aux = jnp.zeros((), jnp.float32)
+    moe = MOE.no_counts()
     for si, stack in enumerate(cfg.stacks):
         sc = cache["layers"][si] if cache is not None else None
-        x, nc, a = _run_stack(cfg, stack, params["stacks"][si], sc, x,
-                              positions=positions, cache_pos=cache_pos,
-                              mode=mode, max_len=max_len, remat=remat,
-                              attend_cache=attend_cache, paged=paged)
+        x, nc, a, mc = _run_stack(cfg, stack, params["stacks"][si], sc, x,
+                                  positions=positions, cache_pos=cache_pos,
+                                  mode=mode, max_len=max_len, remat=remat,
+                                  attend_cache=attend_cache, paged=paged)
         new_layers.append(nc)
         aux = aux + a
+        moe = MOE.merge_counts(moe, mc)
     new_cache = {"layers": tuple(new_layers)} if cache is not None else None
-    return x, new_cache, aux
+    return x, new_cache, aux, moe
 
 
 # ---------------------------------------------------------------------------
@@ -451,8 +502,9 @@ def forward_train(params, cfg: ModelConfig, tokens, *, prefix_embeds=None,
                            else 0)
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     x = _embed(cfg, params, tokens, prefix_embeds, positions, policy)
-    x, _, aux = _run_all(cfg, params, x, positions=positions, cache_pos=None,
-                         cache=None, mode="train", max_len=S, remat=remat)
+    x, _, aux, _ = _run_all(cfg, params, x, positions=positions,
+                            cache_pos=None, cache=None, mode="train",
+                            max_len=S, remat=remat)
     h_final = L.apply_norm(cfg, params["final_norm"], x)
     logits = policy.output_cast(L.unembed(cfg, params, h_final))
     aux_d = {"moe_aux": aux}
@@ -471,10 +523,10 @@ def _mtp_forward(params, cfg, h, tokens, positions, policy):
         [L.apply_norm(cfg, p["norm_h"], h_cur),
          L.apply_norm(cfg, p["norm_e"], emb_next)], axis=-1)
     x = merged @ p["proj"].astype(h.dtype)
-    x, _, _ = layer_apply(cfg, LayerSpec(ATTN, DENSE_FFN), p["layer"], x,
-                          positions=positions[:, :-1], cache_pos=None,
-                          cache=None, mode="train",
-                          max_len=positions.shape[1])
+    x, _, _, _ = layer_apply(cfg, LayerSpec(ATTN, DENSE_FFN), p["layer"], x,
+                             positions=positions[:, :-1], cache_pos=None,
+                             cache=None, mode="train",
+                             max_len=positions.shape[1])
     h_final = L.apply_norm(cfg, params["final_norm"], x)
     return policy.output_cast(L.unembed(cfg, params, h_final))
 
@@ -491,9 +543,9 @@ def forward_prefill(params, cfg: ModelConfig, tokens, prompt_lengths, cache,
     offline" applied to a shared prompt's KV); it may be a static int or
     a per-row (B,) array (paged admission, where each request resumes
     from its own matched prefix length).  Returns
-    (logits (B,S,V), cache) — or (B,1,V) when ``last_only`` (production
-    serving: unembed only the sampled position, which for a 262k vocab
-    saves terabytes of logits at 32k prefill).
+    (logits (B,S,V), cache, moe) — or (B,1,V) logits when ``last_only``
+    (production serving: unembed only the sampled position, which for a
+    262k vocab saves terabytes of logits at 32k prefill).
     """
     B = tokens.shape[0]
     S = tokens.shape[1] + (prefix_embeds.shape[1] if prefix_embeds is not None
@@ -505,33 +557,34 @@ def forward_prefill(params, cfg: ModelConfig, tokens, prompt_lengths, cache,
     cache_pos = jnp.where(positions < start + prompt_lengths[:, None],
                           positions, -1)
     x = _embed(cfg, params, tokens, prefix_embeds, positions, policy)
-    x, cache, _ = _run_all(cfg, params, x, positions=positions,
-                           cache_pos=cache_pos, cache=cache, mode="prefill",
-                           max_len=max_len, attend_cache=attend,
-                           paged=paged)
+    x, cache, _, moe = _run_all(cfg, params, x, positions=positions,
+                                cache_pos=cache_pos, cache=cache,
+                                mode="prefill", max_len=max_len,
+                                attend_cache=attend, paged=paged)
     if last_only:
         x = jnp.take_along_axis(
             x, (prompt_lengths - 1)[:, None, None].astype(jnp.int32), axis=1)
     h_final = L.apply_norm(cfg, params["final_norm"], x)
     logits = policy.output_cast(L.unembed(cfg, params, h_final))
-    return logits, cache
+    return logits, cache, moe
 
 
 def forward_decode(params, cfg: ModelConfig, tokens, cache, lengths, *,
                    policy: Policy = FP32, max_len: Optional[int] = None,
                    paged=None):
     """One new token per slot. tokens: (B,1); lengths: (B,) current context
-    length (the new token's absolute position). Returns (logits, cache)."""
+    length (the new token's absolute position). Returns (logits, cache,
+    moe)."""
     B = tokens.shape[0]
     max_len = max_len or _cache_max_len(cfg, cache)
     positions = lengths[:, None]
     x = _embed(cfg, params, tokens, None, positions, policy)
-    x, cache, _ = _run_all(cfg, params, x, positions=positions,
-                           cache_pos=None, cache=cache, mode="decode",
-                           max_len=max_len, paged=paged)
+    x, cache, _, moe = _run_all(cfg, params, x, positions=positions,
+                                cache_pos=None, cache=cache, mode="decode",
+                                max_len=max_len, paged=paged)
     h_final = L.apply_norm(cfg, params["final_norm"], x)
     logits = policy.output_cast(L.unembed(cfg, params, h_final))
-    return logits, cache
+    return logits, cache, moe
 
 
 def forward_verify(params, cfg: ModelConfig, tokens, cache, lengths, *,
@@ -546,7 +599,7 @@ def forward_verify(params, cfg: ModelConfig, tokens, cache, lengths, *,
     writes the whole window's K/V into its paged pool (masked by
     ``paged["active"]``), and each query position attends causally via
     the stored positions — including the window's own earlier tokens.
-    Returns (logits (B, K+1, V), cache); logits[:, j] is the target
+    Returns (logits (B, K+1, V), cache, moe); logits[:, j] is the target
     distribution for the token following tokens[:, j], so the rejection
     sampler (``sampling.speculative_verify``) reads acceptance straight
     off this one pass.  The caller must rewind rejected entries
@@ -560,12 +613,12 @@ def forward_verify(params, cfg: ModelConfig, tokens, cache, lengths, *,
     max_len = max_len or _cache_max_len(cfg, cache)
     positions = lengths[:, None] + jnp.arange(K1)[None, :]
     x = _embed(cfg, params, tokens, None, positions, policy)
-    x, cache, _ = _run_all(cfg, params, x, positions=positions,
-                           cache_pos=None, cache=cache, mode="verify",
-                           max_len=max_len, paged=paged)
+    x, cache, _, moe = _run_all(cfg, params, x, positions=positions,
+                                cache_pos=None, cache=cache, mode="verify",
+                                max_len=max_len, paged=paged)
     h_final = L.apply_norm(cfg, params["final_norm"], x)
     logits = policy.output_cast(L.unembed(cfg, params, h_final))
-    return logits, cache
+    return logits, cache, moe
 
 
 def forward_mixed(params, cfg: ModelConfig, tokens, cache, row_start, n_q, *,
@@ -588,7 +641,8 @@ def forward_mixed(params, cfg: ModelConfig, tokens, cache, row_start, n_q, *,
     tokens (stored positions make the intra-window causal mask exact),
     so any chunk boundary is legal, page-aligned or not.
 
-    Returns (logits (B, 1, V) at each row's LAST real token, cache):
+    Returns (logits (B, 1, V) at each row's LAST real token, cache,
+    moe):
     for decode rows that is the next-token distribution, for a prompt's
     final chunk it seeds sampling; other chunk rows' logits are
     computed-and-discarded by the caller.  Padding lanes carry -1
@@ -607,16 +661,16 @@ def forward_mixed(params, cfg: ModelConfig, tokens, cache, row_start, n_q, *,
     paged = dict(paged or {})
     paged["active"] = valid
     x = _embed(cfg, params, tokens, None, positions, policy)
-    x, cache, _ = _run_all(cfg, params, x, positions=positions,
-                           cache_pos=None, cache=cache, mode="verify",
-                           max_len=max_len, paged=paged)
+    x, cache, _, moe = _run_all(cfg, params, x, positions=positions,
+                                cache_pos=None, cache=cache, mode="verify",
+                                max_len=max_len, paged=paged)
     # unembed only each row's sampled position (last real token) — the
     # same logits economy as forward_prefill(last_only=True)
     idx = jnp.maximum(n_q - 1, 0)
     x = jnp.take_along_axis(x, idx[:, None, None].astype(jnp.int32), axis=1)
     h_final = L.apply_norm(cfg, params["final_norm"], x)
     logits = policy.output_cast(L.unembed(cfg, params, h_final))
-    return logits, cache
+    return logits, cache, moe
 
 
 def forward_packed(params, cfg: ModelConfig, tokens, cache, slot_ids,
@@ -644,7 +698,7 @@ def forward_packed(params, cfg: ModelConfig, tokens, cache, slot_ids,
     dispatch exactly as on the bucketed path), and each lane attends its
     slot's whole paged history under its own causal mask.
 
-    Returns (logits (1, S, V) at each segment's last token, cache) —
+    Returns (logits (1, S, V) at each segment's last token, cache, moe) —
     decode segments read their next-token distribution, final chunks
     seed sampling, earlier chunks are computed-and-discarded.  Gated
     like verify/mixed to paged pure-attention families.
@@ -654,16 +708,16 @@ def forward_packed(params, cfg: ModelConfig, tokens, cache, slot_ids,
     paged = dict(paged or {})
     paged["slot_ids"] = slot_ids
     x = _embed(cfg, params, tokens, None, pos2, policy)
-    x, cache, _ = _run_all(cfg, params, x, positions=pos2, cache_pos=None,
-                           cache=cache, mode="packed", max_len=max_len,
-                           paged=paged)
+    x, cache, _, moe = _run_all(cfg, params, x, positions=pos2,
+                                cache_pos=None, cache=cache, mode="packed",
+                                max_len=max_len, paged=paged)
     # unembed only the sampled positions (forward_mixed's logits economy,
     # one gather for all segments)
     x = jnp.take_along_axis(x, seg_last[None, :, None].astype(jnp.int32),
                             axis=1)
     h_final = L.apply_norm(cfg, params["final_norm"], x)
     logits = policy.output_cast(L.unembed(cfg, params, h_final))
-    return logits, cache
+    return logits, cache, moe
 
 
 def _cache_max_len(cfg: ModelConfig, cache) -> int:

@@ -27,7 +27,8 @@ def _toks(cfg, rng, B, S):
 
 def _decode_fn(cfg, params):
     def step(tok, cache, lens):
-        return T.forward_decode(params, cfg, tok, cache, lens, policy=FP32)
+        return T.forward_decode(params, cfg, tok, cache, lens,
+                                policy=FP32)[:2]
     return jax.jit(step)
 
 
@@ -42,7 +43,7 @@ def test_decode_matches_teacher_forcing(arch, rng, key):
 
     cache = T.init_cache(cfg, B, 64, jnp.float32)
     lens = jnp.full((B,), 4, jnp.int32)
-    lg, cache = T.forward_prefill(params, cfg, toks[:, :4], lens, cache,
+    lg, cache, _ = T.forward_prefill(params, cfg, toks[:, :4], lens, cache,
                                   policy=FP32)
     np.testing.assert_allclose(np.asarray(lg), np.asarray(full[:, :4]),
                                rtol=3e-4, atol=3e-4)
@@ -66,7 +67,7 @@ def test_ring_cache_eviction_matches_window(arch, rng, key):
     full, _ = T.forward_train(params, cfg, toks, policy=FP32, remat=False)
     cache = T.init_cache(cfg, B, S, jnp.float32)
     lens = jnp.full((B,), 1, jnp.int32)
-    _, cache = T.forward_prefill(params, cfg, toks[:, :1], lens, cache,
+    _, cache, _ = T.forward_prefill(params, cfg, toks[:, :1], lens, cache,
                                  policy=FP32)
     step = _decode_fn(cfg, params)
     for t in range(1, S):
@@ -89,22 +90,22 @@ def test_ragged_prefill_matches_per_row(seed):
     toks = jnp.asarray(rng.integers(4, cfg.vocab_size, size=(B, S)),
                        jnp.int32)
     cache = T.init_cache(cfg, B, 32, jnp.float32)
-    lg, cache = T.forward_prefill(params, cfg, toks,
+    lg, cache, _ = T.forward_prefill(params, cfg, toks,
                                   jnp.asarray(lens, jnp.int32), cache,
                                   policy=FP32)
-    nxt, cache2 = T.forward_decode(
+    nxt, cache2, _ = T.forward_decode(
         params, cfg, toks[:, :1], cache, jnp.asarray(lens, jnp.int32),
         policy=FP32)
     for b in range(int(B)):
         lb = int(lens[b])
         c1 = T.init_cache(cfg, 1, 32, jnp.float32)
-        lg1, c1 = T.forward_prefill(params, cfg, toks[b:b+1, :lb],
+        lg1, c1, _ = T.forward_prefill(params, cfg, toks[b:b+1, :lb],
                                     jnp.asarray([lb], jnp.int32), c1,
                                     policy=FP32)
         np.testing.assert_allclose(np.asarray(lg[b, :lb]),
                                    np.asarray(lg1[0]),
                                    rtol=3e-4, atol=3e-4)
-        n1, _ = T.forward_decode(params, cfg, toks[b:b+1, :1], c1,
+        n1, _, _ = T.forward_decode(params, cfg, toks[b:b+1, :1], c1,
                                  jnp.asarray([lb], jnp.int32), policy=FP32)
         np.testing.assert_allclose(np.asarray(nxt[b]), np.asarray(n1[0]),
                                    rtol=3e-4, atol=3e-4)
@@ -134,7 +135,7 @@ def test_long_context_override_ring_bounded(rng, key):
     toks = _toks(cfg, rng, B, S)
     cache = T.init_cache(cfg, B, 128, jnp.float32)   # 128 > native 48
     lens = jnp.full((B,), 1, jnp.int32)
-    _, cache = T.forward_prefill(params, cfg, toks[:, :1], lens, cache,
+    _, cache, _ = T.forward_prefill(params, cfg, toks[:, :1], lens, cache,
                                  policy=FP32, max_len=128)
     step = _decode_fn(cfg, params)
     outs = []
@@ -161,9 +162,9 @@ def test_prefill_last_only_matches_full(rng, key):
     toks = _toks(cfg, rng, B, S)
     lens = jnp.asarray([S, S - 3], jnp.int32)
     c1 = T.init_cache(cfg, B, 32, jnp.float32)
-    lg_all, _ = T.forward_prefill(params, cfg, toks, lens, c1, policy=FP32)
+    lg_all, _, _ = T.forward_prefill(params, cfg, toks, lens, c1, policy=FP32)
     c2 = T.init_cache(cfg, B, 32, jnp.float32)
-    lg_last, _ = T.forward_prefill(params, cfg, toks, lens, c2, policy=FP32,
+    lg_last, _, _ = T.forward_prefill(params, cfg, toks, lens, c2, policy=FP32,
                                    last_only=True)
     picked = np.stack([np.asarray(lg_all)[b, int(lens[b]) - 1]
                        for b in range(B)])

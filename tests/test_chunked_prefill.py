@@ -347,11 +347,11 @@ def test_forward_mixed_matches_decode_and_prefill(rng):
     cache = fresh()
     tok0 = jnp.asarray([toks[0] + [0] * 7, toks[1] + [0] * 3], jnp.int32)
     plens = jnp.asarray([9, 13], jnp.int32)
-    lg_p, cache = T.forward_prefill(
+    lg_p, cache, _ = T.forward_prefill(
         params, cfg, tok0, plens, cache, policy=FP32, max_len=48,
         last_only=True, paged={**paged, "active": jnp.ones((2,), bool)})
     nxt0 = int(jnp.argmax(lg_p[0, 0]))
-    lg_d, cache = T.forward_decode(
+    lg_d, cache, _ = T.forward_decode(
         params, cfg, jnp.asarray([[nxt0], [0]], jnp.int32), cache,
         jnp.asarray([9, 13], jnp.int32), policy=FP32, max_len=48,
         paged={**paged, "active": jnp.asarray([True, False])})
@@ -359,12 +359,12 @@ def test_forward_mixed_matches_decode_and_prefill(rng):
     # mixed: slot 0 already prefilled -> decode row; slot 1 prefills its
     # last 5 tokens as a chunk (first 8 pre-written by a prefix call)
     cache2 = fresh()
-    _, cache2 = T.forward_prefill(
+    _, cache2, _ = T.forward_prefill(
         params, cfg, tok0, plens, cache2, policy=FP32, max_len=48,
         last_only=True, paged={**paged, "active": jnp.ones((2,), bool)})
     from repro.core import kv_cache as KV
     cache2 = KV.reset_pages_all(cache2, np.asarray(bt[1, :3]))
-    _, cache2 = T.forward_prefill(
+    _, cache2, _ = T.forward_prefill(
         params, cfg, jnp.asarray([toks[1][:8] + [0] * 5], jnp.int32),
         jnp.asarray([8], jnp.int32),
         KV.slot_view(cache2, 1), policy=FP32, max_len=48,
@@ -375,7 +375,7 @@ def test_forward_mixed_matches_decode_and_prefill(rng):
     mixed_toks = np.zeros((slots, W), np.int32)
     mixed_toks[0, 0] = nxt0
     mixed_toks[1, :5] = toks[1][8:]
-    lg_m, _ = T.forward_mixed(
+    lg_m, _, _ = T.forward_mixed(
         params, cfg, jnp.asarray(mixed_toks), cache2,
         jnp.asarray([9, 8], jnp.int32), jnp.asarray([1, 5], jnp.int32),
         policy=FP32, max_len=48, paged=paged)

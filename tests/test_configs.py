@@ -15,6 +15,9 @@ EXPECT = {
     "internvl2-1b": dict(L=24, d=896, H=14, kv=2, ff=4864, V=151655),
     "qwen3-moe-235b-a22b": dict(L=94, d=4096, H=64, kv=4, V=151936),
     "gemma2-2b": dict(L=26, d=2304, H=8, kv=4, ff=9216, V=256000),
+    "qwen3-30b-a3b": dict(L=48, d=2048, H=32, kv=4, ff=6144, V=151936),
+    "qwen3-30b-a3b-stage6": dict(L=6, d=2048, H=32, kv=4, ff=6144,
+                                 V=151936),
 }
 
 
@@ -44,6 +47,28 @@ def test_moe_configs():
     qw = get_config("qwen3-moe-235b-a22b")
     assert qw.moe.num_experts == 128 and qw.moe.top_k == 8
     assert all(s.pattern[0].ffn == MOE_FFN for s in qw.stacks)
+
+
+def test_qwen3_30b_a3b_and_its_stage():
+    """The published Qwen3-30B-A3B: 128 experts of 768, top 8, no shared
+    expert, qk-norm, untied; 30.5B parameters, 3.3B active.  The stage
+    is the same widths at 6 layers."""
+    full, stage = get_config("qwen3-30b-a3b"), get_config(
+        "qwen3-30b-a3b-stage6")
+    for cfg in (full, stage):
+        m = cfg.moe
+        assert (m.num_experts, m.top_k, m.num_shared_experts,
+                m.d_ff_expert) == (128, 8, 0, 768)
+        assert cfg.qk_norm and not cfg.tie_embeddings
+        assert cfg.rope_theta == 1e6 and cfg.resolved_head_dim == 128
+        assert cfg.long_context_override is None
+        assert all(s.pattern[0].ffn == MOE_FFN for s in cfg.stacks)
+    assert 3.0e10 < full.param_counts()["total"] < 3.1e10
+    assert 3.2e9 < full.param_counts()["active"] < 3.4e9
+    assert 4.3e9 < stage.param_counts()["total"] < 4.4e9
+    r = get_reduced("qwen3-30b-a3b")
+    assert r.num_layers == 2 and r.moe.num_experts >= 8 \
+        and r.moe.top_k == 2
 
 
 def test_param_counts_scale():

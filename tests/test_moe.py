@@ -1,4 +1,5 @@
-"""MoE dispatch: sort-based capacity routing vs a dense per-token oracle."""
+"""MoE dispatch: sort-based capacity routing (training) and the dropless
+grouped dispatch (serving) vs a dense per-token oracle."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,7 +48,7 @@ def test_moe_matches_dense_oracle(arch, kind, key, rng):
         d_ff_expert=cfg.moe.d_ff_expert, capacity_factor=8.0))
     p = MOE.moe_init(key, cfg)
     x = jnp.asarray(rng.normal(size=(2, 16, cfg.d_model)), jnp.float32) * 0.3
-    out, aux = MOE.moe_apply(cfg, p, x, kind)
+    out, aux = MOE.moe_apply_capacity(cfg, p, x, kind)
     ref = _oracle(cfg, p, x, kind)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=5e-4, atol=5e-4)
@@ -62,7 +63,7 @@ def test_moe_capacity_drops_are_bounded(key, rng):
                                     capacity_factor=0.5))
     p = MOE.moe_init(key, cfg)
     x = jnp.asarray(rng.normal(size=(4, 32, cfg.d_model)), jnp.float32)
-    out, aux = MOE.moe_apply(cfg, p, x, "softmax")
+    out, aux = MOE.moe_apply_capacity(cfg, p, x, "softmax")
     assert bool(jnp.isfinite(out).all())
 
 
@@ -70,31 +71,32 @@ def test_moe_aux_balanced_router_lower_than_collapsed(key, rng):
     cfg = get_reduced("qwen3-moe-235b-a22b")
     p = MOE.moe_init(key, cfg)
     x = jnp.asarray(rng.normal(size=(2, 64, cfg.d_model)), jnp.float32)
-    _, aux_rand = MOE.moe_apply(cfg, p, x, "softmax")
+    _, aux_rand = MOE.moe_apply_capacity(cfg, p, x, "softmax")
     # collapse router to a single expert
     p2 = dict(p)
     bias = jnp.zeros_like(p["router"]).at[:, 0].set(50.0)
     p2["router"] = p["router"] * 0.0 + bias
-    _, aux_coll = MOE.moe_apply(cfg, p2, x, "softmax")
+    _, aux_coll = MOE.moe_apply_capacity(cfg, p2, x, "softmax")
     assert float(aux_coll) > float(aux_rand)
 
 
 @pytest.mark.parametrize("kind", ["softmax", "sigmoid"])
 def test_moe_ragged_matches_capacity(kind, key, rng):
-    """Beyond-paper ragged_dot dispatch == capacity dispatch when capacity
-    is generous (no drops)."""
+    """The dropless grouped dispatch (``lax.ragged_dot`` off the chip) ==
+    capacity dispatch when capacity is generous (no drops), and its
+    gradient reaches the expert weights."""
     cfg = get_reduced("qwen3-moe-235b-a22b")
     cfg = cfg.replace(moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=64,
                                     capacity_factor=8.0))
     p = MOE.moe_init(key, cfg)
     x = jnp.asarray(rng.normal(size=(2, 24, cfg.d_model)),
                     jnp.float32) * 0.3
-    out_r, aux_r = MOE.moe_apply_ragged(cfg, p, x, kind)
-    out_c, aux_c = MOE.moe_apply_capacity(cfg, p, x, kind)
+    out_r, counts = MOE.moe_apply_dropless(cfg, p, x, kind)
+    out_c, _ = MOE.moe_apply_capacity(cfg, p, x, kind)
     np.testing.assert_allclose(np.asarray(out_r), np.asarray(out_c),
                                rtol=2e-5, atol=2e-5)
-    assert abs(float(aux_r) - float(aux_c)) < 1e-6
-    g = jax.grad(lambda q: MOE.moe_apply_ragged(cfg, q, x, kind)[0].sum())(p)
+    assert int(counts[0]) == 2 * 24 * 2
+    g = jax.grad(lambda q: MOE.moe_apply_dropless(cfg, q, x, kind)[0].sum())(p)
     assert float(jnp.abs(g["wi"]).sum()) > 0
 
 
@@ -104,7 +106,7 @@ def test_moe_grad_flows(key, rng):
     x = jnp.asarray(rng.normal(size=(1, 8, cfg.d_model)), jnp.float32)
 
     def loss(p):
-        out, aux = MOE.moe_apply(cfg, p, x, "softmax")
+        out, aux = MOE.moe_apply_capacity(cfg, p, x, "softmax")
         return jnp.sum(out ** 2) + aux
 
     g = jax.grad(loss)(p)

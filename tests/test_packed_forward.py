@@ -241,11 +241,11 @@ def test_forward_packed_matches_decode_and_prefill(rng):
     cache = fresh()
     tok0 = jnp.asarray([toks[0] + [0] * 7, toks[1] + [0] * 3], jnp.int32)
     plens = jnp.asarray([9, 13], jnp.int32)
-    lg_p, cache = T.forward_prefill(
+    lg_p, cache, _ = T.forward_prefill(
         params, cfg, tok0, plens, cache, policy=FP32, max_len=48,
         last_only=True, paged={**paged, "active": jnp.ones((2,), bool)})
     nxt0 = int(jnp.argmax(lg_p[0, 0]))
-    lg_d, cache = T.forward_decode(
+    lg_d, cache, _ = T.forward_decode(
         params, cfg, jnp.asarray([[nxt0], [0]], jnp.int32), cache,
         jnp.asarray([9, 13], jnp.int32), policy=FP32, max_len=48,
         paged={**paged, "active": jnp.asarray([True, False])})
@@ -253,11 +253,11 @@ def test_forward_packed_matches_decode_and_prefill(rng):
     # packed: slot 0 decode lane + slot 1's final 5-token chunk, one
     # (1, 8) stream (first 8 of slot 1 pre-written by a prefix call)
     cache2 = fresh()
-    _, cache2 = T.forward_prefill(
+    _, cache2, _ = T.forward_prefill(
         params, cfg, tok0, plens, cache2, policy=FP32, max_len=48,
         last_only=True, paged={**paged, "active": jnp.ones((2,), bool)})
     cache2 = KV.reset_pages_all(cache2, np.asarray(bt[1, :3]))
-    _, cache2 = T.forward_prefill(
+    _, cache2, _ = T.forward_prefill(
         params, cfg, jnp.asarray([toks[1][:8] + [0] * 5], jnp.int32),
         jnp.asarray([8], jnp.int32), KV.slot_view(cache2, 1), policy=FP32,
         max_len=48, last_only=True,
@@ -269,7 +269,7 @@ def test_forward_packed_matches_decode_and_prefill(rng):
     slot_ids = np.asarray([0, 1, 1, 1, 1, 1, -1, -1], np.int32)
     positions = np.asarray([9, 8, 9, 10, 11, 12, -1, -1], np.int32)
     seg_last = np.asarray([0, 5], np.int32)
-    lg_pk, _ = T.forward_packed(
+    lg_pk, _, _ = T.forward_packed(
         params, cfg, jnp.asarray(stream[None, :]), cache2,
         jnp.asarray(slot_ids), jnp.asarray(positions),
         jnp.asarray(seg_last), policy=FP32, max_len=48, paged=paged)

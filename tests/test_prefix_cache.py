@@ -463,13 +463,13 @@ def test_dense_resume_prefill_matches_full(arch, rng):
     toks = jnp.asarray(rng.integers(4, min(cfg.vocab_size, 400),
                                     size=(B, S)), jnp.int32)
     c_full = T.init_cache(cfg, B, 32, jnp.float32)
-    lg_full, _ = T.forward_prefill(params, cfg, toks,
+    lg_full, _, _ = T.forward_prefill(params, cfg, toks,
                                    jnp.full((B,), S, jnp.int32), c_full,
                                    policy=FP32)
     c = T.init_cache(cfg, B, 32, jnp.float32)
-    _, c = T.forward_prefill(params, cfg, toks[:, :cut],
+    _, c, _ = T.forward_prefill(params, cfg, toks[:, :cut],
                              jnp.full((B,), cut, jnp.int32), c, policy=FP32)
-    lg2, _ = T.forward_prefill(params, cfg, toks[:, cut:],
+    lg2, _, _ = T.forward_prefill(params, cfg, toks[:, cut:],
                                jnp.full((B,), S - cut, jnp.int32), c,
                                policy=FP32, start=cut)
     np.testing.assert_allclose(np.asarray(lg2), np.asarray(lg_full[:, cut:]),

@@ -70,13 +70,13 @@ def test_smoke_serve_roundtrip(arch, key):
                               prefix_embeds=pre, policy=FP32, remat=False)
     cache = T.init_cache(cfg, B, 64, jnp.float32)
     lengths = jnp.full((B,), S_tot, jnp.int32)
-    lg, cache = T.forward_prefill(params, cfg, batch["tokens"], lengths,
+    lg, cache, _ = T.forward_prefill(params, cfg, batch["tokens"], lengths,
                                   cache, prefix_embeds=pre, policy=FP32)
     np.testing.assert_allclose(np.asarray(lg), np.asarray(full),
                                rtol=2e-4, atol=2e-4)
     tok1 = (batch["tokens"][:, :1])
     for i in range(2):
-        lg1, cache = T.forward_decode(params, cfg, tok1, cache,
+        lg1, cache, _ = T.forward_decode(params, cfg, tok1, cache,
                                       lengths + i, policy=FP32)
         assert not bool(jnp.isnan(lg1).any())
         assert lg1.shape[0] == B and lg1.shape[1] == 1

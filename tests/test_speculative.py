@@ -490,7 +490,7 @@ def _paged_two_slots(cfg, params, rng, max_len=64, page_size=8):
     view = KV.slot_view(cache, slots)
     paged = {"block_tables": jnp.asarray(bt),
              "active": jnp.ones((slots,), bool)}
-    _, view = T.forward_prefill(params, cfg, jnp.asarray(prompt),
+    _, view, _ = T.forward_prefill(params, cfg, jnp.asarray(prompt),
                                 jnp.asarray(lens), view, policy=FP32,
                                 max_len=max_len, last_only=True,
                                 paged=paged)
@@ -508,12 +508,12 @@ def test_forward_verify_matches_sequential_decode(rng):
     seq = []
     c1 = cache
     for j in range(K1):
-        lg, c1 = T.forward_decode(params, cfg, jnp.asarray(toks[:, j:j + 1]),
-                                  c1, jnp.asarray(lens + j), policy=FP32,
-                                  max_len=64, paged=paged)
+        lg, c1, _ = T.forward_decode(
+            params, cfg, jnp.asarray(toks[:, j:j + 1]), c1,
+            jnp.asarray(lens + j), policy=FP32, max_len=64, paged=paged)
         seq.append(np.asarray(lg[:, 0]))
     seq = np.stack(seq, axis=1)
-    vl, c2 = T.forward_verify(params, cfg, jnp.asarray(toks), cache,
+    vl, c2, _ = T.forward_verify(params, cfg, jnp.asarray(toks), cache,
                               jnp.asarray(lens), policy=FP32, max_len=64,
                               paged=paged)
     np.testing.assert_array_equal(np.asarray(vl), seq)
@@ -532,13 +532,13 @@ def test_forward_verify_kernel_interpret_matches_fallback():
     toks = np.asarray(np.random.default_rng(6).integers(4, 400,
                                                         size=(2, 3)),
                       np.int32)
-    base, _ = T.forward_verify(params, cfg, jnp.asarray(toks), cache,
+    base, _, _ = T.forward_verify(params, cfg, jnp.asarray(toks), cache,
                                jnp.asarray(lens), policy=FP32, max_len=64,
                                paged=paged)
     with KOPS.kernel_mode_ctx("interpret"):
         cache3, paged3, lens3 = _paged_two_slots(cfg, params,
                                                  np.random.default_rng(5))
-        kout, _ = T.forward_verify(params, cfg, jnp.asarray(toks), cache3,
+        kout, _, _ = T.forward_verify(params, cfg, jnp.asarray(toks), cache3,
                                    jnp.asarray(lens3), policy=FP32,
                                    max_len=64, paged=paged3)
     np.testing.assert_allclose(np.asarray(kout), np.asarray(base),

@@ -3,8 +3,9 @@
 The TPU compiler is installed with JAX, and it compiles for a chip that
 is described rather than attached.  These tests compile every kernel of
 the continuous serving path at the widths of the models it serves —
-qwen3-4b (Hq 32, Hkv 8, head_dim 128) and unimo-text (Hq = Hkv = 16,
-head_dim 64) — with bf16 and int8 page pools, and check that the
+qwen3-4b (Hq 32, Hkv 8, head_dim 128), qwen3-30b-a3b (Hq 32, Hkv 4,
+head_dim 128, and its grouped expert matmul) and unimo-text (Hq = Hkv =
+16, head_dim 64) — with bf16 and int8 page pools, and check that the
 compiled program holds the Pallas kernel (``tpu_custom_call``).  They
 catch what interpret mode cannot: blocks that break the TPU tiling
 rule, unaligned slices, kernels that use too much fast memory.  A
@@ -21,11 +22,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import decode_attention as DA
+from repro.kernels import expert_matmul as EM
 from repro.kernels import flash_attention as FA
 from repro.kernels import quant_matmul as QM
 
 # (Hq, Hkv, head_dim)
-WIDTHS = {"qwen3-4b": (32, 8, 128), "unimo-text": (16, 16, 64)}
+WIDTHS = {"qwen3-4b": (32, 8, 128), "qwen3-30b-a3b": (32, 4, 128),
+          "unimo-text": (16, 16, 64)}
 # serving geometry of the smoke: 4 slots, 512-token context, 16-token
 # pages, a 256-token packed stream
 SLOTS, PAGE, PAGES_PER_SLOT, STREAM = 4, 16, 32, 256
@@ -150,6 +153,31 @@ def test_quant_matmul_compiles(one_chip, M, K, N):
                         ((M, K), jnp.bfloat16), ((K, N), jnp.int8),
                         ((N,), jnp.float32))
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("rows", [64, 512, 2048])
+@pytest.mark.parametrize("K,N", [(2048, 768), (768, 2048)])
+def test_expert_matmul_compiles(one_chip, rows, K, N):
+    """qwen3-30b-a3b's 128 experts (d 2048, width 768) against the
+    token-expert rows of the smallest packed stream (8 tokens x top 8), a
+    64-slot decode step and a 256-token packed stream."""
+    compiled = _compile(one_chip, EM.expert_matmul,
+                        ((rows, K), jnp.bfloat16), ((128, K, N), jnp.bfloat16),
+                        ((128,), jnp.int32))
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("K,N", [(2048, 768), (768, 2048)])
+def test_expert_matmul_compiles_on_a_whole_stack(one_chip, K, N):
+    """The serving layer scan's form: the stage's 6 layers of 128 experts
+    passed whole, one layer's groups read in place, and no copy of a
+    layer's stack (128, K, N) in the compiled program."""
+    compiled = _compile(one_chip, EM.expert_matmul,
+                        ((512, K), jnp.bfloat16),
+                        ((6, 128, K, N), jnp.bfloat16),
+                        ((128,), jnp.int32), ((), jnp.int32))
+    _assert_kernel(compiled)
+    assert f"bf16[128,{K},{N}]" not in compiled.as_text()
 
 
 def test_qwen3_4b_init_holds_only_bf16_weights(one_chip):

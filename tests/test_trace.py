@@ -425,6 +425,69 @@ def test_page_counters_match_hand_count(rng, model, packed):
     assert calls["packed" if packed else "mixed"] and calls["step"]
 
 
+def _moe_tokens_packed(args, _out):
+    return (int((np.asarray(args[2]) >= 0).sum()),)
+
+
+def _moe_tokens_step(args, out):
+    return (int(np.asarray(out[7]).sum()),)
+
+
+def _moe_tokens_mixed(args, _out):
+    return (int(np.asarray(args[3]).sum()),)
+
+
+BRUTE_MOE = {"packed": _moe_tokens_packed, "step": _moe_tokens_step,
+             "mixed": _moe_tokens_mixed}
+
+
+@pytest.fixture(scope="module")
+def moe_model():
+    cfg = get_reduced("qwen3-30b-a3b")
+    return cfg, T.init_params(jax.random.PRNGKey(0), cfg)
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_moe_counters_count_real_tokens_only(rng, moe_model, model, packed):
+    """Each iteration's moe_rows is top_k x expert layers x the real
+    tokens of its dispatches (packed lanes with a slot, active decode
+    lanes at every fused step, chunk tokens): padding lanes and inactive
+    slots add nothing.  Experts hit and the largest expert's rows lie
+    within what those rows allow; a dense model's records read 0."""
+    cfg, params = moe_model
+    k, layers, E = cfg.moe.top_k, cfg.num_layers, cfg.moe.num_experts
+    reqs = _requests(rng, cfg, [(30, 3), (26, 9), (9, 6), (22, 7)])
+    tr = ServeTracer(clock=FakeClock())
+    eng = _engine(cfg, params)
+    calls = _record_dispatches(eng, tr, brute=BRUTE_MOE)
+    _serve(eng, reqs, trace=tr, packed=packed)
+    assert validate_events(tr.events) == []
+    tokens = {}
+    for name in ("packed", "step", "mixed"):
+        for it, (n,) in calls[name]:
+            tokens[it] = tokens.get(it, 0) + n
+    its = list(tr.iter_events("iteration"))
+    for e in its:
+        n = tokens.get(e["iter"], 0)
+        assert e["moe_rows"] == n * k * layers, e
+        # per forward and layer at most E experts, and one row per token
+        # of one expert; a dispatch runs at most 4 forwards (fused steps)
+        assert (e["moe_experts_hit"] > 0) == (n > 0)
+        assert e["moe_experts_hit"] <= E * layers * 4
+        assert (e["moe_rows_max"] > 0) == (n > 0)
+        assert e["moe_rows_max"] <= n
+    assert any(e["padded_lanes"] > 0 for e in its if e["moe_rows"]) \
+        or not packed
+    fused = [e for e in its if not e["idle"] and e["chunk_segments"] == 0]
+    assert fused and all(e["moe_rows"] > 0 for e in fused)
+    # a model without experts stamps zeros
+    dcfg, dparams = model
+    tr2 = ServeTracer(clock=FakeClock())
+    _serve(_engine(dcfg, dparams), _requests(rng, dcfg, [(9, 4)]), trace=tr2)
+    assert all(e["moe_rows"] == e["moe_experts_hit"] == e["moe_rows_max"]
+               == 0 for e in tr2.iter_events("iteration"))
+
+
 def test_attention_work_rule():
     from repro.core.continuous import attention_work
     # decode lanes at lens 5 and 0: one query each over lens + 1 tokens
@@ -517,7 +580,8 @@ def test_schema_v2_fields_validate():
           "width_bucket": 16, "padded_lanes": 7, "idle": False,
           "pages_in_use": 3, "host_bytes": 0, "trie_nodes": 1,
           "attn_kv_tokens": 40, "attn_pairs": 90, "attn_pages": 12,
-          "attn_page_steps": 96}
+          "attn_page_steps": 96, "moe_rows": 0, "moe_experts_hit": 0,
+          "moe_rows_max": 0}
     assert validate_event(it) == []
     for f in ("attn_kv_tokens", "attn_pairs"):
         assert validate_event({k: v for k, v in it.items() if k != f})
@@ -539,15 +603,32 @@ def test_schema_v3_fields_validate():
           "width_bucket": 16, "padded_lanes": 7, "idle": False,
           "pages_in_use": 3, "host_bytes": 0, "trie_nodes": 1,
           "attn_kv_tokens": 40, "attn_pairs": 90, "attn_pages": 12,
-          "attn_page_steps": 96}
+          "attn_page_steps": 96, "moe_rows": 0, "moe_experts_hit": 0,
+          "moe_rows_max": 0}
     assert validate_event(it) == []
     for f in ("attn_pages", "attn_page_steps"):
         assert validate_event({k: v for k, v in it.items() if k != f})
         assert validate_event({**it, f: 1.5})
-    assert TR.TRACE_SCHEMA_VERSION == 3
-    assert validate_event({"kind": "trace_header", "v": 3}) == []
-    for old in (1, 2):
+    assert TR.TRACE_SCHEMA_VERSION == 4
+    assert validate_event({"kind": "trace_header", "v": 4}) == []
+    for old in (1, 2, 3):
         assert validate_event({"kind": "trace_header", "v": old})
+
+
+def test_schema_v4_fields_validate():
+    it = {"kind": "iteration", "t": 0.0, "iter": 0, "dur": 0.1,
+          "host_s": 0.01, "device_s": 0.09, "budget": 16, "budget_used": 9,
+          "decode_lanes": 2, "chunk_segments": 1, "chunk_tokens": 7,
+          "width_bucket": 16, "padded_lanes": 7, "idle": False,
+          "pages_in_use": 3, "host_bytes": 0, "trie_nodes": 1,
+          "attn_kv_tokens": 40, "attn_pairs": 90, "attn_pages": 12,
+          "attn_page_steps": 96, "moe_rows": 36, "moe_experts_hit": 15,
+          "moe_rows_max": 5}
+    assert validate_event(it) == []
+    for f in ("moe_rows", "moe_experts_hit", "moe_rows_max"):
+        assert validate_event({k: v for k, v in it.items() if k != f})
+        assert validate_event({**it, f: 1.5})
+        assert validate_event({**it, f: True})
 
 
 # ---------------------------------------------------------------------------
@@ -580,7 +661,8 @@ def test_perfetto_structure(rng, model):
     # iteration slices carry the attention counters in their args
     its = [e for e in te if e["ph"] == "X" and e["name"] == "iteration"]
     assert its and all({"attn_kv_tokens", "attn_pairs", "attn_pages",
-                        "attn_page_steps"} <= set(e["args"]) for e in its)
+                        "attn_page_steps", "moe_rows", "moe_experts_hit",
+                        "moe_rows_max"} <= set(e["args"]) for e in its)
     assert all(isinstance(e["ts"], (int, float))
                for e in te if "ts" in e)
 
