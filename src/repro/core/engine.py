@@ -380,7 +380,8 @@ class InferenceEngine:
         cache = T.init_paged_cache(
             self.cfg, num_pages=num_pages, page_size=page_size,
             max_slots=slots, max_len=self.max_len,
-            dtype=self.policy.compute_dtype, kv_dtype=kv_dtype)
+            dtype=self.policy.compute_dtype, kv_dtype=kv_dtype,
+            lanes=KV.pool_lanes(jax.devices()[0]))
         pool_bytes = KV.paged_pool_bytes(cache)
         self._paged_ctx = {
             "key": key, "page_size": page_size, "num_pages": num_pages,
@@ -442,12 +443,13 @@ class InferenceEngine:
                             pages, cow_src, cow_dst, cow_keep, cache, rng):
             # order matters: reset the fresh pages' stale positions, THEN
             # copy-on-write the partial tail (the destination is one of
-            # the fresh pages), THEN prefill the suffix into it
+            # the fresh pages), THEN prefill the suffix into it (each
+            # layer copies its tail before it writes)
             cache = KV.reset_pages_all(cache, pages)
-            cache = KV.copy_pages_all(cache, cow_src, cow_dst, cow_keep)
             view = KV.slot_view(cache, tokens.shape[0])
             paged = {"block_tables": block_row,
-                     "active": jnp.ones((tokens.shape[0],), bool)}
+                     "active": jnp.ones((tokens.shape[0],), bool),
+                     "cow": (cow_src, cow_dst, cow_keep)}
             logits, view, moe = T.forward_prefill(
                 params, cfg, tokens, length, view, policy=policy,
                 max_len=max_len, last_only=True, start=start, paged=paged)
@@ -513,10 +515,10 @@ class InferenceEngine:
         def mixed_fn(params, tokens, row_start, n_q, block_tables,
                      reset_rows, cow_src, cow_dst, cow_keep, cache, rng):
             cache = KV.reset_pages_all(cache, reset_rows)
-            cache = KV.copy_pages_all(cache, cow_src, cow_dst, cow_keep)
             logits, cache, moe = T.forward_mixed(
                 params, cfg, tokens, cache, row_start, n_q, policy=policy,
-                max_len=max_len, paged={"block_tables": block_tables})
+                max_len=max_len, paged={"block_tables": block_tables,
+                                        "cow": (cow_src, cow_dst, cow_keep)})
             rng, sub = jax.random.split(rng)
             nxt = sample(logits[:, 0], sub, sp)
             return nxt, cache, rng, moe
@@ -550,11 +552,11 @@ class InferenceEngine:
                       block_tables, reset_rows, cow_src, cow_dst, cow_keep,
                       cache, rng):
             cache = KV.reset_pages_all(cache, reset_rows)
-            cache = KV.copy_pages_all(cache, cow_src, cow_dst, cow_keep)
             logits, cache, moe = T.forward_packed(
                 params, cfg, tokens, cache, slot_ids, positions, seg_last,
                 policy=policy, max_len=max_len,
-                paged={"block_tables": block_tables, "packed_meta": meta})
+                paged={"block_tables": block_tables, "packed_meta": meta,
+                       "cow": (cow_src, cow_dst, cow_keep)})
             rng, sub = jax.random.split(rng)
             nxt = sample(logits[0], sub, sp)        # (S,)
             return nxt, cache, rng, moe

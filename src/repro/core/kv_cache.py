@@ -183,6 +183,12 @@ def cache_mask(cache_pos, q_pos, window: Optional[int]):
 # token row quantizes to the same bytes no matter which request wrote it.
 # Scales travel with their pages through COW copies, trie mappings and
 # eviction exactly like pk/pv.
+#
+# A model cache stacks each pool over its layer stack's scan repeats:
+# (R, P, page, ...).  The serving layer scan carries the whole stack and
+# writes a layer's entries at [layer, phys, off] (the writes below take
+# ``layer``), so the step programs update the pool in place; given
+# ``layer=None`` they act on one layer's (P, page, ...) pool.
 
 PAGED_KEYS = ("pk", "pv", "ppos", "pk_scale", "pv_scale")
 PAGED_DATA_KEYS = ("pk", "pv", "pk_scale", "pv_scale")
@@ -210,23 +216,65 @@ def dequantize_kv(q, scale, dtype=jnp.float32):
         .astype(dtype)
 
 
-def _scatter_kv(cache: dict, out: dict, val, phys, off) -> None:
-    """Scatter new K/V rows into ``out``'s pools at (phys, off) —
-    quantizing (codes + scale rows) when the pool is int8, casting to
-    the pool dtype otherwise.  ``val``: {"k"/"v": (..., H, D)} rows
-    aligned with phys/off.  Shared by the prefill-chunk and
-    decode-single-token writes so the two paths can never desynchronize
-    their quantized layout."""
+def _at(layer, *idx):
+    """Index of the entries ``idx`` in a pool leaf: of one layer's pool
+    (``layer`` None), or of layer ``layer`` of a whole stack."""
+    return idx if layer is None else (layer,) + idx
+
+
+def _pages_of(cache, pages, layer=None):
+    """Index arrays of physical ``pages`` (any shape) in a pool's leaves:
+    in layer ``layer`` of a stack, in every layer of a stack where
+    ``layer`` is None, or in one layer's pool."""
+    if cache["ppos"].ndim == 2:
+        return (pages,)
+    if layer is None:
+        R = cache["ppos"].shape[0]
+        return (jnp.arange(R).reshape((R,) + (1,) * pages.ndim), pages[None])
+    return (layer, pages)
+
+
+def _index(key, x, at):
+    """``at``, index arrays over the leading dims of pool leaf ``x``,
+    extended to reach what a page move of leaf ``key`` reads and writes
+    at a time: K/V rows (H, D) whole, positions element by element, and
+    scales as whole (page, H) blocks.  These are the forms XLA runs in
+    the layout each leaf is kept in; others have it relay the whole
+    stack out around them."""
+    lead = len(at)
+    n = {"pk": 1, "pv": 1, "ppos": x.ndim - lead}.get(key, 0)
+    at = tuple(jnp.reshape(a, jnp.shape(a) + (1,) * n) for a in at)
+    return at + tuple(jnp.arange(d).reshape((d,) + (1,) * (n - 1 - i))
+                      for i, d in enumerate(x.shape[lead:lead + n]))
+
+
+def _scatter_kv(cache: dict, out: dict, val, idx) -> None:
+    """Scatter new K/V rows into ``out``'s pools at ``idx`` ((phys, off)
+    or (layer, phys, off)) — quantizing (codes + scale rows) when the
+    pool is int8, casting to the pool dtype otherwise.  ``val``:
+    {"k"/"v": (..., H, D)} rows aligned with phys/off.  Shared by the
+    prefill-chunk and decode-single-token writes so the two paths can
+    never desynchronize their quantized layout."""
     quant = "pk_scale" in cache
     for key, pool_key in (("k", "pk"), ("v", "pv")):
         if quant:
             q, sc = quantize_kv(val[key])
-            out[pool_key] = cache[pool_key].at[phys, off].set(q)
-            out[pool_key + "_scale"] = \
-                cache[pool_key + "_scale"].at[phys, off].set(sc)
+            out[pool_key] = cache[pool_key].at[idx].set(
+                _widen(q, cache[pool_key]))
+            skey = pool_key + "_scale"
+            out[skey] = cache[skey].at[idx].set(_widen(sc, cache[skey]))
         else:
-            out[pool_key] = cache[pool_key].at[phys, off].set(
-                val[key].astype(cache[pool_key].dtype))
+            out[pool_key] = cache[pool_key].at[idx].set(
+                _widen(val[key].astype(cache[pool_key].dtype),
+                       cache[pool_key]))
+
+
+def _widen(rows, leaf):
+    """``rows`` zero-padded in their minor dim to pool ``leaf``'s, which
+    :func:`paged_layer_cache_shape` may have widened to whole lanes."""
+    pad = leaf.shape[-1] - rows.shape[-1]
+    return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)]) \
+        if pad else rows
 
 
 def paged_pool_bytes(cache: dict) -> int:
@@ -244,10 +292,19 @@ def paged_pool_bytes(cache: dict) -> int:
     return total
 
 
+def pool_lanes(device) -> int:
+    """The multiple a paged pool's rows are widened to on ``device``: a
+    TPU keeps an array whose minor dim is not a whole number of its
+    128 lanes with another dim minor (pages, for a pool), which the
+    paged kernels cannot read in place; at whole lanes its default
+    layout is row-major, the kernels' layout."""
+    return 128 if device.platform == "tpu" else 1
+
+
 def paged_layer_cache_shape(cfg: ModelConfig, spec: LayerSpec,
                             num_pages: int, page_size: int, max_slots: int,
                             max_len: int, dtype,
-                            kv_dtype: str = "auto") -> dict:
+                            kv_dtype: str = "auto", lanes: int = 1) -> dict:
     """Paged cache for one layer.  ATTN / HYBRID attention K/V become page
     pools; MLA and recurrent families keep their dense per-slot state (the
     slot API — admit/retire — is identical for them).
@@ -257,25 +314,30 @@ def paged_layer_cache_shape(cfg: ModelConfig, spec: LayerSpec,
     per-entry scale pools — for pure attention layers only.  Hybrid
     layers keep full-precision pools (their SSM/conv state is dense fp32
     anyway), the same families that opt out of prefix sharing.
+
+    ``lanes`` (:func:`pool_lanes`) widens each K/V row (head dim) and
+    each scale row (heads) to a multiple of it, zero-padded: the writes
+    pad new rows, and readers take the first head-dim (heads) entries.
     """
     from repro.core.precision import kv_store_dtype
-    hd = cfg.resolved_head_dim
+    hd, H = cfg.resolved_head_dim, cfg.num_kv_heads
     quant = kv_dtype == "int8" and spec.mixer == ATTN
     pool_dtype = (jnp.int8 if quant
                   else kv_store_dtype(kv_dtype, dtype, allow_int8=False))
 
+    def wide(n):
+        return -(-n // lanes) * lanes
+
     def pool():
         P = num_pages + 1                               # +1 dump page
-        out = {"pk": jnp.zeros((P, page_size, cfg.num_kv_heads, hd),
-                               pool_dtype),
-               "pv": jnp.zeros((P, page_size, cfg.num_kv_heads, hd),
-                               pool_dtype),
+        out = {"pk": jnp.zeros((P, page_size, H, wide(hd)), pool_dtype),
+               "pv": jnp.zeros((P, page_size, H, wide(hd)), pool_dtype),
                "ppos": jnp.full((P, page_size), -1, jnp.int32)}
         if quant:
-            out["pk_scale"] = jnp.zeros(
-                (P, page_size, cfg.num_kv_heads), jnp.float32)
-            out["pv_scale"] = jnp.zeros(
-                (P, page_size, cfg.num_kv_heads), jnp.float32)
+            out["pk_scale"] = jnp.zeros((P, page_size, wide(H)),
+                                        jnp.float32)
+            out["pv_scale"] = jnp.zeros((P, page_size, wide(H)),
+                                        jnp.float32)
         return out
 
     if spec.mixer == ATTN:
@@ -302,7 +364,7 @@ def paged_ring_len(window: Optional[int], page_size: int,
 
 
 def paged_write_prefill(cache: dict, new: dict, cache_pos, block_tables, *,
-                        ring_len: int) -> dict:
+                        ring_len: int, layer=None) -> dict:
     """Scatter a prompt's K/V into pool pages via the slot block tables.
 
     cache_pos: (B, S) absolute positions (-1 = padding); block_tables:
@@ -311,8 +373,8 @@ def paged_write_prefill(cache: dict, new: dict, cache_pos, block_tables, *,
     would otherwise scatter twice into one entry, which is unordered).
     """
     out = dict(cache)
-    page = cache["ppos"].shape[1]
-    dump = cache["ppos"].shape[0] - 1
+    page = cache["ppos"].shape[-1]
+    dump = cache["ppos"].shape[-2] - 1
     B, S = cache_pos.shape
     take = min(S, ring_len)
     valid = jnp.maximum(cache_pos.max(axis=1) + 1, 0)          # (B,)
@@ -325,21 +387,21 @@ def paged_write_prefill(cache: dict, new: dict, cache_pos, block_tables, *,
     phys = jnp.take_along_axis(block_tables, lp, axis=1)       # (B, take)
     ok = (pos_w >= 0) & (phys >= 0)
     phys = jnp.where(ok, phys, dump)
+    at = _at(layer, phys, off)
     _scatter_kv(cache, out,
                 {key: new[key][b_idx, idx] for key in ("k", "v")},
-                phys, off)                                     # (B,take,H,D)
-    out["ppos"] = cache["ppos"].at[phys, off].set(
-        jnp.where(ok, pos_w, -1))
+                at)                                            # (B,take,H,D)
+    out["ppos"] = cache["ppos"].at[at].set(jnp.where(ok, pos_w, -1))
     return out
 
 
 def paged_write_decode(cache: dict, new: dict, lengths, block_tables,
-                       active=None, *, ring_len: int) -> dict:
+                       active=None, *, ring_len: int, layer=None) -> dict:
     """Write one token per slot at absolute position ``lengths`` (B,).
     Inactive slots (active == False) are routed to the dump page."""
     out = dict(cache)
-    page = cache["ppos"].shape[1]
-    dump = cache["ppos"].shape[0] - 1
+    page = cache["ppos"].shape[-1]
+    dump = cache["ppos"].shape[-2] - 1
     B = lengths.shape[0]
     rp = lengths % ring_len
     lp, off = rp // page, rp % page
@@ -348,15 +410,16 @@ def paged_write_decode(cache: dict, new: dict, lengths, block_tables,
     if active is not None:
         ok &= active
     phys = jnp.where(ok, phys, dump)
+    at = _at(layer, phys, off)
     _scatter_kv(cache, out, {key: new[key][:, 0] for key in ("k", "v")},
-                phys, off)                                     # (B, H, D)
-    out["ppos"] = cache["ppos"].at[phys, off].set(
-        jnp.where(ok, lengths, -1))
+                at)                                            # (B, H, D)
+    out["ppos"] = cache["ppos"].at[at].set(jnp.where(ok, lengths, -1))
     return out
 
 
 def paged_write_decode_multi(cache: dict, new: dict, lengths, block_tables,
-                             active=None, *, ring_len: int) -> dict:
+                             active=None, *, ring_len: int,
+                             layer=None) -> dict:
     """Scatter a speculation window of ``K1`` tokens per slot at absolute
     positions ``lengths[b] .. lengths[b] + K1 - 1`` (the draft-verify
     forward writes the pending token plus every drafted token in one
@@ -372,8 +435,8 @@ def paged_write_decode_multi(cache: dict, new: dict, lengths, block_tables,
     engine gates speculation to non-windowed attention).
     """
     out = dict(cache)
-    page = cache["ppos"].shape[1]
-    dump = cache["ppos"].shape[0] - 1
+    page = cache["ppos"].shape[-1]
+    dump = cache["ppos"].shape[-2] - 1
     B, K1 = new["k"].shape[:2]
     pos = lengths[:, None] + jnp.arange(K1)[None, :]           # (B, K1)
     ok = pos < ring_len
@@ -384,15 +447,15 @@ def paged_write_decode_multi(cache: dict, new: dict, lengths, block_tables,
     if active is not None:
         ok &= active if active.ndim == 2 else active[:, None]
     phys = jnp.where(ok, phys, dump)
+    at = _at(layer, phys, off)
     _scatter_kv(cache, out, {key: new[key] for key in ("k", "v")},
-                phys, off)                                     # (B,K1,H,D)
-    out["ppos"] = cache["ppos"].at[phys, off].set(
-        jnp.where(ok, pos, -1))
+                at)                                            # (B,K1,H,D)
+    out["ppos"] = cache["ppos"].at[at].set(jnp.where(ok, pos, -1))
     return out
 
 
 def paged_write_packed(cache: dict, new: dict, slot_ids, positions,
-                       block_tables, *, ring_len: int) -> dict:
+                       block_tables, *, ring_len: int, layer=None) -> dict:
     """Scatter a token-packed stream's K/V: token t of the flat stream
     belongs to slot ``slot_ids[t]`` at absolute position ``positions[t]``
     and lands in that slot's pages via ``block_tables``.
@@ -405,8 +468,8 @@ def paged_write_packed(cache: dict, new: dict, slot_ids, positions,
     positions all route to the dump page.
     """
     out = dict(cache)
-    page = cache["ppos"].shape[1]
-    dump = cache["ppos"].shape[0] - 1
+    page = cache["ppos"].shape[-1]
+    dump = cache["ppos"].shape[-2] - 1
     B = block_tables.shape[0]
     ok = (slot_ids >= 0) & (positions >= 0) & (positions < ring_len)
     rp = jnp.where(ok, positions, 0)
@@ -415,10 +478,10 @@ def paged_write_packed(cache: dict, new: dict, slot_ids, positions,
     phys = block_tables[safe_slot, lp]                          # (T,)
     ok &= phys >= 0
     phys = jnp.where(ok, phys, dump)
+    at = _at(layer, phys, off)
     _scatter_kv(cache, out, {key: new[key][0] for key in ("k", "v")},
-                phys, off)                                      # (T, H, D)
-    out["ppos"] = cache["ppos"].at[phys, off].set(
-        jnp.where(ok, positions, -1))
+                at)                                             # (T, H, D)
+    out["ppos"] = cache["ppos"].at[at].set(jnp.where(ok, positions, -1))
     return out
 
 
@@ -438,17 +501,15 @@ def paged_truncate(cache, block_tables, keep_len) -> dict:
         return cache
     out = dict(cache)
     # pool dim is second-to-last: ppos is (P, page) or (R, P, page)
-    dump = cache["ppos"].shape[-2] - 1
-    safe = jnp.where(block_tables >= 0, block_tables, dump)    # (B, npages)
-    if cache["ppos"].ndim == 3:          # leading scan-repeats dim
-        pos = cache["ppos"][:, safe]                 # (R, B, npages, page)
-        keep = pos < keep_len[None, :, None, None]
-        out["ppos"] = cache["ppos"].at[:, safe].set(
-            jnp.where(keep, pos, -1))
-    else:
-        pos = cache["ppos"][safe]                    # (B, npages, page)
-        keep = pos < keep_len[:, None, None]
-        out["ppos"] = cache["ppos"].at[safe].set(jnp.where(keep, pos, -1))
+    P = cache["ppos"].shape[-2]
+    safe = jnp.where(block_tables >= 0, block_tables, P - 1)  # (B, npages)
+    # each page's bound, the least over the rows that hold it (a shared
+    # page holds no position at or past any sharer's bound)
+    bound = jnp.full((P,), jnp.iinfo(jnp.int32).max, jnp.int32).at[safe] \
+        .min(jnp.broadcast_to(keep_len[:, None], safe.shape)
+             .astype(jnp.int32))
+    out["ppos"] = jnp.where(cache["ppos"] >= bound[:, None], -1,
+                            cache["ppos"])
     return out
 
 
@@ -459,30 +520,37 @@ def paged_truncate_all(cache: dict, block_tables, keep_len) -> dict:
         for stack_c in cache["layers"])}
 
 
-def paged_gather(cache: dict, block_tables):
-    """Dense per-slot view of the pool: (B, pages*page, H, D) k/v plus
-    (B, pages*page) positions.  Unallocated table entries read the dump
-    page and are masked to pos = -1.  Quantized pools are dequantized on
-    gather (fp32 out; callers cast to their compute dtype)."""
-    dump = cache["ppos"].shape[0] - 1
-    safe = jnp.where(block_tables >= 0, block_tables, dump)
-    k = cache["pk"][safe]                      # (B, pages, page, H, D)
-    v = cache["pv"][safe]
+def paged_gather(cache: dict, block_tables, layer=None, dim=None):
+    """Dense per-slot view of the pool (of layer ``layer`` of a stacked
+    one): (B, pages*page, H, D) k/v plus (B, pages*page) positions, D
+    the pool's row or the first ``dim`` entries of it (a widened pool,
+    :func:`paged_layer_cache_shape`).  Unallocated table entries read
+    the dump page and are masked to pos = -1.  Quantized pools are
+    dequantized on gather (fp32 out; callers cast to their compute
+    dtype)."""
+    dump = cache["ppos"].shape[-2] - 1
+    at = _at(layer, jnp.where(block_tables >= 0, block_tables, dump))
+    rows = {k: a[_index(k, a, at)] for k, a in cache.items()
+            if k in PAGED_KEYS}
+    k, v = rows["pk"][..., :dim], rows["pv"][..., :dim]   # (B,pages,page,H,D)
     if "pk_scale" in cache:
-        k = dequantize_kv(k, cache["pk_scale"][safe])
-        v = dequantize_kv(v, cache["pv_scale"][safe])
+        H = k.shape[-2]
+        k = dequantize_kv(k, rows["pk_scale"][..., :H])
+        v = dequantize_kv(v, rows["pv_scale"][..., :H])
     kp = jnp.where((block_tables >= 0)[..., None],
-                   cache["ppos"][safe], -1)    # (B, pages, page)
+                   rows["ppos"], -1)           # (B, pages, page)
     B, npg, page = kp.shape
     return (k.reshape(B, npg * page, *k.shape[3:]),
             v.reshape(B, npg * page, *v.shape[3:]),
             kp.reshape(B, npg * page))
 
 
-def copy_pages(cache, src, dst, keep_below) -> dict:
+def copy_pages(cache, src, dst, keep_below, layer=None) -> dict:
     """Copy-on-write clone: page ``dst[i]`` becomes a copy of ``src[i]``
     with only the entries at absolute positions ``0 <= pos <
-    keep_below[i]`` kept valid (the rest are masked to pos = -1).
+    keep_below[i]`` kept valid (the rest are masked to pos = -1).  On a
+    stacked pool, in layer ``layer`` (the serving layer scan copies each
+    layer's tail pages before that layer writes), or in every layer.
 
     This is how a request whose prefix match ends *inside* a page gets a
     private tail page: the shared source page is read, never written,
@@ -493,28 +561,16 @@ def copy_pages(cache, src, dst, keep_below) -> dict:
     if "ppos" not in cache:
         return cache
     out = dict(cache)
-    data_keys = [k for k in PAGED_DATA_KEYS if k in cache]
-    if cache["ppos"].ndim == 3:          # leading scan-repeats dim
-        pos = cache["ppos"][:, src]                      # (R, N, page)
-        keep = (pos >= 0) & (pos < keep_below[None, :, None])
-        out["ppos"] = cache["ppos"].at[:, dst].set(
-            jnp.where(keep, pos, -1))
-        for k in data_keys:
-            out[k] = cache[k].at[:, dst].set(cache[k][:, src])
-    else:
-        pos = cache["ppos"][src]                         # (N, page)
-        keep = (pos >= 0) & (pos < keep_below[:, None])
-        out["ppos"] = cache["ppos"].at[dst].set(jnp.where(keep, pos, -1))
-        for k in data_keys:
-            out[k] = cache[k].at[dst].set(cache[k][src])
+    at_src = _pages_of(cache, src, layer)
+    at_dst = _pages_of(cache, dst, layer)
+    for k in PAGED_KEYS:
+        if k in cache:
+            rows = cache[k][_index(k, cache[k], at_src)]
+            if k == "ppos":                            # ([R,] N, page)
+                keep = (rows >= 0) & (rows < keep_below[:, None])
+                rows = jnp.where(keep, rows, -1)
+            out[k] = cache[k].at[_index(k, cache[k], at_dst)].set(rows)
     return out
-
-
-def copy_pages_all(cache: dict, src, dst, keep_below) -> dict:
-    """:func:`copy_pages` over every paged layer of a full model cache."""
-    return {"layers": tuple(
-        tuple(copy_pages(c, src, dst, keep_below) for c in stack_c)
-        for stack_c in cache["layers"])}
 
 
 def reset_pages_all(cache: dict, pages) -> dict:
@@ -531,11 +587,8 @@ def reset_pages(cache, pages) -> dict:
     if "ppos" not in cache:
         return cache
     out = dict(cache)
-    # pool leaves may carry a leading scan-repeats dim
-    if cache["ppos"].ndim == 3:
-        out["ppos"] = cache["ppos"].at[:, pages, :].set(-1)
-    else:
-        out["ppos"] = cache["ppos"].at[pages, :].set(-1)
+    fresh = jnp.zeros(cache["ppos"].shape[-2], bool).at[pages].set(True)
+    out["ppos"] = jnp.where(fresh[:, None], -1, cache["ppos"])
     return out
 
 
@@ -582,8 +635,7 @@ def restore_pages(cache: dict, blob: list, pages) -> dict:
     Every paged leaf row is overwritten wholesale, so no prior
     ``reset_pages`` is needed: stale previous-owner state cannot survive.
     """
-    import numpy as np
-    pages = np.asarray(pages, np.int32)
+    pages = jnp.asarray(pages, jnp.int32)
     layers = []
     for stack_c, brow in zip(cache["layers"], blob):
         row = []
@@ -591,9 +643,8 @@ def restore_pages(cache: dict, blob: list, pages) -> dict:
             if b is None:
                 row.append(c)
                 continue
-            rep = c["ppos"].ndim == 3
-            row.append({k: (c[k].at[:, pages].set(b[k]) if rep
-                            else c[k].at[pages].set(b[k]))
+            at = _pages_of(c, pages)
+            row.append({k: c[k].at[_index(k, c[k], at)].set(b[k])
                         if k in b else c[k] for k in c})
         layers.append(tuple(row))
     return {"layers": tuple(layers)}

@@ -27,6 +27,13 @@ pipeline fetches nothing, and the body is skipped; ``_init`` and
 output contract are those of a full walk.  A skipped page lies wholly
 beyond the item's queries, and a fully masked page leaves the online
 softmax state exactly as it was, so the outputs are the full walk's.
+
+The paged kernels read the pool in place from the whole layer stack a
+serving layer scan carries: pools ``(L, P, page, Hkv, D)``, positions
+``(L, P, page)`` and scales ``(L, P, page, Hkv)``, with the layer to read
+passed as one more scalar-prefetch operand, so no layer's slice of the
+stack is copied out for the kernel.  Given ``layer=None`` they take one
+layer's pools, as a stack of one.
 """
 from __future__ import annotations
 
@@ -91,11 +98,74 @@ def _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, *, scale,
                      attn_softcap=attn_softcap)
 
 
-def _page_positions(ppos):
-    """(P, page) -> (P, 1, page): each page's stored positions become
-    one lane row, so a (1, 1, page) block meets the TPU tiling rule
-    (last two block dims equal to the array's)."""
-    return ppos.reshape(ppos.shape[0], 1, ppos.shape[1])
+# pages whose stored positions one streamed block holds: a kernel reads
+# the positions transposed, (L, page, P), as the pool keeps them (pages
+# minor, its TPU default layout), and picks its page's column out
+POS_LANES = 128
+
+
+def _stack(layer, pools):
+    """The layer to read as a (1,) int32 scalar-prefetch operand, and
+    the pools as whole layer stacks: a single layer's pools (``layer``
+    None) become a stack of one."""
+    if layer is None:
+        return jnp.zeros((1,), jnp.int32), [p[None] for p in pools]
+    return jnp.reshape(layer, (1,)).astype(jnp.int32), list(pools)
+
+
+def _pool_specs(kpool, vpool, k_scale, page_of):
+    """BlockSpecs of the stacked pool operands in the kernels' order: k,
+    [k scale,] v, [v scale,] positions (transposed, :func:`_positions`).
+    Each streams page ``page_of(i, j, *prefetch)`` of layer ``ly[0]``;
+    the positions come as the (page, POS_LANES) block that holds that
+    page's column."""
+    page, Hkv, D = kpool.shape[-3:]
+    Dv = vpool.shape[-1]
+
+    def spec(*block):
+        return pl.BlockSpec(
+            (None, 1) + block,
+            lambda i, j, ly, *pf: (ly[0], page_of(i, j, *pf))
+            + (0,) * len(block))
+
+    scale = [spec(page, k_scale.shape[-1])] if k_scale is not None else []
+    pos = pl.BlockSpec(
+        (None, page, POS_LANES),
+        lambda i, j, ly, *pf: (ly[0], 0, page_of(i, j, *pf) // POS_LANES))
+    return [spec(page, Hkv, D)] + scale + [spec(page, Hkv, Dv)] + scale \
+        + [pos]
+
+
+def _positions(ppos):
+    """(L, P, page) -> (L, page, P): the positions operand, in the
+    layout a TPU keeps ``ppos`` in (pages minor), so this transpose is
+    free."""
+    return jnp.swapaxes(ppos, -1, -2)
+
+
+def _page_pos(kp_ref, bt_ref, row, j, n_live, dump):
+    """(1, page) stored positions of the page streamed at step j: its
+    column of the (page, POS_LANES) block, as a row."""
+    c = _live_page_id(bt_ref, row, j, n_live, dump) % POS_LANES
+    cols = kp_ref[...].T                                   # (lanes, page)
+    pick = jax.lax.broadcasted_iota(jnp.int32, cols.shape, 0) == c
+    return jnp.sum(jnp.where(pick, cols, 0), axis=0, keepdims=True)
+
+
+def _page_kv(q_ref, k_ref, v_ref, ks_ref=None, vs_ref=None):
+    """The streamed page's K and V as float32 (dequantized with their
+    scale rows for an int8 pool).  A pool widened to whole lanes
+    (``kv_cache.paged_layer_cache_shape``) holds zeros past each row's
+    head dim and heads: K is cut to the query's head dim and the scale
+    rows to K's heads; V keeps the pool's width, and the caller cuts the
+    output."""
+    d, hkv = q_ref.shape[-1], k_ref.shape[-2]
+    k = k_ref[0, :, :, :d].astype(jnp.float32)             # (page, Hkv, D)
+    v = v_ref[0].astype(jnp.float32)                       # (page, Hkv, Dv)
+    if ks_ref is not None:
+        k = k * ks_ref[0, :, :hkv].astype(jnp.float32)[..., None]
+        v = v * vs_ref[0, :, :hkv].astype(jnp.float32)[..., None]
+    return k, v
 
 
 def live_pages(max_qpos, page: int, npages: int):
@@ -175,15 +245,15 @@ def _kernel(q_ref, k_ref, v_ref, kp_ref, qp_ref, o_ref,
 
 def paged_shape_supported(q, kpool, block_tables) -> bool:
     B, Sq, Hq, D = q.shape
-    page, Hkv = kpool.shape[1], kpool.shape[2]
+    page, Hkv = kpool.shape[-3], kpool.shape[-2]
     return (Sq == 1 and Hq % Hkv == 0 and D % 8 == 0
-            and kpool.shape[3] % 8 == 0 and page % 8 == 0
+            and kpool.shape[-1] % 8 == 0 and page % 8 == 0
             and block_tables.shape[0] == B)
 
 
-def _paged_kernel(nl_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref,
-                  o_ref, m_scr, l_scr, acc_scr, *, scale, attn_softcap,
-                  window, npages, g):
+def _paged_kernel(ly_ref, nl_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref,
+                  qp_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
+                  attn_softcap, window, npages, dump, g):
     """Same online-softmax scheme as _kernel, but the grid walks the
     slot's block table: page j streams physical page bt[b, j] from the
     pool (the BlockSpec index_map does the indirection; bt itself arrives
@@ -201,9 +271,8 @@ def _paged_kernel(nl_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref,
     @_on_live_page(j, nl_ref[b])
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)                # (Hq, D)
-        k = k_ref[0].astype(jnp.float32)                   # (page, Hkv, D)
-        v = v_ref[0].astype(jnp.float32)                   # (page, Hkv, Dv)
-        kp = kp_ref[0]                                     # (1, page)
+        k, v = _page_kv(q_ref, k_ref, v_ref)             # (page, Hkv, D[v])
+        kp = _page_pos(kp_ref, bt_ref, b, j, nl_ref[b], dump)  # (1, page)
         qp = qp_ref[0]                                     # (1, 1)
         mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)  # (1, page)
         _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
@@ -219,25 +288,29 @@ def _paged_kernel(nl_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref,
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",
                                              "attn_softcap", "interpret"))
-def paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
-                           window: Optional[int], scale: float,
+def paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos,
+                           layer=None, *, window: Optional[int],
+                           scale: float,
                            attn_softcap: Optional[float] = None,
                            interpret: bool = False):
     """Decode attention over a paged KV pool.
 
-    q: (B,1,Hq,D); kpool/vpool: (P,page,Hkv,D[v]); ppos: (P,page) absolute
-    positions (-1 empty); block_tables: (B,npages) physical page ids with
-    -1 = unallocated; q_pos: (B,1).  Page P-1 is the dump page.
+    q: (B,1,Hq,D); kpool/vpool: (L,P,page,Hkv,D[v]); ppos: (L,P,page)
+    absolute positions (-1 empty), read at layer ``layer`` (or one
+    layer's (P,page,...) pools with ``layer`` None); block_tables:
+    (B,npages) physical page ids with -1 = unallocated; q_pos: (B,1).
+    Page P-1 is the dump page.
 
     The grid spans (B, npages), but slot b streams and attends only its
     ``n_live`` live pages (``q_pos[b] // page + 1``; none for a row with
     no first page); later steps fetch nothing and compute nothing.
     """
     B, _, Hq, D = q.shape
-    P, page, Hkv, Dv = vpool.shape
+    P, page, Hkv, Dv = vpool.shape[-4:]
     npages = block_tables.shape[1]
     g = Hq // Hkv
     dump = P - 1
+    ly, (kpool, vpool, ppos) = _stack(layer, (kpool, vpool, ppos))
 
     n_live = _row_live_pages(q_pos, block_tables, page)
 
@@ -247,18 +320,13 @@ def paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
 
     kernel = functools.partial(_paged_kernel, scale=scale,
                                attn_softcap=attn_softcap, window=window,
-                               npages=npages, g=g)
+                               npages=npages, dump=dump, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, npages),
         in_specs=[
             pl.BlockSpec((1, 1, Hq, D), lambda b, j, *pf: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
-            pl.BlockSpec((1, 1, page),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
+            *_pool_specs(kpool, vpool, None, page_of),
             pl.BlockSpec((1, 1, 1), lambda b, j, *pf: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, Hq, Dv), lambda b, j, *pf: (b, 0, 0, 0)),
@@ -273,14 +341,14 @@ def paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(n_live, block_tables, q, kpool, vpool, _page_positions(ppos),
+    )(ly, n_live, block_tables, q, kpool, vpool, _positions(ppos),
       q_pos.reshape(B, -1, 1))
     return out
 
 
-def _paged_kernel_q8(nl_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
-                     kp_ref, qp_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
-                     attn_softcap, window, npages, g):
+def _paged_kernel_q8(ly_ref, nl_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
+                     vs_ref, kp_ref, qp_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                     scale, attn_softcap, window, npages, dump, g):
     """Quantized-pool variant of _paged_kernel: the page tile arrives as
     int8 codes plus a per-entry (page, Hkv) fp32 scale row, and the
     dequantize (code * scale) happens in-register before QK^T / PV — the
@@ -296,11 +364,8 @@ def _paged_kernel_q8(nl_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
     @_on_live_page(j, nl_ref[b])
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)                # (Hq, D)
-        k = k_ref[0].astype(jnp.float32) \
-            * ks_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, D)
-        v = v_ref[0].astype(jnp.float32) \
-            * vs_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, Dv)
-        kp = kp_ref[0]                                     # (1, page)
+        k, v = _page_kv(q_ref, k_ref, v_ref, ks_ref, vs_ref)
+        kp = _page_pos(kp_ref, bt_ref, b, j, nl_ref[b], dump)  # (1, page)
         qp = qp_ref[0]                                     # (1, 1)
         mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)  # (1, page)
         _attend_block(q, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
@@ -317,23 +382,25 @@ def _paged_kernel_q8(nl_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
 @functools.partial(jax.jit, static_argnames=("window", "scale",
                                              "attn_softcap", "interpret"))
 def paged_decode_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
-                              block_tables, q_pos, *,
+                              block_tables, q_pos, layer=None, *,
                               window: Optional[int], scale: float,
                               attn_softcap: Optional[float] = None,
                               interpret: bool = False):
     """Decode attention over an int8-quantized paged KV pool.
 
     Same contract as :func:`paged_decode_attention` plus the parallel
-    scale pools: kpool/vpool are (P,page,Hkv,D[v]) int8 codes and
-    k_scale/v_scale are (P,page,Hkv) fp32 per-entry absmax scales.
+    scale pools: kpool/vpool are (L,P,page,Hkv,D[v]) int8 codes and
+    k_scale/v_scale are (L,P,page,Hkv) fp32 per-entry absmax scales.
     Dequantization is fused into the page stream (in-register, before
     the matmuls).  Like the fp entry, each slot streams and dequantizes
     only its live pages of the (B, npages) grid."""
     B, _, Hq, D = q.shape
-    P, page, Hkv, Dv = vpool.shape
+    P, page, Hkv, Dv = vpool.shape[-4:]
     npages = block_tables.shape[1]
     g = Hq // Hkv
     dump = P - 1
+    ly, (kpool, k_scale, vpool, v_scale, ppos) = _stack(
+        layer, (kpool, k_scale, vpool, v_scale, ppos))
 
     n_live = _row_live_pages(q_pos, block_tables, page)
 
@@ -343,22 +410,13 @@ def paged_decode_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
 
     kernel = functools.partial(_paged_kernel_q8, scale=scale,
                                attn_softcap=attn_softcap, window=window,
-                               npages=npages, g=g)
+                               npages=npages, dump=dump, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, npages),
         in_specs=[
             pl.BlockSpec((1, 1, Hq, D), lambda b, j, *pf: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
-            pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
-            pl.BlockSpec((1, 1, page),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
+            *_pool_specs(kpool, vpool, k_scale, page_of),
             pl.BlockSpec((1, 1, 1), lambda b, j, *pf: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, Hq, Dv), lambda b, j, *pf: (b, 0, 0, 0)),
@@ -373,16 +431,16 @@ def paged_decode_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(n_live, block_tables, q, kpool, k_scale, vpool, v_scale,
-      _page_positions(ppos), q_pos.reshape(B, -1, 1))
+    )(ly, n_live, block_tables, q, kpool, k_scale, vpool, v_scale,
+      _positions(ppos), q_pos.reshape(B, -1, 1))
     return out
 
 
 def paged_mixed_shape_supported(q, kpool, block_tables) -> bool:
     B, Sq, Hq, D = q.shape
-    page, Hkv = kpool.shape[1], kpool.shape[2]
+    page, Hkv = kpool.shape[-3], kpool.shape[-2]
     return (Sq >= 1 and Hq % Hkv == 0 and D % 8 == 0
-            and kpool.shape[3] % 8 == 0 and page % 8 == 0
+            and kpool.shape[-1] % 8 == 0 and page % 8 == 0
             and block_tables.shape[0] == B)
 
 
@@ -406,9 +464,9 @@ def _mq_mask(kp, qp, allocated, window):
     return mask
 
 
-def _paged_mixed_kernel(nl_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref,
-                        o_ref, m_scr, l_scr, acc_scr, *, scale, attn_softcap,
-                        window, npages, g):
+def _paged_mixed_kernel(ly_ref, nl_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref,
+                        qp_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
+                        attn_softcap, window, npages, dump, g):
     """Multi-query-per-slot variant of _paged_kernel: all W query
     positions of a slot's window (prefill chunk, speculation window, or
     a single decode token plus padding) stream the slot's pages ONCE
@@ -428,9 +486,8 @@ def _paged_mixed_kernel(nl_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref,
     @_on_live_page(j, nl_ref[b])
     def _step():
         q = q_ref[0].astype(jnp.float32)                   # (K1, Hq, D)
-        k = k_ref[0].astype(jnp.float32)                   # (page, Hkv, D)
-        v = v_ref[0].astype(jnp.float32)                   # (page, Hkv, Dv)
-        kp = kp_ref[0]                                     # (1, page)
+        k, v = _page_kv(q_ref, k_ref, v_ref)             # (page, Hkv, D[v])
+        kp = _page_pos(kp_ref, bt_ref, b, j, nl_ref[b], dump)  # (1, page)
         qp = qp_ref[0]                                     # (K1, 1)
         mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)
         qg = q.reshape(K1, Hkv, g, D).transpose(1, 0, 2, 3)  # (Hkv,K1,g,D)
@@ -448,8 +505,8 @@ def _paged_mixed_kernel(nl_ref, bt_ref, q_ref, k_ref, v_ref, kp_ref, qp_ref,
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",
                                              "attn_softcap", "interpret"))
-def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
-                          window: Optional[int], scale: float,
+def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
+                          layer=None, *, window: Optional[int], scale: float,
                           attn_softcap: Optional[float] = None,
                           interpret: bool = False):
     """Variable-length mixed-batch attention over a paged KV pool: up to
@@ -465,13 +522,15 @@ def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
     make the per-query causal mask exact inside the window, so any
     chunk boundary is legal.  The grid spans (B, npages), but row b
     streams and attends only the live pages of its largest query
-    position (:func:`live_pages`).
+    position (:func:`live_pages`).  Pools are stacked and read at
+    ``layer`` as in :func:`paged_decode_attention`.
     """
     B, K1, Hq, D = q.shape
-    P, page, Hkv, Dv = vpool.shape
+    P, page, Hkv, Dv = vpool.shape[-4:]
     npages = block_tables.shape[1]
     g = Hq // Hkv
     dump = P - 1
+    ly, (kpool, vpool, ppos) = _stack(layer, (kpool, vpool, ppos))
 
     n_live = _row_live_pages(q_pos, block_tables, page)
 
@@ -481,18 +540,13 @@ def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
 
     kernel = functools.partial(_paged_mixed_kernel, scale=scale,
                                attn_softcap=attn_softcap, window=window,
-                               npages=npages, g=g)
+                               npages=npages, dump=dump, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, npages),
         in_specs=[
             pl.BlockSpec((1, K1, Hq, D), lambda b, j, *pf: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
-            pl.BlockSpec((1, 1, page),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
+            *_pool_specs(kpool, vpool, None, page_of),
             pl.BlockSpec((1, K1, 1), lambda b, j, *pf: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, K1, Hq, Dv),
@@ -508,7 +562,7 @@ def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(n_live, block_tables, q, kpool, vpool, _page_positions(ppos),
+    )(ly, n_live, block_tables, q, kpool, vpool, _positions(ppos),
       q_pos.reshape(B, -1, 1))
     return out
 
@@ -517,10 +571,10 @@ def paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos, *,
 paged_verify_attention = paged_mixed_attention
 
 
-def _paged_mixed_kernel_q8(nl_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
-                           vs_ref, kp_ref, qp_ref, o_ref, m_scr, l_scr,
-                           acc_scr, *, scale, attn_softcap, window, npages,
-                           g):
+def _paged_mixed_kernel_q8(ly_ref, nl_ref, bt_ref, q_ref, k_ref, ks_ref,
+                           v_ref, vs_ref, kp_ref, qp_ref, o_ref, m_scr,
+                           l_scr, acc_scr, *, scale, attn_softcap, window,
+                           npages, dump, g):
     """Quantized-pool mixed kernel: int8 page tiles + per-entry scale
     rows dequantized in-register (exactly _paged_kernel_q8's stream)
     feeding the multi-query online-softmax body."""
@@ -538,11 +592,8 @@ def _paged_mixed_kernel_q8(nl_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
     @_on_live_page(j, nl_ref[b])
     def _step():
         q = q_ref[0].astype(jnp.float32)                   # (K1, Hq, D)
-        k = k_ref[0].astype(jnp.float32) \
-            * ks_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, D)
-        v = v_ref[0].astype(jnp.float32) \
-            * vs_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, Dv)
-        kp = kp_ref[0]                                     # (1, page)
+        k, v = _page_kv(q_ref, k_ref, v_ref, ks_ref, vs_ref)
+        kp = _page_pos(kp_ref, bt_ref, b, j, nl_ref[b], dump)  # (1, page)
         qp = qp_ref[0]                                     # (K1, 1)
         mask = _mq_mask(kp, qp, bt_ref[b, j] >= 0, window)
         qg = q.reshape(K1, Hkv, g, D).transpose(1, 0, 2, 3)
@@ -561,7 +612,7 @@ def _paged_mixed_kernel_q8(nl_ref, bt_ref, q_ref, k_ref, ks_ref, v_ref,
 @functools.partial(jax.jit, static_argnames=("window", "scale",
                                              "attn_softcap", "interpret"))
 def paged_mixed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
-                             block_tables, q_pos, *,
+                             block_tables, q_pos, layer=None, *,
                              window: Optional[int], scale: float,
                              attn_softcap: Optional[float] = None,
                              interpret: bool = False):
@@ -570,10 +621,12 @@ def paged_mixed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
     -1 marks padding queries exactly like the fp entry, and each row
     streams only its live pages)."""
     B, K1, Hq, D = q.shape
-    P, page, Hkv, Dv = vpool.shape
+    P, page, Hkv, Dv = vpool.shape[-4:]
     npages = block_tables.shape[1]
     g = Hq // Hkv
     dump = P - 1
+    ly, (kpool, k_scale, vpool, v_scale, ppos) = _stack(
+        layer, (kpool, k_scale, vpool, v_scale, ppos))
 
     n_live = _row_live_pages(q_pos, block_tables, page)
 
@@ -583,22 +636,13 @@ def paged_mixed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
 
     kernel = functools.partial(_paged_mixed_kernel_q8, scale=scale,
                                attn_softcap=attn_softcap, window=window,
-                               npages=npages, g=g)
+                               npages=npages, dump=dump, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, npages),
         in_specs=[
             pl.BlockSpec((1, K1, Hq, D), lambda b, j, *pf: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
-            pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
-            pl.BlockSpec((1, 1, page),
-                         lambda b, j, *pf: (page_of(b, j, *pf), 0, 0)),
+            *_pool_specs(kpool, vpool, k_scale, page_of),
             pl.BlockSpec((1, K1, 1), lambda b, j, *pf: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, K1, Hq, Dv),
@@ -614,8 +658,8 @@ def paged_mixed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K1, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(n_live, block_tables, q, kpool, k_scale, vpool, v_scale,
-      _page_positions(ppos), q_pos.reshape(B, -1, 1))
+    )(ly, n_live, block_tables, q, kpool, k_scale, vpool, v_scale,
+      _positions(ppos), q_pos.reshape(B, -1, 1))
     return out
 
 
@@ -631,7 +675,7 @@ PACKED_VMEM_BYTES = 8 * 1024 * 1024
 def paged_packed_shape_supported(q, kpool, block_tables,
                                  meta=None) -> bool:
     _, T, Hq, D = q.shape
-    page, Hkv, Dv = kpool.shape[1], kpool.shape[2], kpool.shape[3]
+    page, Hkv, Dv = kpool.shape[-3:]
     return (q.shape[0] == 1 and T >= PACKED_BLOCK_Q
             and T % PACKED_BLOCK_Q == 0 and Hq % Hkv == 0
             and D % 8 == 0 and Dv % 8 == 0 and page % 8 == 0
@@ -671,9 +715,9 @@ def packed_live_pages(meta, q_pos, page: int, npages: int):
     return live_pages(jnp.where(meta[:, 0] >= 0, last, -1), page, npages)
 
 
-def _paged_packed_kernel(nl_ref, meta_ref, bt_ref, q_ref, k_ref, v_ref,
-                         kp_ref, qp_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                         scale, attn_softcap, window, npages, g):
+def _paged_packed_kernel(ly_ref, nl_ref, meta_ref, bt_ref, q_ref, k_ref,
+                         v_ref, kp_ref, qp_ref, o_ref, m_scr, l_scr, acc_scr,
+                         *, scale, attn_softcap, window, npages, dump, g):
     """Token-packed ragged variant of _paged_mixed_kernel: the grid's
     first dim walks *query windows* of the flat (1, T) stream instead of
     slots.  Work item w covers PACKED_BLOCK_Q stream lanes starting at
@@ -708,9 +752,9 @@ def _paged_packed_kernel(nl_ref, meta_ref, bt_ref, q_ref, k_ref, v_ref,
     @_on_live_page(j, nl_ref[w])
     def _step():
         q = q_ref[0, pl.ds(offc, bq)].astype(jnp.float32)  # (bq, Hq, D)
-        k = k_ref[0].astype(jnp.float32)                   # (page, Hkv, D)
-        v = v_ref[0].astype(jnp.float32)                   # (page, Hkv, Dv)
-        kp = kp_ref[0]                                     # (1, page)
+        k, v = _page_kv(q_ref, k_ref, v_ref)             # (page, Hkv, D[v])
+        kp = _page_pos(kp_ref, bt_ref, jnp.maximum(slot, 0), j, nl_ref[w],
+                       dump)                               # (1, page)
         qp = qp_ref[pl.ds(offc, bq)]                       # (bq, 1)
         qp_eff = jnp.where(in_win, qp, -1)
         allocated = (slot >= 0) & (bt_ref[jnp.maximum(slot, 0), j] >= 0)
@@ -733,7 +777,8 @@ def _paged_packed_kernel(nl_ref, meta_ref, bt_ref, q_ref, k_ref, v_ref,
 @functools.partial(jax.jit, static_argnames=("window", "scale",
                                              "attn_softcap", "interpret"))
 def paged_packed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
-                           meta, *, window: Optional[int], scale: float,
+                           meta, layer=None, *, window: Optional[int],
+                           scale: float,
                            attn_softcap: Optional[float] = None,
                            interpret: bool = False):
     """Token-packed ragged attention over a paged KV pool: the whole
@@ -749,14 +794,16 @@ def paged_packed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
     earlier tokens of its own chunk.  The grid spans (n_work, npages),
     but work row w streams and attends only the live pages of its
     window's last lane (:func:`packed_live_pages`); an unused row
-    (slot -1) streams nothing."""
+    (slot -1) streams nothing.  Pools are stacked and read at ``layer``
+    as in :func:`paged_decode_attention`."""
     _, T, Hq, D = q.shape
-    P, page, Hkv, Dv = vpool.shape
+    P, page, Hkv, Dv = vpool.shape[-4:]
     npages = block_tables.shape[1]
     n_work = meta.shape[0]
     g = Hq // Hkv
     dump = P - 1
     bq = PACKED_BLOCK_Q
+    ly, (kpool, vpool, ppos) = _stack(layer, (kpool, vpool, ppos))
 
     n_live = packed_live_pages(meta, q_pos, page, npages)
 
@@ -766,21 +813,13 @@ def paged_packed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
 
     kernel = functools.partial(_paged_packed_kernel, scale=scale,
                                attn_softcap=attn_softcap, window=window,
-                               npages=npages, g=g)
+                               npages=npages, dump=dump, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(n_work, npages),
         in_specs=[
             pl.BlockSpec((1, T, Hq, D), lambda w, j, *pf: (0, 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda w, j, *pf: (page_of(w, j, *pf),
-                                                 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda w, j, *pf: (page_of(w, j, *pf),
-                                                 0, 0, 0)),
-            pl.BlockSpec((1, 1, page),
-                         lambda w, j, *pf: (page_of(w, j, *pf),
-                                                 0, 0)),
+            *_pool_specs(kpool, vpool, None, page_of),
             pl.BlockSpec((T, 1), lambda w, j, *pf: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, T, Hq, Dv),
@@ -796,16 +835,16 @@ def paged_packed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, T, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(n_live, meta, block_tables, q, kpool, vpool, _page_positions(ppos),
+    )(ly, n_live, meta, block_tables, q, kpool, vpool, _positions(ppos),
       q_pos.reshape(T, 1))
     # lanes no window owns (stream padding) are never written: zero them
     return jnp.where((q_pos >= 0)[..., None, None], out, 0)
 
 
-def _paged_packed_kernel_q8(nl_ref, meta_ref, bt_ref, q_ref, k_ref, ks_ref,
-                            v_ref, vs_ref, kp_ref, qp_ref, o_ref, m_scr,
-                            l_scr, acc_scr, *, scale, attn_softcap, window,
-                            npages, g):
+def _paged_packed_kernel_q8(ly_ref, nl_ref, meta_ref, bt_ref, q_ref, k_ref,
+                            ks_ref, v_ref, vs_ref, kp_ref, qp_ref, o_ref,
+                            m_scr, l_scr, acc_scr, *, scale, attn_softcap,
+                            window, npages, dump, g):
     """Quantized-pool packed kernel: int8 page tiles + per-entry scale
     rows dequantized in-register, feeding the same windowed
     online-softmax body as _paged_packed_kernel."""
@@ -830,11 +869,9 @@ def _paged_packed_kernel_q8(nl_ref, meta_ref, bt_ref, q_ref, k_ref, ks_ref,
     @_on_live_page(j, nl_ref[w])
     def _step():
         q = q_ref[0, pl.ds(offc, bq)].astype(jnp.float32)  # (bq, Hq, D)
-        k = k_ref[0].astype(jnp.float32) \
-            * ks_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, D)
-        v = v_ref[0].astype(jnp.float32) \
-            * vs_ref[0].astype(jnp.float32)[..., None]     # (page, Hkv, Dv)
-        kp = kp_ref[0]                                     # (1, page)
+        k, v = _page_kv(q_ref, k_ref, v_ref, ks_ref, vs_ref)
+        kp = _page_pos(kp_ref, bt_ref, jnp.maximum(slot, 0), j, nl_ref[w],
+                       dump)                               # (1, page)
         qp = qp_ref[pl.ds(offc, bq)]                       # (bq, 1)
         qp_eff = jnp.where(in_win, qp, -1)
         allocated = (slot >= 0) & (bt_ref[jnp.maximum(slot, 0), j] >= 0)
@@ -857,7 +894,7 @@ def _paged_packed_kernel_q8(nl_ref, meta_ref, bt_ref, q_ref, k_ref, ks_ref,
 @functools.partial(jax.jit, static_argnames=("window", "scale",
                                              "attn_softcap", "interpret"))
 def paged_packed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
-                              block_tables, q_pos, meta, *,
+                              block_tables, q_pos, meta, layer=None, *,
                               window: Optional[int], scale: float,
                               attn_softcap: Optional[float] = None,
                               interpret: bool = False):
@@ -865,12 +902,14 @@ def paged_packed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
     scale-pool contract as :func:`paged_decode_attention_q8`; each work
     row streams and dequantizes only its live pages)."""
     _, T, Hq, D = q.shape
-    P, page, Hkv, Dv = vpool.shape
+    P, page, Hkv, Dv = vpool.shape[-4:]
     npages = block_tables.shape[1]
     n_work = meta.shape[0]
     g = Hq // Hkv
     dump = P - 1
     bq = PACKED_BLOCK_Q
+    ly, (kpool, k_scale, vpool, v_scale, ppos) = _stack(
+        layer, (kpool, k_scale, vpool, v_scale, ppos))
 
     n_live = packed_live_pages(meta, q_pos, page, npages)
 
@@ -880,27 +919,13 @@ def paged_packed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
 
     kernel = functools.partial(_paged_packed_kernel_q8, scale=scale,
                                attn_softcap=attn_softcap, window=window,
-                               npages=npages, g=g)
+                               npages=npages, dump=dump, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(n_work, npages),
         in_specs=[
             pl.BlockSpec((1, T, Hq, D), lambda w, j, *pf: (0, 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda w, j, *pf: (page_of(w, j, *pf),
-                                                 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv),
-                         lambda w, j, *pf: (page_of(w, j, *pf),
-                                                 0, 0)),
-            pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda w, j, *pf: (page_of(w, j, *pf),
-                                                 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv),
-                         lambda w, j, *pf: (page_of(w, j, *pf),
-                                                 0, 0)),
-            pl.BlockSpec((1, 1, page),
-                         lambda w, j, *pf: (page_of(w, j, *pf),
-                                                 0, 0)),
+            *_pool_specs(kpool, vpool, k_scale, page_of),
             pl.BlockSpec((T, 1), lambda w, j, *pf: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, T, Hq, Dv),
@@ -916,8 +941,8 @@ def paged_packed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, T, Hq, Dv), q.dtype),
         interpret=interpret,
-    )(n_live, meta, block_tables, q, kpool, k_scale, vpool, v_scale,
-      _page_positions(ppos), q_pos.reshape(T, 1))
+    )(ly, n_live, meta, block_tables, q, kpool, k_scale, vpool, v_scale,
+      _positions(ppos), q_pos.reshape(T, 1))
     return jnp.where((q_pos >= 0)[..., None, None], out, 0)
 
 

@@ -125,7 +125,9 @@ def maybe_decode_attention(q, k, v, k_pos, q_pos, *, window, scale,
 
 def maybe_paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos,
                                  *, window, scale, attn_softcap=None,
-                                 k_scale=None, v_scale=None):
+                                 k_scale=None, v_scale=None, layer=None):
+    """Single-query paged attention; with ``layer`` the pools are whole
+    layer stacks read in place at that layer."""
     mode = kernel_mode()
     if mode == "off":
         return None
@@ -137,18 +139,18 @@ def maybe_paged_decode_attention(q, kpool, vpool, ppos, block_tables, q_pos,
         return _ran("paged_decode_attention_q8",
                     DA.paged_decode_attention_q8(
                         q, kpool, k_scale, vpool, v_scale, ppos,
-                        block_tables, q_pos, window=window, scale=scale,
-                        attn_softcap=attn_softcap,
+                        block_tables, q_pos, layer, window=window,
+                        scale=scale, attn_softcap=attn_softcap,
                         interpret=(mode == "interpret")))
     return _ran("paged_decode_attention", DA.paged_decode_attention(
-        q, kpool, vpool, ppos, block_tables, q_pos, window=window,
+        q, kpool, vpool, ppos, block_tables, q_pos, layer, window=window,
         scale=scale, attn_softcap=attn_softcap,
         interpret=(mode == "interpret")))
 
 
 def maybe_paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
                                 *, window, scale, attn_softcap=None,
-                                k_scale=None, v_scale=None):
+                                k_scale=None, v_scale=None, layer=None):
     """Multi-query paged attention with per-slot variable query counts:
     q (B, W, Hq, D) / q_pos (B, W) score a whole per-slot window —
     prefill chunk, speculation window, or a lone decode token — in one
@@ -163,11 +165,11 @@ def maybe_paged_mixed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
         return _ran("paged_mixed_attention_q8",
                     DA.paged_mixed_attention_q8(
                         q, kpool, k_scale, vpool, v_scale, ppos,
-                        block_tables, q_pos, window=window, scale=scale,
-                        attn_softcap=attn_softcap,
+                        block_tables, q_pos, layer, window=window,
+                        scale=scale, attn_softcap=attn_softcap,
                         interpret=(mode == "interpret")))
     return _ran("paged_mixed_attention", DA.paged_mixed_attention(
-        q, kpool, vpool, ppos, block_tables, q_pos, window=window,
+        q, kpool, vpool, ppos, block_tables, q_pos, layer, window=window,
         scale=scale, attn_softcap=attn_softcap,
         interpret=(mode == "interpret")))
 
@@ -179,7 +181,7 @@ maybe_paged_verify_attention = maybe_paged_mixed_attention
 def maybe_paged_packed_attention(q, kpool, vpool, ppos, block_tables,
                                  q_pos, meta, *, window, scale,
                                  attn_softcap=None, k_scale=None,
-                                 v_scale=None):
+                                 v_scale=None, layer=None):
     """Token-packed ragged paged attention: q (1, T, Hq, D) is one flat
     stream covering every slot's decode token and prefill-chunk tokens;
     ``meta`` is the (n_work, 4) query-window table from
@@ -196,12 +198,12 @@ def maybe_paged_packed_attention(q, kpool, vpool, ppos, block_tables,
         return _ran("paged_packed_attention_q8",
                     DA.paged_packed_attention_q8(
                         q, kpool, k_scale, vpool, v_scale, ppos,
-                        block_tables, q_pos, meta, window=window,
+                        block_tables, q_pos, meta, layer, window=window,
                         scale=scale, attn_softcap=attn_softcap,
                         interpret=(mode == "interpret")))
     return _ran("paged_packed_attention", DA.paged_packed_attention(
-        q, kpool, vpool, ppos, block_tables, q_pos, meta, window=window,
-        scale=scale, attn_softcap=attn_softcap,
+        q, kpool, vpool, ppos, block_tables, q_pos, meta, layer,
+        window=window, scale=scale, attn_softcap=attn_softcap,
         interpret=(mode == "interpret")))
 
 

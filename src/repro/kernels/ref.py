@@ -45,19 +45,20 @@ def decode_attention_ref(q, k, v, k_pos, q_pos, *, window: Optional[int],
 def paged_decode_attention_ref(q, kpool, vpool, ppos, block_tables, q_pos, *,
                                window: Optional[int], scale: float,
                                attn_softcap: Optional[float] = None,
-                               k_scale=None, v_scale=None):
+                               k_scale=None, v_scale=None, layer=None):
     """Dense-gather oracle for the paged decode kernel: resolve each slot's
     block table into a contiguous (B, npages*page, ...) view (the same
     ``kv_cache.paged_gather`` the production fallback uses), then run the
     dense decode reference.  With ``k_scale``/``v_scale`` the pools hold
     int8 codes and the gather dequantizes them — the fp32 target the
-    fused-dequant Pallas kernel must match."""
+    fused-dequant Pallas kernel must match.  With ``layer`` the pools are
+    whole layer stacks, gathered from at that layer."""
     from repro.core.kv_cache import paged_gather
     pool = {"pk": kpool, "pv": vpool, "ppos": ppos}
     if k_scale is not None:
         pool["pk_scale"] = k_scale
         pool["pv_scale"] = v_scale
-    k, v, kp = paged_gather(pool, block_tables)
+    k, v, kp = paged_gather(pool, block_tables, layer)
     return decode_attention_ref(q, k.astype(q.dtype), v.astype(q.dtype),
                                 kp, q_pos, window=window,
                                 scale=scale, attn_softcap=attn_softcap)
@@ -66,7 +67,7 @@ def paged_decode_attention_ref(q, kpool, vpool, ppos, block_tables, q_pos, *,
 def paged_mixed_attention_ref(q, kpool, vpool, ppos, block_tables, q_pos,
                               *, window: Optional[int], scale: float,
                               attn_softcap: Optional[float] = None,
-                              k_scale=None, v_scale=None):
+                              k_scale=None, v_scale=None, layer=None):
     """Oracle for the multi-query paged *mixed* kernel (chunked prefill
     rows, decode rows, speculative verify windows): q (B, W, Hq, D) with
     per-slot query counts expressed through q_pos (B, W) — real queries
@@ -78,7 +79,7 @@ def paged_mixed_attention_ref(q, kpool, vpool, ppos, block_tables, q_pos,
     return paged_decode_attention_ref(
         q, kpool, vpool, ppos, block_tables, q_pos, window=window,
         scale=scale, attn_softcap=attn_softcap, k_scale=k_scale,
-        v_scale=v_scale)
+        v_scale=v_scale, layer=layer)
 
 
 # speculative verify = the mixed oracle with every row's window full
@@ -89,7 +90,7 @@ def paged_packed_attention_ref(q, kpool, vpool, ppos, block_tables, q_pos,
                                slot_ids, *, window: Optional[int],
                                scale: float,
                                attn_softcap: Optional[float] = None,
-                               k_scale=None, v_scale=None):
+                               k_scale=None, v_scale=None, layer=None):
     """Oracle for the token-packed ragged kernel: q (1, T, Hq, D) is one
     flat stream where token t belongs to slot ``slot_ids[t]`` and attends
     that slot's paged history only.  Gathers each *slot's* pages densely
@@ -103,7 +104,7 @@ def paged_packed_attention_ref(q, kpool, vpool, ppos, block_tables, q_pos,
     if k_scale is not None:
         pool["pk_scale"] = k_scale
         pool["pv_scale"] = v_scale
-    k, v, kp = paged_gather(pool, block_tables)     # (B, ctx, H, D)
+    k, v, kp = paged_gather(pool, block_tables, layer)   # (B, ctx, H, D)
     B = block_tables.shape[0]
     _, T, Hq, _ = q.shape
     sid = slot_ids.reshape(T)

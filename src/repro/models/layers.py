@@ -173,7 +173,7 @@ def mha_attention(q, k, v, q_pos, k_pos, *, window: Optional[int],
 
 def mha_attention_paged(q, pool, block_tables, q_pos, *,
                         window: Optional[int], scale: float,
-                        attn_softcap: Optional[float] = None):
+                        attn_softcap: Optional[float] = None, layer=None):
     """Decode / mixed-window attention against a paged KV pool
     (continuous batching).
 
@@ -186,7 +186,10 @@ def mha_attention_paged(q, pool, block_tables, q_pos, *,
     causal masking exact); pool: {"pk"/"pv": (P,page,Hkv,D), "ppos":
     (P,page)}, plus "pk_scale"/"pv_scale" (P,page,Hkv) when the pool
     stores int8; block_tables: (B, pages_per_slot) physical page ids
-    (-1 = none).
+    (-1 = none).  With ``layer`` the pool's leaves are whole layer
+    stacks (a leading layer dim), read in place at that layer.  A pool
+    whose rows are widened to whole lanes
+    (``kv_cache.paged_layer_cache_shape``) is read to q's head dim.
 
     Dispatch: paged Pallas kernel (single- or multi-query variant;
     gathers pages in-kernel via scalar-prefetched block tables; int8
@@ -200,10 +203,11 @@ def mha_attention_paged(q, pool, block_tables, q_pos, *,
     out = dispatch(
         q, pool["pk"], pool["pv"], pool["ppos"], block_tables, q_pos,
         window=window, scale=scale, attn_softcap=attn_softcap,
-        k_scale=pool.get("pk_scale"), v_scale=pool.get("pv_scale"))
+        k_scale=pool.get("pk_scale"), v_scale=pool.get("pv_scale"),
+        layer=layer)
     if out is not None:
-        return out
-    kk, vv, kp = KV.paged_gather(pool, block_tables)
+        return out[..., :q.shape[-1]]
+    kk, vv, kp = KV.paged_gather(pool, block_tables, layer, q.shape[-1])
     return mha_attention(q, kk.astype(q.dtype), vv.astype(q.dtype),
                          q_pos, kp, window=window, scale=scale,
                          attn_softcap=attn_softcap)
@@ -211,7 +215,8 @@ def mha_attention_paged(q, pool, block_tables, q_pos, *,
 
 def mha_attention_paged_packed(q, pool, block_tables, q_pos, slot_ids,
                                meta, *, window: Optional[int], scale: float,
-                               attn_softcap: Optional[float] = None):
+                               attn_softcap: Optional[float] = None,
+                               layer=None):
     """Token-packed ragged attention against a paged KV pool: one flat
     (1, T) query stream covering every slot's decode token and
     prefill-chunk tokens for a whole scheduler iteration.
@@ -221,7 +226,8 @@ def mha_attention_paged_packed(q, pool, block_tables, q_pos, slot_ids,
     padding); meta: kernel work table from
     ``decode_attention.packed_meta_table`` (may be None — fallback only);
     block_tables: (slots, pages_per_slot).  The stream's own K/V must
-    already be in the pool (``kv_cache.paged_write_packed``).
+    already be in the pool (``kv_cache.paged_write_packed``); ``layer``
+    as in :func:`mha_attention_paged`.
 
     Dispatch: packed Pallas kernel -> per-token dense gather + the same
     ``mha_attention`` reference the bucketed per-slot fallback uses.
@@ -235,10 +241,12 @@ def mha_attention_paged_packed(q, pool, block_tables, q_pos, slot_ids,
     out = kops.maybe_paged_packed_attention(
         q, pool["pk"], pool["pv"], pool["ppos"], block_tables, q_pos,
         meta, window=window, scale=scale, attn_softcap=attn_softcap,
-        k_scale=pool.get("pk_scale"), v_scale=pool.get("pv_scale"))
+        k_scale=pool.get("pk_scale"), v_scale=pool.get("pv_scale"),
+        layer=layer)
     if out is not None:
-        return out
-    kk, vv, kp = KV.paged_gather(pool, block_tables)   # (slots, ctx, H, D)
+        return out[..., :q.shape[-1]]
+    kk, vv, kp = KV.paged_gather(pool, block_tables, layer,
+                                 q.shape[-1])           # (slots, ctx, H, D)
     B = block_tables.shape[0]
     _, T, Hq, _ = q.shape
     safe = jnp.clip(slot_ids, 0, B - 1)

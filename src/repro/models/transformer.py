@@ -3,7 +3,8 @@
 Every assigned architecture — dense, MoE(+MLA), xLSTM, Hymba hybrid, VLM and
 audio backbones — is this one module driven by its :class:`ModelConfig`.
 Layer stacks run as ``lax.scan`` over pattern repeats (HLO stays the size of
-one pattern), with caches carried as scan xs/ys for prefill/decode.
+one pattern), with dense caches carried as scan xs/ys for prefill/decode and
+paged pools whole in the scan's carry, updated in place.
 
 Entry points:
     init_params(rng, cfg, policy)
@@ -135,20 +136,21 @@ def cache_struct(cfg: ModelConfig, batch: int, max_len: int,
 
 def init_paged_cache(cfg: ModelConfig, *, num_pages: int, page_size: int,
                      max_slots: int, max_len: int, dtype=jnp.bfloat16,
-                     kv_dtype: str = "auto"):
+                     kv_dtype: str = "auto", lanes: int = 1):
     """Paged-pool model cache for continuous batching: attention layers
     share ``num_pages`` fixed-size pages (+1 reserved dump page) indexed
     through per-slot block tables; MLA / recurrent layers keep dense
     per-slot state.  Same stacked-over-repeats layout as init_cache.
-    ``kv_dtype`` selects pool storage (int8 adds per-entry scale pools;
-    see ``kv_cache.paged_layer_cache_shape``)."""
+    ``kv_dtype`` selects pool storage (int8 adds per-entry scale pools)
+    and ``lanes`` the multiple its rows are widened to (see
+    ``kv_cache.paged_layer_cache_shape``)."""
     layers = []
     for stack in cfg.stacks:
         per_pos = []
         for spec in stack.pattern:
             one = KV.paged_layer_cache_shape(cfg, spec, num_pages, page_size,
                                              max_slots, max_len, dtype,
-                                             kv_dtype=kv_dtype)
+                                             kv_dtype=kv_dtype, lanes=lanes)
             per_pos.append(jax.tree.map(
                 lambda a, r=stack.repeats: jnp.tile(
                     a[None], (r,) + (1,) * a.ndim), one))
@@ -170,7 +172,11 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions,
     attend_cache: prefill continues from a pre-filled cache (prefix
     caching) — queries attend to cache contents, not just in-context k/v.
     paged: {"block_tables": (B, pages), "active": (B,) bool | None} when
-    the cache uses the paged pool layout (continuous batching).
+    the cache uses the paged pool layout (continuous batching); with
+    "layer", the cache's pool leaves are the layer stack's whole pools,
+    written and read in place at that layer; "cow": (src, dst,
+    keep_below) copy-on-write tail pages, copied in the layer before it
+    writes (``kv_cache.copy_pages``).
     """
     aux = jnp.zeros((), jnp.float32)
     B, S, _ = x.shape
@@ -200,17 +206,20 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions,
         scale = L.attn_scale(cfg)
         if is_paged:
             bt = paged["block_tables"]
+            lyr = paged.get("layer")
             pool = {n: cache[n] for n in KV.PAGED_KEYS if n in cache}
+            if paged.get("cow") is not None:
+                pool = KV.copy_pages(pool, *paged["cow"], layer=lyr)
             quant = "pk_scale" in pool
-            ring = KV.paged_ring_len(window, pool["ppos"].shape[1],
+            ring = KV.paged_ring_len(window, pool["ppos"].shape[-1],
                                      bt.shape[1])
             if mode == "decode":
                 c_attn = KV.paged_write_decode(
                     pool, {"k": k, "v": v}, positions[:, 0], bt,
-                    paged.get("active"), ring_len=ring)
+                    paged.get("active"), ring_len=ring, layer=lyr)
                 ctx = L.mha_attention_paged(
                     q, c_attn, bt, positions, window=window, scale=scale,
-                    attn_softcap=cfg.attn_softcap)
+                    attn_softcap=cfg.attn_softcap, layer=lyr)
             elif mode == "verify":
                 # speculative window: write the pending + drafted tokens'
                 # K/V (positions[:, 0] .. positions[:, 0] + K), THEN
@@ -220,10 +229,10 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions,
                 # (kv_cache.paged_truncate).
                 c_attn = KV.paged_write_decode_multi(
                     pool, {"k": k, "v": v}, positions[:, 0], bt,
-                    paged.get("active"), ring_len=ring)
+                    paged.get("active"), ring_len=ring, layer=lyr)
                 ctx = L.mha_attention_paged(
                     q, c_attn, bt, positions, window=window, scale=scale,
-                    attn_softcap=cfg.attn_softcap)
+                    attn_softcap=cfg.attn_softcap, layer=lyr)
             elif mode == "packed":
                 # token-packed ragged stream: scatter every lane's K/V
                 # into its OWN slot's pages, then attend each lane to its
@@ -231,11 +240,11 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions,
                 # per lane via slot_ids, not per row).
                 c_attn = KV.paged_write_packed(
                     pool, {"k": k, "v": v}, paged["slot_ids"],
-                    positions[0], bt, ring_len=ring)
+                    positions[0], bt, ring_len=ring, layer=lyr)
                 ctx = L.mha_attention_paged_packed(
                     q, c_attn, bt, positions, paged["slot_ids"],
                     paged.get("packed_meta"), window=window, scale=scale,
-                    attn_softcap=cfg.attn_softcap)
+                    attn_softcap=cfg.attn_softcap, layer=lyr)
             elif attend_cache or (quant and window is None):
                 # prefix-cached admission: the prompt's suffix is written
                 # into this request's own pages first, then queries attend
@@ -250,8 +259,9 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions,
                 # that decode will later read, which keeps shared-prefix
                 # int8 serving bit-identical to unshared int8 serving.
                 c_attn = KV.paged_write_prefill(
-                    pool, {"k": k, "v": v}, cache_pos, bt, ring_len=ring)
-                kk, vv, kp = KV.paged_gather(c_attn, bt)
+                    pool, {"k": k, "v": v}, cache_pos, bt, ring_len=ring,
+                    layer=lyr)
+                kk, vv, kp = KV.paged_gather(c_attn, bt, lyr, q.shape[-1])
                 ctx = L.mha_attention(q, kk.astype(x.dtype),
                                       vv.astype(x.dtype), positions, kp,
                                       window=window, scale=scale,
@@ -261,7 +271,8 @@ def layer_apply(cfg: ModelConfig, spec: LayerSpec, p, x, *, positions,
                                       window=window, scale=scale,
                                       attn_softcap=cfg.attn_softcap)
                 c_attn = KV.paged_write_prefill(
-                    pool, {"k": k, "v": v}, cache_pos, bt, ring_len=ring)
+                    pool, {"k": k, "v": v}, cache_pos, bt, ring_len=ring,
+                    layer=lyr)
         elif mode == "decode":
             c_attn = {n: cache[n] for n in ("k", "v", "pos")}
             c_attn = KV.write_decode(c_attn, {"k": k, "v": v}, positions[:, 0])
@@ -392,9 +403,22 @@ def _run_stack(cfg, stack: Stack, stack_p, stack_c, x, *, positions,
                              if n in ffn}
                 stack_p[pi] = {**stack_p[pi], "ffn": ffn}
         stack_p = tuple(stack_p)
+    # likewise each paged pool rides whole in the scan's carry: a layer
+    # writes and reads its entries in place at [layer, ...], where a pool
+    # in xs/ys would be sliced out and written back whole every layer.
+    # Dense per-slot state (MLA, recurrent, a hybrid's SSM) stays in xs.
+    pools = {}
+    if has_cache:
+        stack_c = list(stack_c)
+        for pi, c in enumerate(stack_c):
+            if "pk" in c:
+                pools[pi] = {k: c[k] for k in KV.PAGED_KEYS if k in c}
+                stack_c[pi] = {k: v for k, v in c.items()
+                               if k not in KV.PAGED_KEYS}
+        stack_c = tuple(stack_c)
 
     def body(carry, xs):
-        xx, aux, moe = carry
+        xx, aux, moe, pools = carry
         if has_cache:
             p_r, c_r, layer = xs
         else:
@@ -402,26 +426,35 @@ def _run_stack(cfg, stack: Stack, stack_p, stack_c, x, *, positions,
             c_r = (None,) * len(stack.pattern)
         new_cs = []
         for pi, spec in enumerate(stack.pattern):
-            p_l = p_r[pi]
+            p_l, c_l, paged_l = p_r[pi], c_r[pi], paged
             if pi in whole:
                 p_l = {**p_l, "ffn": {**p_l["ffn"], **whole[pi],
                                       "layer": layer}}
+            if pi in pools:
+                c_l = {**c_l, **pools[pi]}
+                paged_l = {**paged, "layer": layer}
             xx, nc, a, mc = layer_apply(
                 cfg, spec, p_l, xx, positions=positions,
-                cache_pos=cache_pos, cache=c_r[pi], mode=mode,
-                max_len=max_len, attend_cache=attend_cache, paged=paged)
+                cache_pos=cache_pos, cache=c_l, mode=mode,
+                max_len=max_len, attend_cache=attend_cache, paged=paged_l)
+            if pi in pools:
+                pools = {**pools, pi: {k: nc[k] for k in pools[pi]}}
+                nc = {k: v for k, v in nc.items() if k not in pools[pi]}
             new_cs.append(nc)
             aux = aux + a
             moe = MOE.merge_counts(moe, mc)
-        return (xx, aux, moe), (tuple(new_cs) if has_cache else None)
+        return (xx, aux, moe, pools), (tuple(new_cs) if has_cache else None)
 
     if remat and mode == "train":
         body = jax.checkpoint(body)
 
     xs = ((stack_p, stack_c, jnp.arange(stack.repeats)) if has_cache
           else (stack_p,))
-    (x, aux, moe), new_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32), MOE.no_counts()), xs)
+    (x, aux, moe, pools), new_cache = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), MOE.no_counts(), pools), xs)
+    if pools:
+        new_cache = tuple({**c, **pools.get(pi, {})}
+                          for pi, c in enumerate(new_cache))
     return x, new_cache, aux, moe
 
 

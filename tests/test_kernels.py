@@ -11,6 +11,7 @@ from repro.kernels import flash_attention as FA
 from repro.kernels import ops as KOPS
 from repro.kernels import ref as R
 from repro.kernels import rmsnorm as RN
+from repro.models import layers as LY
 
 
 def _rand(rng, shape, dtype):
@@ -399,3 +400,121 @@ def test_mlstm_dispatch_refuses_tpu_mode(rng):
           "m": jnp.zeros((1, 2))}
     with KOPS.kernel_mode_ctx("tpu"), pytest.raises(NotImplementedError):
         KOPS.maybe_mlstm_chunked(q, q, q, g, g, st)
+
+
+# ---------------------------------------------------------------------------
+# Stacked pools: each kernel reads its layer of the whole stack in place
+# ---------------------------------------------------------------------------
+
+STACK_LAYERS, STACK_PAGE, STACK_NPAGES = 3, 8, 4
+
+
+def _stack_setup(rng, pool):
+    """Four slots' block tables over a 3-layer stacked pool whose layers
+    differ: each holds its own K/V (codes and scales for ``pool`` q8)
+    and its own stored positions, a slot's context cut at a different
+    length in each layer.  Returns the pool dict of stacked leaves, the
+    same pool with its rows widened to whole lanes as the engine makes
+    it on a TPU (zeros past the head dim and the heads), the block
+    tables and each slot's last query position."""
+    L, page, npages = STACK_LAYERS, STACK_PAGE, STACK_NPAGES
+    B, P, Hkv, D = 4, 21, 2, 32
+    ctx = np.asarray([1, 12, 19, npages * page])
+    bt = np.full((B, npages), -1, np.int32)
+    free = iter(rng.permutation(P - 1))      # page P-1 stays the dump page
+    ppos = np.full((L, P, page), -1, np.int32)
+    for b in range(B):
+        bt[b, :-(-ctx[b] // page)] = [next(free)
+                                      for _ in range(-(-ctx[b] // page))]
+        for layer in range(L):
+            for t in range(max(ctx[b] - 3 * layer, 0)):
+                ppos[layer, bt[b, t // page], t % page] = t
+    k = jnp.asarray(rng.normal(size=(L, P, page, Hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(L, P, page, Hkv, D)), jnp.float32)
+    stack = {"ppos": jnp.asarray(ppos)}
+    if pool == "q8":
+        (stack["pk"], stack["pk_scale"]), (stack["pv"], stack["pv_scale"]) \
+            = KV.quantize_kv(k), KV.quantize_kv(v)
+    else:
+        stack["pk"], stack["pv"] = k, v
+    wide = {n: a if n == "ppos" else jnp.pad(
+        a, [(0, 0)] * (a.ndim - 1) + [(0, 128 - a.shape[-1])])
+        for n, a in stack.items()}
+    return stack, wide, jnp.asarray(bt), ctx - 1
+
+
+@pytest.mark.parametrize("layer", range(STACK_LAYERS))
+@pytest.mark.parametrize("pool", ["fp", "q8"])
+@pytest.mark.parametrize("kind", ["decode", "mixed", "packed"])
+def test_paged_kernels_read_their_layer_of_a_stack(rng, kind, pool, layer):
+    """Each paged kernel given the whole stacked pool, its rows widened
+    to whole lanes, and a layer index, and its jnp fallback
+    (``layers.mha_attention_paged[_packed]`` with kernels off), equals
+    the oracle run on that layer's slice; the kernel equals itself run
+    on the slice, and on the unwidened stack, bit for bit."""
+    Hq, D = 4, 32
+    narrow, stack, bt, q_last = _stack_setup(rng, pool)
+    one = {n: a[layer] for n, a in narrow.items()}
+    kw = dict(window=None, scale=D ** -0.5)
+    names = ("pk", "pk_scale", "pv", "pv_scale") if pool == "q8" \
+        else ("pk", "pv")
+
+    def pools(p):
+        return [p[n] for n in names] + [p["ppos"]]
+
+    def ref_kw(p):
+        return (dict(k_scale=p["pk_scale"], v_scale=p["pv_scale"])
+                if pool == "q8" else {})
+
+    suffix = "_q8" if pool == "q8" else ""
+    if kind == "packed":
+        seg_len = np.asarray([1, 3, 2, 4])
+        T_ = 16
+        slot_ids = np.full(T_, -1, np.int32)
+        q_pos = np.full(T_, -1, np.int32)
+        seg_start = np.concatenate([[0], np.cumsum(seg_len)[:-1]])
+        for b, (s, n) in enumerate(zip(seg_start, seg_len)):
+            slot_ids[s:s + n] = b
+            q_pos[s:s + n] = q_last[b] - n + 1 + np.arange(n)
+        meta = jnp.asarray(DA.packed_meta_table(
+            seg_start, seg_len, np.arange(4, dtype=np.int32), T_,
+            T_ // DA.PACKED_BLOCK_Q + 4))
+        q = jnp.asarray(rng.normal(size=(1, T_, Hq, D)), jnp.float32)
+        q_pos, slot_ids = jnp.asarray(q_pos[None]), jnp.asarray(slot_ids)
+        fn = getattr(DA, "paged_packed_attention" + suffix)
+
+        def run(p, lyr):
+            return fn(q, *pools(p), bt, q_pos, meta, lyr, interpret=True,
+                      **kw)
+
+        ref = R.paged_packed_attention_ref(
+            q, one["pk"], one["pv"], one["ppos"], bt, q_pos, slot_ids,
+            **ref_kw(one), **kw)
+        with KOPS.kernel_mode_ctx("off"):
+            fallback = LY.mha_attention_paged_packed(
+                q, stack, bt, q_pos, slot_ids, meta, layer=layer, **kw)
+    else:
+        W = 1 if kind == "decode" else 3
+        q_pos = q_last[:, None] - np.arange(W)[::-1][None, :]
+        q_pos = jnp.asarray(np.where(q_pos >= 0, q_pos, -1), jnp.int32)
+        q = jnp.asarray(rng.normal(size=(4, W, Hq, D)), jnp.float32)
+        fn = getattr(DA, f"paged_{kind}_attention" + suffix)
+
+        def run(p, lyr):
+            return fn(q, *pools(p), bt, q_pos, lyr, interpret=True, **kw)
+
+        ref = R.paged_mixed_attention_ref(
+            q, one["pk"], one["pv"], one["ppos"], bt, q_pos,
+            **ref_kw(one), **kw)
+        with KOPS.kernel_mode_ctx("off"):
+            fallback = LY.mha_attention_paged(q, stack, bt, q_pos,
+                                              layer=layer, **kw)
+    out = run(stack, layer)[..., :D]      # V's widened lanes read zero
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(fallback), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for p, lyr in (({n: a[layer] for n, a in stack.items()}, None),
+                   (narrow, layer)):
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(run(p, lyr)[..., :D]))

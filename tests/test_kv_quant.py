@@ -164,6 +164,74 @@ def test_paged_write_gather_roundtrip_int8(rng):
         assert (err <= bound + 1e-7).all()
 
 
+@pytest.mark.parametrize("lanes", [1, 128])
+@pytest.mark.parametrize("layer", [None, 1], ids=["one", "stacked"])
+@pytest.mark.parametrize("kind", ["prefill", "packed", "verify"])
+def test_paged_writes_land_entry_by_entry(rng, kind, layer, lanes):
+    """Each write of new tokens into an int8 pool, with many tokens on
+    one page and invalid tokens sent to the dump page, leaves every
+    valid token's codes and scales at its (page, offset), in the
+    layer it names of a stacked pool, zero-padded to the rows of a pool
+    widened to whole lanes, and every other entry as it was (the dump
+    page aside): what an entry-by-entry scatter leaves."""
+    P, page, H, D, L = 9, 4, 2, 8, 3
+    Dw, Hw = max(D, lanes), max(H, lanes)
+    rows = {"pk": (H, Dw), "pv": (H, Dw), "pk_scale": (Hw,),
+            "pv_scale": (Hw,), "ppos": ()}
+    pool = {k: jnp.asarray(rng.normal(size=(L, P, page) + rows[k]) * 3)
+            .astype(a.dtype) for k, a in _int8_pool(P, page, H, D).items()}
+    one = pool if layer is not None else {k: a[0] for k, a in pool.items()}
+    bt = np.asarray([[2, 5, 0], [7, 1, -1]], np.int32)
+    if kind == "prefill":
+        pos = np.asarray([[3, 4, 5, 6, 7, 8, 9, -1],
+                          [0, 1, 2, 3, 4, 5, -1, -1]], np.int32)
+        slots, new_pos = np.repeat([0, 1], 8), pos.reshape(-1)
+    elif kind == "packed":
+        slots = np.asarray([0, 0, 0, 0, 0, 1, 1, 1, 1, -1], np.int32)
+        new_pos = np.asarray([5, 6, 7, 8, 9, 2, 3, 4, 5, -1], np.int32)
+    else:
+        pos = np.asarray([6, 2], np.int32)
+        slots = np.repeat([0, 1], 3)
+        new_pos = (pos[:, None] + np.arange(3)).reshape(-1)
+    n = slots.shape[0]
+    k = rng.normal(size=(n, H, D)).astype(np.float32)
+    v = rng.normal(size=(n, H, D)).astype(np.float32)
+    ring = KV.paged_ring_len(None, page, bt.shape[1])
+    kw = dict(ring_len=ring, layer=layer)
+    if kind == "prefill":
+        out = KV.paged_write_prefill(
+            one, {"k": k.reshape(2, 8, H, D), "v": v.reshape(2, 8, H, D)},
+            jnp.asarray(pos), jnp.asarray(bt), **kw)
+    elif kind == "packed":
+        out = KV.paged_write_packed(
+            one, {"k": k[None], "v": v[None]}, jnp.asarray(slots),
+            jnp.asarray(new_pos), jnp.asarray(bt), **kw)
+    else:
+        out = KV.paged_write_decode_multi(
+            one, {"k": k.reshape(2, 3, H, D), "v": v.reshape(2, 3, H, D)},
+            jnp.asarray(pos), jnp.asarray(bt), **kw)
+    want = {key: np.array(a) for key, a in one.items()}
+    at = () if layer is None else (layer,)
+    for t in range(n):
+        if slots[t] < 0 or new_pos[t] < 0:
+            continue
+        phys, off = bt[slots[t], new_pos[t] // page], new_pos[t] % page
+        for key, val in (("pk", k[t]), ("pv", v[t])):
+            q, sc = KV.quantize_kv(jnp.asarray(val))
+            row = want[key][at + (phys, off)]
+            row[...] = 0
+            row[..., :D] = np.asarray(q)
+            row = want[key + "_scale"][at + (phys, off)]
+            row[...] = 0
+            row[:H] = np.asarray(sc)
+        want["ppos"][at + (phys, off)] = new_pos[t]
+    kept = np.arange(P - 1)                 # every page but the dump page
+    for key, a in out.items():
+        np.testing.assert_array_equal(np.take(np.asarray(a), kept, len(at)),
+                                      np.take(want[key], kept, len(at)),
+                                      err_msg=key)
+
+
 def test_copy_pages_carries_scales(rng):
     """A COW clone must copy scale rows with the int8 codes — otherwise
     the private tail page dequantizes with the wrong magnitudes."""
@@ -304,6 +372,38 @@ def test_int8_shared_prefix_bit_identical_to_unshared(rng):
     assert m_off.kv_bytes_per_token < 0.5 * m_fp.kv_bytes_per_token
     assert 0 < m_off.kv_pool_bytes < m_fp.kv_pool_bytes
     assert m_off.peak_pages_in_use > 0
+
+
+@pytest.mark.parametrize("policy", [FP32, INT8], ids=["fp", "q8"])
+def test_pool_widened_to_lanes_serves_the_same_tokens(rng, monkeypatch,
+                                                      policy):
+    """A pool whose rows are widened to whole lanes, as the engine makes
+    it on a TPU (``kv_cache.pool_lanes``), serves the tokens of the
+    unwidened pool, through the paged kernels and through the gather
+    fallback, prefix sharing and copy-on-write tails included."""
+    from repro.kernels import ops as KOPS
+    cfg = get_reduced("qwen3-4b")
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    prefix = list(map(int, rng.integers(4, 400, size=13)))
+    reqs = [Request(uid=i, tokens=[2] + prefix + list(map(int, rng.integers(
+        4, 400, size=ln))), max_new_tokens=4)
+        for i, ln in enumerate([3, 6, 2])]
+
+    def serve(mode):
+        eng = InferenceEngine(cfg, params, policy=policy, max_len=64,
+                              max_batch=2)
+        with KOPS.kernel_mode_ctx(mode):
+            done, m = eng.serve_continuous(copy.deepcopy(reqs), page_size=8,
+                                           steps_per_sync=2)
+        return [r.result for r in done], m
+
+    base, _ = serve("off")
+    monkeypatch.setattr(KV, "pool_lanes", lambda device: 128)
+    for mode in ("off", "interpret"):
+        got, m = serve(mode)
+        assert got == base, mode
+        assert m.cow_copies > 0
+    assert all(base)
 
 
 def test_int8_serving_stays_close_to_fp(rng):

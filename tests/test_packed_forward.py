@@ -35,6 +35,7 @@ from repro.core.engine import InferenceEngine
 from repro.core.precision import FP32
 from repro.core.scheduler import Request
 from repro.kernels import decode_attention as DA
+from repro.kernels import ops as KOPS
 from repro.kernels import ref as R
 from repro.models import transformer as T
 
@@ -277,6 +278,149 @@ def test_forward_packed_matches_decode_and_prefill(rng):
                                np.asarray(lg_d[0, 0]), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(lg_pk[0, 1]),
                                np.asarray(lg_p[1, 0]), rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The pool updated in place vs the slice-per-layer formulation
+# ---------------------------------------------------------------------------
+
+
+def _slice_per_layer_run_stack(cfg, stack, stack_p, stack_c, x, *,
+                               positions, cache_pos, mode, max_len, remat,
+                               attend_cache=False, paged=None):
+    """The layer scan as it ran before the paged pools rode in its
+    carry: each layer's cache, pools included, goes in as the scan's xs
+    (a slice of every stack) and comes back as its ys (dense models
+    only: no aux loss, no expert counters)."""
+    from repro.models import moe as MOE
+
+    def body(xx, xs):
+        p_r, c_r = xs
+        new_cs = []
+        for pi, spec in enumerate(stack.pattern):
+            xx, nc, _, _ = T.layer_apply(
+                cfg, spec, p_r[pi], xx, positions=positions,
+                cache_pos=cache_pos, cache=c_r[pi], mode=mode,
+                max_len=max_len, attend_cache=attend_cache, paged=paged)
+            new_cs.append(nc)
+        return xx, tuple(new_cs)
+
+    x, new_cache = jax.lax.scan(body, x, (stack_p, stack_c))
+    return x, new_cache, jnp.zeros((), jnp.float32), MOE.no_counts()
+
+
+def _reset_and_copy_whole_stacks(cache, pages, src, dst, keep):
+    """Page resets and copy-on-write tails as the slice-per-layer
+    programs made them: scatters of whole page rows across every layer
+    of each stack, before the forward."""
+    def one(c):
+        c = dict(c)
+        c["ppos"] = c["ppos"].at[:, pages].set(-1)
+        pos = c["ppos"][:, src]
+        kept = (pos >= 0) & (pos < keep[None, :, None])
+        c["ppos"] = c["ppos"].at[:, dst].set(jnp.where(kept, pos, -1))
+        for k in KV.PAGED_DATA_KEYS:
+            if k in c:
+                c[k] = c[k].at[:, dst].set(c[k][:, src])
+        return c
+    return {"layers": tuple(tuple(one(c) for c in st)
+                            for st in cache["layers"])}
+
+
+@pytest.mark.parametrize("kernels", ["off", "interpret"])
+@pytest.mark.parametrize("policy", ["fp32", "int8"], ids=["fp", "q8"])
+def test_pool_in_place_matches_slice_per_layer(rng, monkeypatch, policy,
+                                               kernels):
+    """A packed forward (two decode lanes and an admitting slot's chunk,
+    its fresh pages reset and its partial prefix page copied on write)
+    and then a fused two-step decode, on a 3-layer stacked pool: the
+    in-place programs leave the pool the slice-per-layer formulation
+    leaves, bit for bit, with the same logits."""
+    pol = INT8 if policy == "int8" else FP32
+    base = get_reduced("unimo-text")
+    cfg = base.replace(stacks=(dataclasses.replace(base.stacks[0],
+                                                   repeats=3),))
+    params = T.init_params(jax.random.PRNGKey(1), cfg)
+    page, num_pages, slots, max_len = 8, 15, 3, 48
+    cache0 = T.init_paged_cache(cfg, num_pages=num_pages, page_size=page,
+                                max_slots=slots, max_len=max_len,
+                                dtype=jnp.float32, kv_dtype=pol.kv_dtype)
+    # slots 0 and 1 hold 13 and 11 tokens; pages 6-8 hold a retired
+    # request's stale entries, which slot 2 gets as its fresh pages
+    bt = np.full((slots, 6), -1, np.int32)
+    bt[0, :2], bt[1, :2], bt[2, :2] = [0, 1], [2, 3], [6, 7]
+    toks = rng.integers(4, 400, size=(slots, 16)).astype(np.int32)
+    _, cache0, _ = T.forward_prefill(
+        params, cfg, jnp.asarray(toks), jnp.asarray([13, 11, 16]),
+        cache0, policy=pol, max_len=max_len, last_only=True,
+        paged={"block_tables": jnp.asarray(bt),
+               "active": jnp.ones((slots,), bool)})
+    # slot 2 now shares slot 1's first 10 tokens: page 2 whole, page 3
+    # up to position 10 copied into its fresh page 7, then its 5-token
+    # chunk from position 10; its fresh page 6 holds stale positions
+    # until reset (reset pages padded with the dump page)
+    bt[2, :3] = [2, 7, 6]
+    fresh = np.full((slots, 6), num_pages, np.int32)
+    fresh[2, :2] = [7, 6]
+    cow = (jnp.asarray([num_pages, num_pages, 3]),
+           jnp.asarray([num_pages, num_pages, 7]), jnp.asarray([0, 0, 10]))
+    T_ = 8
+    stream = np.zeros(T_, np.int32)
+    stream[:2] = [5, 6]
+    stream[2:7] = toks[2, 10:15]
+    slot_ids = np.asarray([0, 1, 2, 2, 2, 2, 2, -1], np.int32)
+    positions = np.asarray([13, 11, 10, 11, 12, 13, 14, -1], np.int32)
+    seg_last = np.asarray([0, 1, 6], np.int32)
+    meta = DA.packed_meta_table(np.asarray([0, 1, 2]), np.asarray([1, 1, 5]),
+                                np.arange(3, dtype=np.int32), T_,
+                                T_ // DA.PACKED_BLOCK_Q + slots)
+    paged = {"block_tables": jnp.asarray(bt), "packed_meta": jnp.asarray(meta)}
+
+    def packed(cache, in_place):
+        if in_place:
+            cache = KV.reset_pages_all(cache, jnp.asarray(fresh))
+            pg = {**paged, "cow": cow}
+        else:
+            cache = _reset_and_copy_whole_stacks(
+                cache, jnp.asarray(fresh), *cow)
+            pg = paged
+        return T.forward_packed(
+            params, cfg, jnp.asarray(stream[None]), cache,
+            jnp.asarray(slot_ids), jnp.asarray(positions),
+            jnp.asarray(seg_last), policy=pol, max_len=max_len,
+            paged=pg)[:2]
+
+    def decode(cache):
+        def step(carry, _):
+            tok, lens, cache = carry
+            logits, cache, _ = T.forward_decode(
+                params, cfg, tok[:, None], cache, lens, policy=pol,
+                max_len=max_len,
+                paged={"block_tables": jnp.asarray(bt),
+                       "active": jnp.ones((slots,), bool)})
+            nxt = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
+            return (nxt, lens + 1, cache), logits
+        (_, _, cache), logits = jax.lax.scan(
+            step, (jnp.asarray([7, 8, 9], jnp.int32),
+                   jnp.asarray([14, 12, 15], jnp.int32), cache), None,
+            length=2)
+        return logits, cache
+
+    runs = {}
+    with KOPS.kernel_mode_ctx(kernels):
+        for in_place in (True, False):
+            with monkeypatch.context() as m:
+                if not in_place:
+                    m.setattr(T, "_run_stack", _slice_per_layer_run_stack)
+                lg_p, cache = jax.jit(lambda c: packed(c, in_place))(cache0)
+                lg_d, cache = jax.jit(decode)(cache)
+            runs[in_place] = (lg_p, lg_d, cache)
+    for a, b in zip(jax.tree.leaves(runs[True]), jax.tree.leaves(runs[False])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the copy-on-write page holds slot 1's first 10 positions, in layers
+    # the formulations share
+    assert np.asarray(runs[True][2]["layers"][0][0]["ppos"])[:, 7, :2] \
+        .tolist() == [[8, 9]] * 3
 
 
 # ---------------------------------------------------------------------------

@@ -11,10 +11,17 @@ catch what interpret mode cannot: blocks that break the TPU tiling
 rule, unaligned slices, kernels that use too much fast memory.  A
 compile that passes says nothing about results or speed.
 
+The engine's two step programs (the fused decode scan and the packed
+forward) are compiled whole, at the benchmark cells' geometry with a
+smaller pool, to check that they update the KV pool in place: no copy
+or slice of the stacked pool, or of one layer of it, and temporaries
+smaller than the pool.
+
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -200,3 +207,96 @@ def test_qwen3_4b_init_holds_only_bf16_weights(one_chip):
     assert tree_bytes <= mem.output_size_in_bytes <= tree_bytes + 2 ** 20
     assert mem.temp_size_in_bytes == 0
     assert tree_bytes < 9e9
+
+
+# the benchmark cells' engines with fewer pages: (policy, kv, weights),
+# slots, max_len, packed stream width, pages.  At 256 pages qwen3's
+# pool (50 MB) would be smaller than its expert layers' temporaries.
+STEP_CELLS = {"unimo-text": (("bf16", "int8", "int8"), 128, 512, 512, 256),
+              "qwen3-30b-a3b-stage6": (("bf16", "auto", "auto"), 64, 2048,
+                                       256, 1024)}
+POOL_MOVES = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                        r"(copy|dynamic-slice|dynamic-update-slice)\(",
+                        re.M)
+
+
+def _pool_moves(text, shapes):
+    """The copies, slices and update-slices of a compiled program whose
+    result has one of ``shapes``."""
+    return [m.group(0).strip() for m in POOL_MOVES.finditer(text)
+            if tuple(int(d) for d in m.group(1).split(",") if d) in shapes]
+
+
+@pytest.fixture(scope="module")
+def step_programs(topo, one_chip):
+    """The engine's fused decode (4 steps) and packed programs for each
+    cell, compiled for the described chip with kernels on, and the
+    pool's shapes (stacked and one layer) and bytes."""
+    from repro.configs.registry import get_config
+    from repro.core import kv_cache as KV
+    from repro.core.engine import InferenceEngine
+    from repro.core.precision import compress_weights
+    from repro.core.sampling import SamplingParams
+    from repro.kernels import ops as KOPS
+    from repro.launch.serve import serving_policy
+    from repro.models import transformer as T
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    out = {}
+    for arch, (pol, slots, max_len, width, pages) in STEP_CELLS.items():
+        cfg, policy = get_config(arch), serving_policy(*pol)
+        params = place(jax.eval_shape(lambda k: compress_weights(
+            policy.cast_params(T._init_params(k, cfg, policy)), policy)[0],
+            jax.random.PRNGKey(0)))
+        # the pool as the engine makes it on a TPU, rows at whole lanes
+        cache = jax.eval_shape(lambda: T.init_paged_cache(
+            cfg, num_pages=pages, page_size=16, max_slots=slots,
+            max_len=max_len, dtype=policy.compute_dtype,
+            kv_dtype=policy.kv_dtype, lanes=KV.pool_lanes(topo.devices[0])))
+        shapes, pool_bytes = set(), KV.paged_pool_bytes(cache)
+        for c in jax.tree.leaves(cache["layers"], is_leaf=lambda x:
+                                 isinstance(x, dict)):
+            for n in KV.PAGED_KEYS:
+                if n in c:
+                    shapes |= {c[n].shape, c[n].shape[1:]}
+        cache = place(cache)
+        rng = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+        # the programs only: no weights are made
+        engine = InferenceEngine(cfg, {}, policy=policy, max_batch=slots,
+                                 max_len=max_len)
+        sp, npg = SamplingParams(), -(-max_len // 16)
+        with KOPS.kernel_mode_ctx("tpu"):
+            step = engine._continuous_fns(sp, 4)[2].lower(
+                params, i32(slots), i32(slots), i32(slots),
+                i32(slots, dtype=jnp.bool_), i32(slots, npg), cache, rng)
+            packed = engine._packed_fns(sp).lower(
+                params, i32(1, width), i32(width), i32(width),
+                i32(width // 8 + slots, 4), i32(slots), i32(slots, npg),
+                i32(slots, npg), i32(slots), i32(slots), i32(slots), cache,
+                rng)
+        out[arch] = ({"step": step.compile(), "packed": packed.compile()},
+                     shapes, pool_bytes)
+    return out
+
+
+@pytest.mark.parametrize("program", ["step", "packed"])
+@pytest.mark.parametrize("arch", sorted(STEP_CELLS))
+def test_step_programs_update_the_pool_in_place(step_programs, arch,
+                                                program):
+    """No copy, dynamic-slice or dynamic-update-slice of the stacked
+    pool or of one layer's slice of it, and temporaries smaller than
+    the pool: the layer scan carries the pool whole, the kernels read
+    their layer from it, and the pool's default layout is the one the
+    kernels read."""
+    programs, shapes, pool_bytes = step_programs[arch]
+    compiled = programs[program]
+    _assert_kernel(compiled)
+    moves = _pool_moves(compiled.as_text(), shapes)
+    assert moves == [], "\n".join(m[:160] for m in moves)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
