@@ -16,6 +16,10 @@ Contracts under test:
     bucketed execution across plain, shared-prefix, int8, speculative
     and preemption/resume serving, while packed runs make exactly ONE
     device dispatch per mixed iteration;
+  * an engine builds each step program once: a second serve call at
+    the same geometry and stream widths traces, lowers and compiles
+    nothing, and the benchmark's warm-up recipe compiles exactly its
+    width ladder plus the fused decode program;
   * the new ServeMetrics derivations (host/device split, dispatches
     per iteration, padded-token fraction) are zero-guarded.
 """
@@ -31,9 +35,11 @@ from repro.configs.registry import get_reduced
 from repro.core import kv_cache as KV
 from repro.core.continuous import (ContinuousScheduler, PageAllocator,
                                    ServeMetrics)
-from repro.core.engine import InferenceEngine
+from repro.core.engine import InferenceEngine, packed_width_buckets
 from repro.core.precision import FP32
+from repro.core.sampling import SamplingParams
 from repro.core.scheduler import Request
+from repro.core.trace import ServeTracer
 from repro.kernels import decode_attention as DA
 from repro.kernels import ops as KOPS
 from repro.kernels import ref as R
@@ -604,6 +610,93 @@ def test_packed_optout_without_chunking_warns(rng):
     assert m.scheduler == "bucketed" and m.packed_tokens_padded == 0
     for r in done:
         assert r.result == base[r.uid]
+
+
+# ---------------------------------------------------------------------------
+# Program reuse: each step program is built and compiled once per engine
+# ---------------------------------------------------------------------------
+
+
+class _CompileEvents:
+    """Counts JAX's trace, lowering and backend-compile events while
+    entered (the events the benchmark's set-up log sums)."""
+
+    NAMES = ("/jax/core/compile/jaxpr_trace_duration",
+             "/jax/core/compile/jaxpr_to_mlir_module_duration",
+             "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        self.counts = dict.fromkeys(self.NAMES, 0)
+
+    def _on(self, event, _seconds, **_):
+        if event in self.counts:
+            self.counts[event] += 1
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._on)
+
+
+@pytest.mark.parametrize("mode", ["plain", "int8", "moe"])
+def test_second_serve_call_builds_nothing(rng, mode):
+    """A second serve call at the same geometry, whose packed widths the
+    first call already used, reuses every jitted program and the paged
+    context: nothing is traced, lowered or compiled."""
+    arch = "qwen3-30b-a3b" if mode == "moe" else "qwen3-4b"
+    cfg = get_reduced(arch)
+    policy = dataclasses.replace(FP32, weights_dtype="int8",
+                                 kv_dtype="int8") if mode == "int8" else FP32
+    eng = InferenceEngine(cfg, T.init_params(jax.random.PRNGKey(0), cfg),
+                          policy=policy, max_len=64, max_batch=3)
+    shapes = [(30, 5), (22, 6), (9, 4)]
+
+    def serve():
+        tr = ServeTracer()
+        _serve(eng, _requests(rng, cfg, shapes), trace=tr)
+        return {e["width_bucket"] for e in tr.iter_events("iteration")
+                if e["chunk_segments"]}
+
+    widths = serve()
+    fns, ctx = dict(eng._cont_cache), eng._paged_ctx
+    packed = fns[("packed", SamplingParams())]
+    step = fns[(SamplingParams(), 4)][2]
+    n_packed = packed._cache_size()
+    with _CompileEvents() as ev:
+        again = serve()
+    assert again <= widths
+    assert eng._cont_cache.keys() == fns.keys()
+    assert all(eng._cont_cache[k] is v for k, v in fns.items())
+    assert eng._paged_ctx is ctx
+    assert step._cache_size() == 1
+    assert packed._cache_size() == n_packed
+    assert ev.counts == dict.fromkeys(_CompileEvents.NAMES, 0), ev.counts
+
+
+def test_warm_up_recipe_compiles_exactly_the_ladder(rng):
+    """The benchmark's warm-up, one lone prompt per stream width of the
+    budget's ladder with two tokens to generate, leaves one packed
+    program per width, one fused decode program, and no admission or
+    verify program."""
+    cfg = get_reduced("qwen3-4b")
+    eng = InferenceEngine(cfg, T.init_params(jax.random.PRNGKey(0), cfg),
+                          policy=FP32, max_len=64, max_batch=3)
+    budget = 32
+    widths = packed_width_buckets(budget)
+    for w in widths:
+        req = Request(uid=0, tokens=[int(t) for t in rng.integers(4, 400, w)],
+                      max_new_tokens=2)
+        eng.serve_continuous([req], page_size=8, steps_per_sync=4,
+                             max_batched_tokens=budget)
+    sp = SamplingParams()
+    fns = eng._cont_cache
+    admit, admit_prefix, step = fns[(sp, 4)]
+    assert fns[("packed", sp)]._cache_size() == len(widths)
+    assert step._cache_size() == 1
+    assert admit._cache_size() == admit_prefix._cache_size() == 0
+    assert set(fns) == {("packed", sp), (sp, 4)}
 
 
 # ---------------------------------------------------------------------------

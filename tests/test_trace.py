@@ -177,6 +177,12 @@ def test_validate_jsonl_roundtrip(tmp_path):
     tr.to_jsonl(p)
     n, errs = TR.validate_jsonl(p)
     assert (n, errs) == (2, [])
+    # the command line (python -m repro.core.trace) gates on the same
+    assert TR._main(["validate", p]) == 0
+    assert TR._main(["summary", p]) == 0
+    bad = tmp_path / "bad.jsonl"
+    bad.write_text(json.dumps({"kind": "nope", "t": 0.0}) + "\n")
+    assert TR._main(["validate", str(bad)]) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -297,6 +303,17 @@ def _brute_mixed(args, _out):
             sum(_visible(row, p) for p in range(start, start + n_q)))
 
 
+def _brute_verify(args, _out):
+    lens, act, bt = (np.asarray(args[i]) for i in (2, 4, 6))
+    k1 = np.shape(args[5])[1] + 1          # pending token + the drafts
+    kv = pairs = 0
+    for s in np.flatnonzero(act):
+        kv += _visible(bt[s], lens[s] + k1 - 1)
+        pairs += sum(_visible(bt[s], p) for p in range(lens[s],
+                                                       lens[s] + k1))
+    return kv, pairs
+
+
 def _live(block_row, qmax):
     """Hand count: the pages of a block-table row a paged kernel computes
     for a work item whose largest query is at ``qmax`` — every page that
@@ -327,10 +344,20 @@ def _pages_mixed(args, _out):
     return _live(row, start + n_q - 1), len(row)
 
 
+def _pages_verify(args, _out):
+    # every slot is a work item, its window through lens + k
+    lens, bt = np.asarray(args[2]), np.asarray(args[6])
+    k = np.shape(args[5])[1]
+    live = sum(_live(bt[s], lens[s] + k) for s in range(len(lens)))
+    return live, bt.size
+
+
 BRUTE_WORK = {"packed": _brute_packed, "step": _brute_step,
-              "mixed": _brute_mixed}
+              "mixed": _brute_mixed, "verify": _brute_verify}
 BRUTE_PAGES = {"packed": _pages_packed, "step": _pages_step,
-               "mixed": _pages_mixed}
+               "mixed": _pages_mixed, "verify": _pages_verify}
+SPEC_K = 3
+DISPATCHES = ("packed", "step", "mixed", "verify")
 
 
 def _record_dispatches(eng, tr, steps_per_sync=4, brute=BRUTE_WORK):
@@ -339,7 +366,8 @@ def _record_dispatches(eng, tr, steps_per_sync=4, brute=BRUTE_WORK):
     arguments it was called with) to the returned dict."""
     from repro.core.sampling import SamplingParams
     sp = SamplingParams()
-    calls = {"packed": [], "step": [], "mixed": [], "lowered": {}}
+    calls = {name: [] for name in DISPATCHES}
+    calls["lowered"] = {}
 
     def wrap(name, fn, brute):
         def run(*args):
@@ -356,33 +384,50 @@ def _record_dispatches(eng, tr, steps_per_sync=4, brute=BRUTE_WORK):
 
     eng._packed_fns(sp)
     eng._mixed_fns(sp)
+    eng._spec_fns(sp, SPEC_K)
     for steps in {1, steps_per_sync}:
         eng._continuous_fns(sp, steps)
     c = eng._cont_cache
     c[("packed", sp)] = wrap("packed", c[("packed", sp)], brute["packed"])
     c[("mixed", sp)] = wrap("mixed", c[("mixed", sp)], brute["mixed"])
+    c[("spec", sp, SPEC_K)] = wrap("verify", c[("spec", sp, SPEC_K)],
+                                   brute["verify"])
     for steps in {1, steps_per_sync}:
         a, ap, step = c[(sp, steps)]
         c[(sp, steps)] = (a, ap, wrap("step", step, brute["step"]))
     return calls
 
 
-@pytest.mark.parametrize("packed", [True, False])
+def _mode_kw(packed):
+    """Serve options of a counter test's case: packed (True), per-chunk
+    mixed (False), or packed with ngram speculation ("spec")."""
+    if packed != "spec":
+        return {"packed": packed}
+    from repro.core.speculative import SpecConfig
+    return {"packed": True, "spec": SpecConfig(k=SPEC_K, drafter="ngram")}
+
+
+def _decode_dispatch(packed):
+    """The dispatch a decode-only iteration runs."""
+    return "verify" if packed == "spec" else "step"
+
+
+@pytest.mark.parametrize("packed", [True, False, "spec"])
 def test_attention_counters_match_brute_force(rng, model, packed):
     """Each iteration's attn_kv_tokens / attn_pairs equal a brute-force
     count over its dispatches' block tables, lengths and plan: packed
-    iterations, mixed ones (decode micro-step plus per-chunk forwards)
-    and fused decode scans."""
+    iterations, mixed ones (decode micro-step plus per-chunk forwards),
+    fused decode scans and (with speculation) verify windows."""
     cfg, params = model
     reqs = _requests(rng, cfg, [(30, 3), (26, 9), (9, 6), (22, 7)])
     tr = ServeTracer(clock=FakeClock())
     eng = _engine(cfg, params)
     calls = _record_dispatches(eng, tr)
-    _serve(eng, reqs, trace=tr, packed=packed)
+    _serve(eng, reqs, trace=tr, **_mode_kw(packed))
     assert validate_events(tr.events) == []
     its = list(tr.iter_events("iteration"))
     want = {}
-    for name in ("packed", "step", "mixed"):
+    for name in DISPATCHES:
         for it, (kv, pairs) in calls[name]:
             w = want.setdefault(it, [0, 0])
             w[0] += kv
@@ -393,25 +438,26 @@ def test_attention_counters_match_brute_force(rng, model, packed):
     assert all(e["attn_kv_tokens"] == e["attn_pairs"] == 0
                for e in its if e["idle"])
     assert calls["packed" if packed else "mixed"]
-    assert any(its[it]["chunk_segments"] == 0 for it, _ in calls["step"]), \
-        "no fused decode iteration ran"
+    dec = _decode_dispatch(packed)
+    assert any(its[it]["chunk_segments"] == 0 for it, _ in calls[dec]), \
+        f"no {dec} iteration ran"
 
 
-@pytest.mark.parametrize("packed", [True, False])
+@pytest.mark.parametrize("packed", [True, False, "spec"])
 def test_page_counters_match_hand_count(rng, model, packed):
     """Each iteration's attn_pages equals a hand count of the live pages
     of its kernel dispatches' work items (packed query windows, fused
-    decode lanes at every step, chunk rows), and attn_page_steps equals
-    work items x pages per slot."""
+    decode lanes at every step, chunk rows, verify windows), and
+    attn_page_steps equals work items x pages per slot."""
     cfg, params = model
     reqs = _requests(rng, cfg, [(30, 3), (26, 9), (9, 6), (22, 7)])
     tr = ServeTracer(clock=FakeClock())
     eng = _engine(cfg, params)
     calls = _record_dispatches(eng, tr, brute=BRUTE_PAGES)
-    _serve(eng, reqs, trace=tr, packed=packed)
+    _serve(eng, reqs, trace=tr, **_mode_kw(packed))
     assert validate_events(tr.events) == []
     want = {}
-    for name in ("packed", "step", "mixed"):
+    for name in DISPATCHES:
         for it, (live, steps) in calls[name]:
             w = want.setdefault(it, [0, 0])
             w[0] += live
@@ -422,7 +468,8 @@ def test_page_counters_match_hand_count(rng, model, packed):
     assert got == want
     live, steps = np.asarray(list(want.values())).sum(0)
     assert 0 < live < steps
-    assert calls["packed" if packed else "mixed"] and calls["step"]
+    assert calls["packed" if packed else "mixed"]
+    assert calls[_decode_dispatch(packed)]
 
 
 def _moe_tokens_packed(args, _out):
@@ -437,8 +484,13 @@ def _moe_tokens_mixed(args, _out):
     return (int(np.asarray(args[3]).sum()),)
 
 
+def _moe_tokens_verify(args, _out):
+    # each active slot's window: its pending token and the drafts
+    return (int(np.asarray(args[4]).sum()) * (np.shape(args[5])[1] + 1),)
+
+
 BRUTE_MOE = {"packed": _moe_tokens_packed, "step": _moe_tokens_step,
-             "mixed": _moe_tokens_mixed}
+             "mixed": _moe_tokens_mixed, "verify": _moe_tokens_verify}
 
 
 @pytest.fixture(scope="module")
@@ -447,12 +499,12 @@ def moe_model():
     return cfg, T.init_params(jax.random.PRNGKey(0), cfg)
 
 
-@pytest.mark.parametrize("packed", [True, False])
+@pytest.mark.parametrize("packed", [True, False, "spec"])
 def test_moe_counters_count_real_tokens_only(rng, moe_model, model, packed):
     """Each iteration's moe_rows is top_k x expert layers x the real
     tokens of its dispatches (packed lanes with a slot, active decode
-    lanes at every fused step, chunk tokens): padding lanes and inactive
-    slots add nothing.  Experts hit and the largest expert's rows lie
+    lanes at every fused step, chunk tokens, active slots' verify
+    windows): padding lanes and inactive slots add nothing.  Experts hit and the largest expert's rows lie
     within what those rows allow; a dense model's records read 0."""
     cfg, params = moe_model
     k, layers, E = cfg.moe.top_k, cfg.num_layers, cfg.moe.num_experts
@@ -460,10 +512,10 @@ def test_moe_counters_count_real_tokens_only(rng, moe_model, model, packed):
     tr = ServeTracer(clock=FakeClock())
     eng = _engine(cfg, params)
     calls = _record_dispatches(eng, tr, brute=BRUTE_MOE)
-    _serve(eng, reqs, trace=tr, packed=packed)
+    _serve(eng, reqs, trace=tr, **_mode_kw(packed))
     assert validate_events(tr.events) == []
     tokens = {}
-    for name in ("packed", "step", "mixed"):
+    for name in DISPATCHES:
         for it, (n,) in calls[name]:
             tokens[it] = tokens.get(it, 0) + n
     its = list(tr.iter_events("iteration"))
