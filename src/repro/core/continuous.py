@@ -380,15 +380,21 @@ def attention_work(starts, lengths) -> Tuple[int, int]:
     return int((s + n).sum()), int((n * s + n * (n + 1) // 2).sum())
 
 
-def page_work(max_qpos, page_size: int, npages: int) -> Tuple[int, int]:
-    """(page steps computed, grid page steps) of one layer's paged
-    attention kernel over work items whose largest query positions are
-    ``max_qpos`` (< 0: the item has no query): the grid walks ``npages``
-    steps per item, and an item computes only its live pages,
-    ``min(max_qpos // page_size + 1, npages)`` — the host twin of
-    ``decode_attention.live_pages``."""
+def page_work(max_qpos, page_size: int, npages: int,
+              block_pages: Optional[int] = None) -> Tuple[int, int]:
+    """(page steps computed, grid steps) of one layer's paged attention
+    kernel over work items whose largest query positions are
+    ``max_qpos`` (< 0: the item has no query).  An item computes only its
+    live pages, ``min(max_qpos // page_size + 1, npages)`` — the host twin
+    of ``decode_attention.live_pages``.  The decode and mixed kernels'
+    grid walks ``npages`` steps per item; the packed kernel's steps once
+    per block of ``block_pages`` live pages
+    (``decode_attention.packed_block_pages``), ``ceil(live /
+    block_pages)`` per item."""
     m = np.asarray(max_qpos, np.int64)
     live = np.where(m >= 0, np.minimum(m // page_size + 1, npages), 0)
+    if block_pages:
+        return int(live.sum()), int((-(-live // block_pages)).sum())
     return int(live.sum()), int(m.size) * npages
 
 

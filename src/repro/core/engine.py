@@ -86,6 +86,20 @@ class EngineStats:
             setattr(self, f, getattr(self, f) + getattr(other, f))
 
 
+def _packed_block_pages(cache, pages_per_slot: int) -> Optional[int]:
+    """The packed kernel's pages per block
+    (``decode_attention.packed_block_pages``) at this paged cache's
+    attention pools; None without one."""
+    from repro.kernels import decode_attention as DA
+    for stack_c in cache["layers"]:
+        for c in stack_c:
+            if isinstance(c, dict) and "pk" in c:
+                return DA.packed_block_pages(c["pk"], c["pv"],
+                                             c.get("pk_scale"),
+                                             pages_per_slot)
+    return None
+
+
 class InferenceEngine:
     """Single-host serving engine for one model (single-stream vocab).
 
@@ -1000,10 +1014,11 @@ class InferenceEngine:
             it_acc["attn_kv"] += kv
             it_acc["attn_pairs"] += pairs
 
-        def count_pages(max_qpos):
+        def count_pages(max_qpos, block_pages=None):
             """Add one paged-kernel dispatch's page steps (``page_work``
             of its work items' largest query positions)."""
-            live, steps = page_work(max_qpos, page_size, pages_per_slot)
+            live, steps = page_work(max_qpos, page_size, pages_per_slot,
+                                    block_pages)
             it_acc["attn_pages"] += live
             it_acc["attn_page_steps"] += steps
 
@@ -1337,11 +1352,12 @@ class InferenceEngine:
                                         pb.seg_slots[:pb.n_segments],
                                         W, n_work)
             if tr is not None:
-                # each work row (query window) through its last lane;
-                # unused rows (slot -1) have none
+                # each work row (query window) through its last lane, in
+                # the kernel's blocks; unused rows (slot -1) have none
                 count_pages(np.where(
                     meta[:, 0] >= 0,
-                    pb.positions[np.maximum(meta[:, 3] - 1, 0)], -1))
+                    pb.positions[np.maximum(meta[:, 3] - 1, 0)], -1),
+                    _packed_block_pages(cache, pages_per_slot))
             # one dispatch carries both shares; device_s sums both pools
             with dev_span("packed", "prefill"):
                 nxt, cache, rng, moe = packed_fn(

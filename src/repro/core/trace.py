@@ -86,7 +86,8 @@ EVENT_SCHEMAS = {
         "attn_pairs": (_INT, _REQUIRED),
         # per layer, over every paged attention kernel dispatch: page steps
         # computed (each work item's live pages, continuous.page_work) and
-        # the grid's page steps (work items x pages per slot)
+        # the kernels' steps (work items x pages per slot; for a packed
+        # dispatch, each row's blocks of live pages)
         "attn_pages": (_INT, _REQUIRED),
         "attn_page_steps": (_INT, _REQUIRED),
         # over every forward and expert layer of the iteration's

@@ -17,16 +17,20 @@ K/V pages plus their per-entry fp32 scale rows and dequantizes
 *in-register* to fp32 right before QK^T / PV — halving the HBM bytes per
 decode step versus bf16 pages while the matmuls still accumulate in fp32.
 
-The paged kernels (decode, mixed, packed) walk a block table: their grid
-is (items, npages) with ``npages = block_tables.shape[1]``, but each item
-streams and computes only its ``n_live`` live pages (:func:`live_pages`,
-from the item's largest query position, passed by scalar prefetch).  On
-the dead steps past them the index maps repeat the last live page, so the
-pipeline fetches nothing, and the body is skipped; ``_init`` and
-``_finish`` still run at the first and last step, so the grid and the
-output contract are those of a full walk.  A skipped page lies wholly
-beyond the item's queries, and a fully masked page leaves the online
-softmax state exactly as it was, so the outputs are the full walk's.
+The paged kernels walk a block table, and each item streams and
+computes only its ``n_live`` live pages (:func:`live_pages`, from the
+item's largest query position, passed by scalar prefetch).  The decode
+and mixed kernels' grid is (items, npages) with ``npages =
+block_tables.shape[1]``: on the dead steps past the live pages the index
+maps repeat the last live page, so the pipeline fetches nothing, and the
+body is skipped; ``_init`` and ``_finish`` still run at the first and
+last step, so the grid and the output contract are those of a full
+walk.  The packed kernel's grid is its work rows alone: each row copies
+its live pages from HBM itself, in blocks of :func:`packed_block_pages`
+pages, double-buffered across blocks and rows.  A skipped page lies
+wholly beyond the item's queries, and a fully masked page leaves the
+online softmax state exactly as it was, so the outputs are the full
+walk's.
 
 The paged kernels read the pool in place from the whole layer stack a
 serving layer scan carries: pools ``(L, P, page, Hkv, D)``, positions
@@ -670,6 +674,13 @@ PACKED_BLOCK_Q = 8
 # the whole (1, T, Hq, D[v]) query/output blocks stay VMEM-resident
 # (constant index maps), so T is capped by a VMEM budget, not the grid
 PACKED_VMEM_BYTES = 8 * 1024 * 1024
+# the packed kernel fetches a work row's live pages itself, BP pages at a
+# time into one of two buffer sets (the next block is in flight while
+# this one is read); both sets together stay within this share of VMEM
+PACKED_PAGE_BUFFER_BYTES = 4 * 1024 * 1024
+# the packed kernel's scoped VMEM: the resident blocks, both page buffer
+# sets and a block's float32 K/V tiles (a v5e core has 128 MiB)
+PACKED_VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 
 
 def paged_packed_shape_supported(q, kpool, block_tables,
@@ -715,63 +726,287 @@ def packed_live_pages(meta, q_pos, page: int, npages: int):
     return live_pages(jnp.where(meta[:, 0] >= 0, last, -1), page, npages)
 
 
-def _paged_packed_kernel(ly_ref, nl_ref, meta_ref, bt_ref, q_ref, k_ref,
-                         v_ref, kp_ref, qp_ref, o_ref, m_scr, l_scr, acc_scr,
-                         *, scale, attn_softcap, window, npages, dump, g):
-    """Token-packed ragged variant of _paged_mixed_kernel: the grid's
-    first dim walks *query windows* of the flat (1, T) stream instead of
-    slots.  Work item w covers PACKED_BLOCK_Q stream lanes starting at
-    meta[w, 1]; only lanes inside [meta[w, 2], meta[w, 3]) belong to the
-    window's segment — the rest are masked off and their output lanes
-    preserved via a masked read-modify-write at finalize (grid items run
-    sequentially, and a lane's owning window is unique, so the RMW never
-    races).  The streamed pages are the *segment's slot's* pages
-    (meta[w, 0] indexes the block table); each query lane masks keys
-    against its own absolute position, so every token of every slot gets
-    its exact causal paged-attention in ONE kernel launch.  Only the
-    window's nl[w] live pages (through its last lane) are streamed and
-    attended."""
-    w, j = pl.program_id(0), pl.program_id(1)
+def _vmem_bytes(shape, dtype) -> int:
+    """Bytes a VMEM array of ``shape`` takes: its minor dim padded to 128
+    lanes, its second-minor to whole sublane tiles (8 rows of 32 bits,
+    packed 16 or 32 rows deep for narrower types)."""
+    size = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sub = 8 * 4 // size
+    n = 1
+    for d in lead:
+        n *= d
+    return n * -(-rows // sub) * sub * -(-lanes // 128) * 128 * size
+
+
+def packed_block_pages(kpool, vpool, k_scale, npages: int) -> int:
+    """BP, the pages the packed kernel fetches and attends per block: the
+    largest power of two <= ``npages`` whose two buffer sets fit
+    PACKED_PAGE_BUFFER_BYTES, at a page's VMEM bytes (K, V, their scale
+    rows for an int8 pool, and the page's (page, POS_LANES) positions
+    block).  Reads only shapes and dtypes, so the host counts the
+    kernel's blocks with the kernel's own BP."""
+    page = kpool.shape[-3]
+    per_page = (_vmem_bytes(kpool.shape[-3:], kpool.dtype)
+                + _vmem_bytes(vpool.shape[-3:], vpool.dtype)
+                + _vmem_bytes((page, POS_LANES), jnp.int32))
+    if k_scale is not None:
+        per_page += 2 * _vmem_bytes(k_scale.shape[-2:], k_scale.dtype)
+    bp = 1
+    while 2 * bp <= npages and 2 * (2 * bp) * per_page \
+            <= PACKED_PAGE_BUFFER_BYTES:
+        bp *= 2
+    return bp
+
+
+def _block_kv(d, k, v, ks=None, vs=None):
+    """A fetched block's K and V as float32 keys: (bp, page, Hkv, D[v])
+    flattened to (bp*page, Hkv, D[v]), dequantized with their (bp, page,
+    heads) scale rows for an int8 pool, K cut to the query's head dim
+    and the scale rows to K's heads (as :func:`_page_kv`)."""
+    bp, page, hkv = k.shape[:3]
+    k = k.reshape(bp * page, hkv, -1)[..., :d].astype(jnp.float32)
+    v = v.reshape(bp * page, hkv, -1).astype(jnp.float32)
+    if ks is not None:
+        k = k * ks.reshape(bp * page, -1)[:, :hkv].astype(
+            jnp.float32)[..., None]
+        v = v * vs.reshape(bp * page, -1)[:, :hkv].astype(
+            jnp.float32)[..., None]
+    return k, v
+
+
+def _paged_packed_kernel(ly_ref, nl_ref, meta_ref, bt_ref, q_ref, *refs,
+                         n_pool, tail, scale, attn_softcap, window, g, bp):
+    """Token-packed ragged variant of _paged_mixed_kernel: grid step w is
+    work row w of the flat (1, T) stream's work table.  Row w covers
+    PACKED_BLOCK_Q stream lanes starting at meta[w, 1]; only lanes inside
+    [meta[w, 2], meta[w, 3]) belong to its window's segment — the rest
+    are masked off and their output lanes preserved via a masked
+    read-modify-write at finalize (steps run sequentially, and a lane's
+    owning window is unique, so the RMW never races).  The attended
+    pages are the *segment's slot's* pages (meta[w, 0] indexes the block
+    table); each query lane masks keys against its own absolute
+    position, so every token of every slot gets its exact causal
+    paged-attention in ONE kernel launch.
+
+    The pools stay in HBM.  The row's nl[w] live pages (through its last
+    lane) come in blocks of ``bp``: the kernel copies each live,
+    allocated page of a block (K, V, their scale rows, and the page's
+    (page, POS_LANES) positions block) into one of two VMEM buffer sets,
+    starts the next block — or, after the row's last, the next row's
+    first — into the other set, and attends the block's bp * page keys
+    in one online-softmax update.  Pages it does not fetch (past nl[w],
+    or unallocated) are masked out of the block, so their buffer entries,
+    stale from an earlier block, contribute nothing (the V buffers are
+    zeroed once, at the first step).  ``tail`` is the first page of a
+    partial last POS_LANES column block of the positions (None when P is
+    a whole number of them): a page there takes its positions from that
+    block, resident in VMEM, since a copy cannot reach past the array."""
+    kpools, vpools = refs[:n_pool], refs[n_pool:2 * n_pool]
+    kp_hbm, refs = refs[2 * n_pool], refs[2 * n_pool + 1:]
+    tail_ref, refs = (refs[0], refs[1:]) if tail is not None \
+        else (None, refs)
+    qp_ref, o_ref, m_scr, l_scr, acc_scr = refs[:5]
+    kbufs, vbufs = refs[5:5 + n_pool], refs[5 + n_pool:5 + 2 * n_pool]
+    pbuf, sem, nxt_ref = refs[5 + 2 * n_pool:]
+
+    w, n_work = pl.program_id(0), pl.num_programs(0)
     bq = PACKED_BLOCK_Q
-    slot = meta_ref[w, 0]
+    npages = bt_ref.shape[1]
+    _, _, page, Hkv, _ = kbufs[0].shape
+    _, _, Hq, D = q_ref.shape
+    keys = bp * page
+    ly = ly_ref[0]
     offc = meta_ref[w, 1]
     ws = meta_ref[w, 2]
     we = meta_ref[w, 3]
+    nl = nl_ref[w]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def row_page(r, j):
+        """Physical page of logical page j of work row r, and whether the
+        kernel fetches it: live (j < nl[r]) and allocated."""
+        pid = bt_ref[jnp.maximum(meta_ref[r, 0], 0),
+                     jnp.minimum(j, npages - 1)]
+        return pid, (j < nl_ref[r]) & (pid >= 0)
 
-    _, _, Hq, D = q_ref.shape
-    Hkv = k_ref.shape[2]
+    def block_copies(r, b, s, start):
+        """Start (``start``) or wait for the copies of block b of work row
+        r into buffer set s.  A wait needs only a copy's size, and it is
+        taken under its start's condition, so each started copy is
+        waited for once."""
+        def go(copies):
+            for c in copies:
+                if start:
+                    c.start()
+                else:
+                    c.wait()
+
+        def one(i, carry):
+            pid, fetch = row_page(r, b * bp + i)
+            pl.when(fetch)(lambda: go([
+                pltpu.make_async_copy(src.at[ly, pid], dst.at[s, i],
+                                      sem.at[s])
+                for src, dst in zip(kpools + vpools, kbufs + vbufs)]))
+            if tail != 0:          # some pages' columns are whole blocks
+                col = pl.multiple_of(pid // POS_LANES * POS_LANES,
+                                     POS_LANES)
+                pos = pltpu.make_async_copy(
+                    kp_hbm.at[ly, :, pl.ds(col, POS_LANES)], pbuf.at[s, i],
+                    sem.at[s])
+                pl.when(fetch if tail is None else fetch & (pid < tail))(
+                    lambda: go([pos]))
+            if tail is not None and start:
+                @pl.when(fetch & (pid >= tail))
+                def _():
+                    pbuf[s, i] = tail_ref[...]
+            return carry
+        jax.lax.fori_loop(0, bp, one, 0)
+
+    @pl.when(w == 0)
+    def _first():
+        nxt_ref[0] = 0                     # no row's first block in flight
+        nxt_ref[1] = 0                     # the buffer set it goes to
+        for buf in vbufs:
+            buf[...] = jnp.zeros_like(buf)
+
+    m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
     lane = offc + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     in_win = (lane >= ws) & (lane < we)                    # (bq, 1)
 
-    @_on_live_page(j, nl_ref[w])
-    def _step():
+    @pl.when(nl > 0)
+    def _row():
         q = q_ref[0, pl.ds(offc, bq)].astype(jnp.float32)  # (bq, Hq, D)
-        k, v = _page_kv(q_ref, k_ref, v_ref)             # (page, Hkv, D[v])
-        kp = _page_pos(kp_ref, bt_ref, jnp.maximum(slot, 0), j, nl_ref[w],
-                       dump)                               # (1, page)
-        qp = qp_ref[pl.ds(offc, bq)]                       # (bq, 1)
-        qp_eff = jnp.where(in_win, qp, -1)
-        allocated = (slot >= 0) & (bt_ref[jnp.maximum(slot, 0), j] >= 0)
-        mask = _mq_mask(kp, qp_eff, allocated, window)
         qg = q.reshape(bq, Hkv, g, D).transpose(1, 0, 2, 3)  # (Hkv,bq,g,D)
-        _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
-                         attn_softcap=attn_softcap)
+        qp_eff = jnp.where(in_win, qp_ref[pl.ds(offc, bq)], -1)  # (bq, 1)
+        key_page = jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1) // page
+        nb = (nl + bp - 1) // bp
+        first = nxt_ref[1]
 
-    @pl.when(j == npages - 1)
-    def _finish():
-        denom = jnp.maximum(l_scr[...], 1e-37)[..., None]
-        out = (acc_scr[...] / denom) \
-            .reshape(Hkv, bq, g, acc_scr.shape[-1]) \
-            .transpose(1, 0, 2, 3).reshape(bq, Hq, acc_scr.shape[-1])
-        old = o_ref[0, pl.ds(offc, bq)]
-        o_ref[0, pl.ds(offc, bq)] = jnp.where(
-            in_win[:, :, None], out.astype(o_ref.dtype), old)
+        @pl.when(nxt_ref[0] == 0)
+        def _():
+            block_copies(w, 0, first, True)
+
+        def block(b, s):
+            # the next block of this row, or after its last the next
+            # row's first, goes to the other set while this one is read
+            more = b + 1 < nb
+
+            @pl.when(more | (w + 1 < n_work))
+            def _():
+                block_copies(jnp.where(more, w, w + 1),
+                             jnp.where(more, b + 1, 0), 1 - s, True)
+
+            block_copies(w, b, s, False)
+
+            # each key's page: its column in the positions block, and
+            # whether it was fetched (int32: a vector carry)
+            def page_cols(i, carry):
+                col, ok = carry
+                pid, fetch = row_page(w, b * bp + i)
+                here = key_page == i
+                return (jnp.where(here, pid % POS_LANES, col),
+                        jnp.where(here, fetch.astype(jnp.int32), ok))
+            col, ok = jax.lax.fori_loop(
+                0, bp, page_cols, (jnp.zeros((1, keys), jnp.int32),
+                                   jnp.zeros((1, keys), jnp.int32)))
+            cols = pbuf[s].reshape(keys, POS_LANES).T      # (lanes, keys)
+            pick = jax.lax.broadcasted_iota(jnp.int32, cols.shape, 0) == col
+            kp = jnp.sum(jnp.where(pick, cols, 0), axis=0, keepdims=True)
+            k, v = _block_kv(D, kbufs[0][s], vbufs[0][s],
+                             *[buf[s] for buf in kbufs[1:] + vbufs[1:]])
+            mask = _mq_mask(kp, qp_eff, ok > 0, window)    # (bq, keys)
+            _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr,
+                             scale=scale, attn_softcap=attn_softcap)
+            return 1 - s
+
+        nxt_ref[1] = jax.lax.fori_loop(0, nb, block, first)
+        nxt_ref[0] = ((w + 1 < n_work) & (
+            nl_ref[jnp.minimum(w + 1, n_work - 1)] > 0)).astype(jnp.int32)
+
+    denom = jnp.maximum(l_scr[...], 1e-37)[..., None]
+    out = (acc_scr[...] / denom) \
+        .reshape(Hkv, bq, g, acc_scr.shape[-1]) \
+        .transpose(1, 0, 2, 3).reshape(bq, Hq, acc_scr.shape[-1])
+    old = o_ref[0, pl.ds(offc, bq)]
+    o_ref[0, pl.ds(offc, bq)] = jnp.where(
+        in_win[:, :, None], out.astype(o_ref.dtype), old)
+
+
+def _whole_lanes(pool):
+    """``pool`` with its minor dim zero-padded to whole 128-lane tiles: a
+    page copy cannot cut a lane tile.  A TPU engine's pools are already
+    whole lanes (``kv_cache.pool_lanes``), so only other callers' pools
+    are copied here."""
+    pad = -pool.shape[-1] % 128
+    if not pad:
+        return pool
+    return jnp.pad(pool, [(0, 0)] * (pool.ndim - 1) + [(0, pad)])
+
+
+def _paged_packed_call(q, kpools, vpools, ppos, block_tables, q_pos, meta,
+                       layer, *, window, scale, attn_softcap, interpret):
+    """The packed kernel over K pools ``kpools`` (codes[, scales]) and V
+    pools ``vpools`` alike: one grid step per work row."""
+    _, T, Hq, D = q.shape
+    n_pool = len(kpools)
+    Dv_out = vpools[0].shape[-1]
+    ly, pools = _stack(layer, (*map(_whole_lanes, kpools + vpools), ppos))
+    kpools, vpools, ppos = pools[:n_pool], pools[n_pool:-1], pools[-1]
+    P, page, Hkv, Dv = vpools[0].shape[-4:]
+    npages = block_tables.shape[1]
+    g = Hq // Hkv
+    bq = PACKED_BLOCK_Q
+    bp = packed_block_pages(kpools[0], vpools[0],
+                            kpools[1] if n_pool > 1 else None, npages)
+    tail = P // POS_LANES * POS_LANES if P % POS_LANES else None
+
+    n_live = packed_live_pages(meta, q_pos, page, npages)
+
+    def resident(shape):
+        return pl.BlockSpec(shape, lambda w, *pf: (0,) * len(shape))
+
+    in_specs = [resident((1, T, Hq, D))] \
+        + [pl.BlockSpec(memory_space=pl.ANY)] * (2 * n_pool + 1)
+    if tail is not None:
+        in_specs.append(pl.BlockSpec(
+            (None, page, POS_LANES),
+            lambda w, ly, *pf: (ly[0], 0, tail // POS_LANES)))
+    in_specs.append(resident((T, 1)))
+    kernel = functools.partial(_paged_packed_kernel, n_pool=n_pool,
+                               tail=tail, scale=scale,
+                               attn_softcap=attn_softcap, window=window,
+                               g=g, bp=bp)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(meta.shape[0],),
+        in_specs=in_specs,
+        out_specs=resident((1, T, Hq, Dv)),
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, bq * g), jnp.float32),
+            pltpu.VMEM((Hkv, bq * g), jnp.float32),
+            pltpu.VMEM((Hkv, bq * g, Dv), jnp.float32),
+            *[pltpu.VMEM((2, bp) + p.shape[2:], p.dtype)
+              for p in kpools + vpools],
+            pltpu.VMEM((2, bp, page, POS_LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+        ],
+    )
+    positions = _positions(ppos)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((1, T, Hq, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=PACKED_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(ly, n_live, meta, block_tables, q, *kpools, *vpools, positions,
+      *([positions] if tail is not None else []), q_pos.reshape(T, 1))
+    # lanes no window owns (stream padding) are never written: zero them
+    return jnp.where((q_pos >= 0)[..., None, None], out[..., :Dv_out], 0)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",
@@ -791,104 +1026,16 @@ def paged_packed_attention(q, kpool, vpool, ppos, block_tables, q_pos,
     The stream's own K/V must already be in the pool (written by
     ``kv_cache.paged_write_packed``); stored absolute positions give each
     query its exact causal mask over its own slot's history, including
-    earlier tokens of its own chunk.  The grid spans (n_work, npages),
-    but work row w streams and attends only the live pages of its
-    window's last lane (:func:`packed_live_pages`); an unused row
-    (slot -1) streams nothing.  Pools are stacked and read at ``layer``
+    earlier tokens of its own chunk.  The grid has one step per work
+    row, and row w fetches and attends only the live pages of its
+    window's last lane (:func:`packed_live_pages`), in blocks of
+    :func:`packed_block_pages` pages, double-buffered; an unused row
+    (slot -1) fetches nothing.  Pools are stacked and read at ``layer``
     as in :func:`paged_decode_attention`."""
-    _, T, Hq, D = q.shape
-    P, page, Hkv, Dv = vpool.shape[-4:]
-    npages = block_tables.shape[1]
-    n_work = meta.shape[0]
-    g = Hq // Hkv
-    dump = P - 1
-    bq = PACKED_BLOCK_Q
-    ly, (kpool, vpool, ppos) = _stack(layer, (kpool, vpool, ppos))
-
-    n_live = packed_live_pages(meta, q_pos, page, npages)
-
-    # index maps take the scalar-prefetch refs (n_live, meta, tables)
-    def page_of(w, j, nl, meta, bt):
-        return _live_page_id(bt, jnp.maximum(meta[w, 0], 0), j, nl[w], dump)
-
-    kernel = functools.partial(_paged_packed_kernel, scale=scale,
-                               attn_softcap=attn_softcap, window=window,
-                               npages=npages, dump=dump, g=g)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(n_work, npages),
-        in_specs=[
-            pl.BlockSpec((1, T, Hq, D), lambda w, j, *pf: (0, 0, 0, 0)),
-            *_pool_specs(kpool, vpool, None, page_of),
-            pl.BlockSpec((T, 1), lambda w, j, *pf: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, T, Hq, Dv),
-                               lambda w, j, *pf: (0, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, bq * g), jnp.float32),
-            pltpu.VMEM((Hkv, bq * g), jnp.float32),
-            pltpu.VMEM((Hkv, bq * g, Dv), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, T, Hq, Dv), q.dtype),
-        interpret=interpret,
-    )(ly, n_live, meta, block_tables, q, kpool, vpool, _positions(ppos),
-      q_pos.reshape(T, 1))
-    # lanes no window owns (stream padding) are never written: zero them
-    return jnp.where((q_pos >= 0)[..., None, None], out, 0)
-
-
-def _paged_packed_kernel_q8(ly_ref, nl_ref, meta_ref, bt_ref, q_ref, k_ref,
-                            ks_ref, v_ref, vs_ref, kp_ref, qp_ref, o_ref,
-                            m_scr, l_scr, acc_scr, *, scale, attn_softcap,
-                            window, npages, dump, g):
-    """Quantized-pool packed kernel: int8 page tiles + per-entry scale
-    rows dequantized in-register, feeding the same windowed
-    online-softmax body as _paged_packed_kernel."""
-    w, j = pl.program_id(0), pl.program_id(1)
-    bq = PACKED_BLOCK_Q
-    slot = meta_ref[w, 0]
-    offc = meta_ref[w, 1]
-    ws = meta_ref[w, 2]
-    we = meta_ref[w, 3]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    _, _, Hq, D = q_ref.shape
-    Hkv = k_ref.shape[2]
-    lane = offc + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-    in_win = (lane >= ws) & (lane < we)                    # (bq, 1)
-
-    @_on_live_page(j, nl_ref[w])
-    def _step():
-        q = q_ref[0, pl.ds(offc, bq)].astype(jnp.float32)  # (bq, Hq, D)
-        k, v = _page_kv(q_ref, k_ref, v_ref, ks_ref, vs_ref)
-        kp = _page_pos(kp_ref, bt_ref, jnp.maximum(slot, 0), j, nl_ref[w],
-                       dump)                               # (1, page)
-        qp = qp_ref[pl.ds(offc, bq)]                       # (bq, 1)
-        qp_eff = jnp.where(in_win, qp, -1)
-        allocated = (slot >= 0) & (bt_ref[jnp.maximum(slot, 0), j] >= 0)
-        mask = _mq_mask(kp, qp_eff, allocated, window)
-        qg = q.reshape(bq, Hkv, g, D).transpose(1, 0, 2, 3)
-        _attend_block_mq(qg, k, v, mask, m_scr, l_scr, acc_scr, scale=scale,
-                         attn_softcap=attn_softcap)
-
-    @pl.when(j == npages - 1)
-    def _finish():
-        denom = jnp.maximum(l_scr[...], 1e-37)[..., None]
-        out = (acc_scr[...] / denom) \
-            .reshape(Hkv, bq, g, acc_scr.shape[-1]) \
-            .transpose(1, 0, 2, 3).reshape(bq, Hq, acc_scr.shape[-1])
-        old = o_ref[0, pl.ds(offc, bq)]
-        o_ref[0, pl.ds(offc, bq)] = jnp.where(
-            in_win[:, :, None], out.astype(o_ref.dtype), old)
+    return _paged_packed_call(
+        q, (kpool,), (vpool,), ppos, block_tables, q_pos, meta, layer,
+        window=window, scale=scale, attn_softcap=attn_softcap,
+        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",
@@ -899,51 +1046,13 @@ def paged_packed_attention_q8(q, kpool, k_scale, vpool, v_scale, ppos,
                               attn_softcap: Optional[float] = None,
                               interpret: bool = False):
     """:func:`paged_packed_attention` over an int8-quantized pool (same
-    scale-pool contract as :func:`paged_decode_attention_q8`; each work
-    row streams and dequantizes only its live pages)."""
-    _, T, Hq, D = q.shape
-    P, page, Hkv, Dv = vpool.shape[-4:]
-    npages = block_tables.shape[1]
-    n_work = meta.shape[0]
-    g = Hq // Hkv
-    dump = P - 1
-    bq = PACKED_BLOCK_Q
-    ly, (kpool, k_scale, vpool, v_scale, ppos) = _stack(
-        layer, (kpool, k_scale, vpool, v_scale, ppos))
-
-    n_live = packed_live_pages(meta, q_pos, page, npages)
-
-    # index maps take the scalar-prefetch refs (n_live, meta, tables)
-    def page_of(w, j, nl, meta, bt):
-        return _live_page_id(bt, jnp.maximum(meta[w, 0], 0), j, nl[w], dump)
-
-    kernel = functools.partial(_paged_packed_kernel_q8, scale=scale,
-                               attn_softcap=attn_softcap, window=window,
-                               npages=npages, dump=dump, g=g)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(n_work, npages),
-        in_specs=[
-            pl.BlockSpec((1, T, Hq, D), lambda w, j, *pf: (0, 0, 0, 0)),
-            *_pool_specs(kpool, vpool, k_scale, page_of),
-            pl.BlockSpec((T, 1), lambda w, j, *pf: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, T, Hq, Dv),
-                               lambda w, j, *pf: (0, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, bq * g), jnp.float32),
-            pltpu.VMEM((Hkv, bq * g), jnp.float32),
-            pltpu.VMEM((Hkv, bq * g, Dv), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, T, Hq, Dv), q.dtype),
-        interpret=interpret,
-    )(ly, n_live, meta, block_tables, q, kpool, k_scale, vpool, v_scale,
-      _positions(ppos), q_pos.reshape(T, 1))
-    return jnp.where((q_pos >= 0)[..., None, None], out, 0)
+    scale-pool contract as :func:`paged_decode_attention_q8`): each work
+    row fetches its live pages' codes and scale rows and dequantizes
+    them in-register."""
+    return _paged_packed_call(
+        q, (kpool, k_scale), (vpool, v_scale), ppos, block_tables, q_pos,
+        meta, layer, window=window, scale=scale, attn_softcap=attn_softcap,
+        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "scale",

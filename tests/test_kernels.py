@@ -293,9 +293,14 @@ def test_live_pages_hand_count():
     got = DA.packed_live_pages(jnp.asarray(meta), jnp.asarray(qp), page,
                                npages).tolist()
     assert got == [1, 2, 2, 2, 0, 0, 0, 0]
-    # the serve loop's host count agrees with the kernels' bound
+    # the serve loop's host count agrees with the kernels' bound; the
+    # packed kernel steps once per block of live pages (at 3 pages a
+    # block, the three 4-page rows take 2, the query-less row none)
     assert page_work(np.asarray(q), page, npages) == (sum(want),
                                                       q.size * npages)
+    assert page_work(np.asarray(q), page, npages, 3) == (
+        sum(want), sum(-(-n // 3) for n in want))
+    assert page_work(np.asarray(q), page, npages, 3)[1] == 13
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])

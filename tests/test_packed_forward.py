@@ -196,6 +196,102 @@ def test_packed_kernel_live_bound_vs_oracle(rng, pool):
     assert not np.asarray(out[0, t:]).any()
 
 
+def _block_edge_stream(rng, *, layers, layer):
+    """A packed stream whose work rows sit at the kernel's block edges:
+    npages 6 and pages small enough that a block holds BP = 4 pages
+    (asserted by the caller).  Decode lanes of 1, BP - 1, BP, BP + 1 and
+    npages live pages; a 12-token chunk (two consecutive rows of one
+    slot); a lane whose live range holds an unallocated entry; a lane
+    whose pages hold stale positions past its query, inside the bound
+    and beyond it; unused rows (0 live pages) between used ones.  Pages
+    are spread over a pool of 136 (> POS_LANES), so some take their
+    positions by copy and some from the resident last column block."""
+    P, page, npages, Hq, Hkv, D = 136, 8, 6, 4, 2, 16
+    # (slot, query positions); slot 6 misses logical page 2, slot 7
+    # holds stale entries in its pages
+    segs = [(0, [0]), (1, [23]), (2, [31]), (3, [32]), (4, [47]),
+            (5, list(range(20, 32))), (6, [39]), (7, [19])]
+    ppos = np.full((layers, P, page), -1, np.int32)
+    bt = np.full((len(segs), npages), -1, np.int32)
+    free = iter(rng.permutation(P - 1))
+    for slot, qs in segs:
+        ctx = npages * page if slot == 7 else qs[-1] + 1
+        for j in range(-(-ctx // page)):
+            if slot == 6 and j == 2:
+                continue
+            bt[slot, j] = next(free)
+            for t in range(page):
+                if j * page + t < ctx:
+                    ppos[layer, bt[slot, j], t] = j * page + t
+    # slot 7's query is at 19: its page 2 holds 16..23, pages 3..5
+    # stale entries beyond it
+    T_ = 24
+    slot_ids = np.full(T_, -1, np.int32)
+    q_pos = np.full(T_, -1, np.int32)
+    starts, lens, t = [], [], 0
+    for slot, qs in segs:
+        starts.append(t)
+        lens.append(len(qs))
+        slot_ids[t:t + len(qs)] = slot
+        q_pos[t:t + len(qs)] = qs
+        t += len(qs)
+    meta = DA.packed_meta_table(starts, lens, [g[0] for g in segs], T_,
+                                T_ // DA.PACKED_BLOCK_Q + len(segs))
+    used = meta[meta[:, 0] >= 0]
+    unused = np.asarray([[-1, 0, 0, 0]], np.int32)
+    # unused rows between used ones: after the first row, after the
+    # chunk's two rows, and at the end
+    meta = np.concatenate([used[:1], unused, used[1:7], unused, unused,
+                           used[7:], unused], axis=0)
+    k = rng.normal(size=(layers, P, page, Hkv, D)).astype(np.float32)
+    v = rng.normal(size=(layers, P, page, Hkv, D)).astype(np.float32)
+    q = rng.normal(size=(1, T_, Hq, D)).astype(np.float32)
+    return (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+            jnp.asarray(ppos), jnp.asarray(bt), jnp.asarray(q_pos[None]),
+            jnp.asarray(slot_ids), jnp.asarray(meta), D, t)
+
+
+@pytest.mark.parametrize("pool", ["fp", "q8"])
+@pytest.mark.parametrize("window,softcap,layer", [
+    (None, None, None), (12, None, None), (None, 30.0, None),
+    (None, None, 1)])
+def test_packed_kernel_block_edges_vs_oracle(rng, pool, window, softcap,
+                                             layer):
+    """The packed kernel's blocks at their edges against the oracle:
+    rows of 0, 1, BP - 1, BP, BP + 1 and npages live pages, consecutive
+    rows of one slot and of different slots (the next row's first block
+    is fetched during this row's last), unused rows between them, an
+    unallocated entry in a live range, stale positions inside and past
+    the bound; under a window, a softcap, and at layer 1 of a stack of
+    3.  Padding lanes come back zero."""
+    layers = 1 if layer is None else 3
+    (q, k, v, ppos, bt, q_pos, slot_ids, meta, D,
+     n_real) = _block_edge_stream(rng, layers=layers, layer=layer or 0)
+    if layer is None:
+        k, v, ppos = k[0], v[0], ppos[0]
+    live = DA.packed_live_pages(meta, q_pos, 8, 6)
+    bp = DA.packed_block_pages(k, v, None, 6)
+    assert bp == 4
+    assert {0, 1, bp - 1, bp, bp + 1, 6} <= set(np.asarray(live).tolist())
+    kw = dict(window=window, scale=D ** -0.5, attn_softcap=softcap)
+    if pool == "q8":
+        (kq, ks), (vq, vs) = KV.quantize_kv(k), KV.quantize_kv(v)
+        out = DA.paged_packed_attention_q8(
+            q, kq, ks, vq, vs, ppos, bt, q_pos, meta, layer, **kw,
+            interpret=True)
+        ref = R.paged_packed_attention_ref(
+            q, kq, vq, ppos, bt, q_pos, slot_ids, k_scale=ks, v_scale=vs,
+            layer=layer, **kw)
+    else:
+        out = DA.paged_packed_attention(q, k, v, ppos, bt, q_pos, meta,
+                                        layer, **kw, interpret=True)
+        ref = R.paged_packed_attention_ref(q, k, v, ppos, bt, q_pos,
+                                           slot_ids, layer=layer, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    assert not np.asarray(out[0, n_real:]).any()
+
+
 def test_packed_meta_table_tiles_segments():
     """Every real lane lands in exactly one query window; unused rows
     are slot -1; tile starts clamp into the padded stream."""

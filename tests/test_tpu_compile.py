@@ -106,6 +106,67 @@ def test_paged_packed_attention_compiles(one_chip, arch, pool):
     _assert_kernel(compiled)
 
 
+# the packed kernel as the benchmark cells run it: (Hq, Hkv, head_dim),
+# pool dtype, stream width, slots, pages per slot, pages with the dump
+# page, layers; pool rows widened to whole lanes, as on a TPU
+PACKED_CELLS = {
+    "unimo-text": ((16, 16, 64), "int8", 512, 128, 32, 2049, 24),
+    "qwen3-30b-a3b": ((32, 4, 128), "bf16", 256, 64, 128, 4097, 6),
+}
+
+
+def _packed_vmem_bill(q, kpool, vpool, k_scale, npages):
+    """VMEM the packed kernel holds: the resident query, output and
+    position blocks (double-buffered by the pipeline), both page buffer
+    sets, the softmax state and a block's float32 K and V."""
+    vm = DA._vmem_bytes
+    _, T, Hq, D = q.shape
+    page, Hkv, Dv = vpool.shape[-3:]
+    bp = DA.packed_block_pages(kpool, vpool, k_scale, npages)
+    g = Hq // Hkv
+    resident = 2 * (vm((T, Hq, D), q.dtype) + vm((T, Hq, Dv), q.dtype)
+                    + vm((T, 1), jnp.int32))
+    buffers = 2 * bp * sum(vm(p.shape[2:], p.dtype)
+                           for p in (kpool, vpool, k_scale, k_scale)
+                           if p is not None)
+    buffers += 2 * bp * vm((page, DA.POS_LANES), jnp.int32)
+    state = 2 * vm((Hkv, 8 * g), jnp.float32) \
+        + vm((Hkv, 8 * g, Dv), jnp.float32)
+    block = 2 * vm((bp * page, Hkv, Dv), jnp.float32)
+    return bp, resident + buffers + state + block
+
+
+@pytest.mark.parametrize("cell", sorted(PACKED_CELLS))
+def test_paged_packed_attention_compiles_at_the_cells_geometry(one_chip,
+                                                               cell):
+    """The packed kernel at a benchmark cell's geometry, on the layer
+    stack: the shape is supported, it compiles, blocks hold 8 or 16
+    pages, and its VMEM bill is within the kernel's scoped limit."""
+    (Hq, Hkv, D), pool, T, slots, npages, P, L = PACKED_CELLS[cell]
+    dt = jnp.int8 if pool == "int8" else jnp.bfloat16
+    kv = ((L, P, PAGE, Hkv, 128), dt)
+    scale = ((L, P, PAGE, 128), jnp.float32)
+    pools = [kv, scale, kv, scale] if pool == "int8" else [kv, kv]
+    shapes = [((1, T, Hq, D), jnp.bfloat16), *pools, ((L, P, PAGE),
+                                                      jnp.int32),
+              ((slots, npages), jnp.int32), ((1, T), jnp.int32),
+              ((T // DA.PACKED_BLOCK_Q + slots, 4), jnp.int32),
+              ((), jnp.int32)]
+    q, kpool, *_ = structs = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    bt = structs[-4]
+    k_scale = structs[2] if pool == "int8" else None
+    assert DA.paged_packed_shape_supported(q, kpool, bt)
+    bp, bill = _packed_vmem_bill(q, kpool, structs[len(pools) // 2 + 1],
+                                 k_scale, npages)
+    assert bp in (8, 16)
+    assert bill <= DA.PACKED_VMEM_LIMIT_BYTES
+    fn = DA.paged_packed_attention_q8 if pool == "int8" \
+        else DA.paged_packed_attention
+    compiled = _compile(
+        one_chip, lambda *a: fn(*a, window=None, scale=D ** -0.5), *shapes)
+    _assert_kernel(compiled)
+
+
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
 def test_paged_decode_attention_compiles(one_chip, arch, pool):

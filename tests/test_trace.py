@@ -324,10 +324,14 @@ def _live(block_row, qmax):
 
 
 def _pages_packed(args, _out):
+    # the packed kernel steps once per block of BP live pages of a row;
+    # the test model's pages are small enough that a block is a whole
+    # row of the block table (test_page_counters_match_hand_count)
     positions, meta, bt = (np.asarray(args[i]) for i in (3, 4, 6))
-    live = sum(_live(bt[slot], positions[ws:we].max())
-               for slot, _, ws, we in meta if slot >= 0)
-    return live, len(meta) * bt.shape[1]
+    live = [_live(bt[slot], positions[ws:we].max())
+            for slot, _, ws, we in meta if slot >= 0]
+    bp = bt.shape[1]
+    return sum(live), sum(-(-n // bp) for n in live)
 
 
 def _pages_step(args, out):
@@ -448,11 +452,18 @@ def test_page_counters_match_hand_count(rng, model, packed):
     """Each iteration's attn_pages equals a hand count of the live pages
     of its kernel dispatches' work items (packed query windows, fused
     decode lanes at every step, chunk rows, verify windows), and
-    attn_page_steps equals work items x pages per slot."""
+    attn_page_steps equals the kernels' steps: work items x pages per
+    slot, and for a packed dispatch one per block of live pages."""
+    from repro.kernels import decode_attention as DA
     cfg, params = model
     reqs = _requests(rng, cfg, [(30, 3), (26, 9), (9, 6), (22, 7)])
     tr = ServeTracer(clock=FakeClock())
     eng = _engine(cfg, params)
+    # a packed block holds a whole block-table row here (_pages_packed)
+    pool = eng._paged_context(PAGE, None, None)["cache"]["layers"][0][0]
+    npages = -(-eng.max_len // PAGE)
+    assert DA.packed_block_pages(pool["pk"], pool["pv"], None,
+                                 npages) == npages
     calls = _record_dispatches(eng, tr, brute=BRUTE_PAGES)
     _serve(eng, reqs, trace=tr, **_mode_kw(packed))
     assert validate_events(tr.events) == []
